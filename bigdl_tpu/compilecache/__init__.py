@@ -5,10 +5,9 @@ model replicas + warm `Engine` thread pools — the reference never pays
 re-initialization per task; here the equivalent fixed cost is XLA
 compilation):
 
-  * **cache**  — persistent XLA compilation cache behind
-                 BIGDL_TPU_COMPILE_CACHE / --compile-cache, with
-                 per-process staging + atomic-rename publishing so
-                 multiple processes can safely share one directory;
+  * **cache**  — jax's persistent XLA compilation cache at
+                 JAX_COMPILATION_CACHE_DIR, else the fixed
+                 `<checkout>/.jax_cache`; entry points call `enable()`;
   * **warmup** — AOT `jit(...).lower(specs).compile()` plumbing for the
                  trainers' `precompile()` (BIGDL_TPU_PRECOMPILE /
                  --precompile), logging XLA cost analysis (flops, bytes,
@@ -19,16 +18,14 @@ See docs/compile_cache.md.
 """
 
 from bigdl_tpu.compilecache.cache import (cache_dir, clear, disable,
-                                          enable, enabled, ensure_enabled,
-                                          stats, sync)
+                                          enable, enabled, stats)
 from bigdl_tpu.compilecache.warmup import (cost_summary, key_sds, log_cost,
                                            precompile_buckets,
                                            precompile_fixed, scalar_sds,
                                            sds_like)
 
 __all__ = [
-    "enable", "ensure_enabled", "enabled", "disable", "sync",
-    "cache_dir", "stats", "clear",
+    "enable", "enabled", "disable", "cache_dir", "stats", "clear",
     "cost_summary", "log_cost", "sds_like", "key_sds", "scalar_sds",
     "precompile_buckets", "precompile_fixed",
 ]

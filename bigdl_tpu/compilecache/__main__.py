@@ -3,11 +3,11 @@
     python -m bigdl_tpu.compilecache stats [DIR]
     python -m bigdl_tpu.compilecache clear [DIR]
 
-DIR defaults to BIGDL_TPU_COMPILE_CACHE. `stats` prints the committed
-entries grouped by program (cache keys embed the jitted function name)
-plus any per-process staging dirs; `clear` removes everything under the
-root — the recovery move when a jax/jaxlib upgrade leaves stale entries
-behind (docs/compile_cache.md)."""
+DIR defaults to JAX_COMPILATION_CACHE_DIR, else `<checkout>/.jax_cache`.
+`stats` prints the entries grouped by program (cache keys embed the
+jitted function name); `clear` removes every entry — the recovery move
+when a jax/jaxlib upgrade leaves stale entries behind
+(docs/compile_cache.md)."""
 
 from __future__ import annotations
 
@@ -21,12 +21,12 @@ from bigdl_tpu.compilecache import cache
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="bigdl_tpu.compilecache")
     sub = ap.add_subparsers(dest="cmd", required=True)
-    p = sub.add_parser("stats", help="inventory the cache root")
+    p = sub.add_parser("stats", help="inventory the cache directory")
     p.add_argument("dir", nargs="?", default=None,
-                   help="cache root (default BIGDL_TPU_COMPILE_CACHE)")
+                   help="cache directory (default: cache_dir())")
     p.add_argument("--json", action="store_true",
                    help="emit one JSON object instead of the table")
-    p = sub.add_parser("clear", help="remove every entry + staging dir")
+    p = sub.add_parser("clear", help="remove every entry")
     p.add_argument("dir", nargs="?", default=None)
     args = ap.parse_args(argv)
 
@@ -40,17 +40,10 @@ def main(argv=None) -> int:
     if getattr(args, "json", False):
         print(json.dumps(s))
         return 0
-    if not s["root"]:
-        print("no cache dir (set BIGDL_TPU_COMPILE_CACHE or pass DIR)")
-        return 1
-    print(f"cache root: {s['root']}")
-    print(f"committed:  {s['entries']} entries, {s['bytes']} bytes")
+    print(f"cache dir: {s['root']}")
+    print(f"entries:   {s['entries']}, {s['bytes']} bytes")
     for prog, n in sorted(s["programs"].items()):
         print(f"  {prog}: {n} variant{'s' if n != 1 else ''}")
-    for st in s["staging"]:
-        state = "live" if st["alive"] else "dead"
-        print(f"staging {st['dir']} ({state} pid {st['pid']}): "
-              f"{st['pending']} unpublished")
     return 0
 
 

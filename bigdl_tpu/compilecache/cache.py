@@ -1,334 +1,133 @@
-"""Persistent XLA compilation cache with safe multi-process sharing.
+"""Persistent XLA compilation cache at a place the caller can choose.
 
 The reference amortizes per-task re-initialization by broadcasting ONE
 serialized model to every executor and reusing it for the whole job
 (`ModelBroadcast.scala`, cached replicas per core). The TPU-native analog
-of that cost is XLA compilation: every trainer process used to recompile
-its step programs from scratch. This module wires jax's
-`jax_compilation_cache_dir` so compiled executables persist across
-processes — a warm run deserializes instead of recompiling.
+of that cost is XLA compilation: every process used to recompile its
+step programs from scratch. jax's own persistent cache
+(`jax_compilation_cache_dir`) removes that cost; this module only decides
+WHERE it lives:
 
-Multi-process discipline: jax's own file cache writes entries with a
-plain `write_bytes` (no temp + rename), so two processes sharing one
-directory can expose a half-written executable to a concurrent reader.
-We therefore point jax at a **per-process staging directory** under the
-cache root, seeded from the root's committed entries (hardlinks — no
-data copy), and publish new entries back with the same atomic-rename
-commit discipline as the v2 snapshot writer (resilience/manifest.py
-COMMIT marker): the `-atime` sidecar lands first, then the `-cache`
-entry via `os.replace`, so a reader either sees a complete entry or no
-entry at all.
+  * `JAX_COMPILATION_CACHE_DIR` set — jax reads it at import and the
+    cache is already on. `enable()` changes nothing, and no code of this
+    repository points jax anywhere else.
+  * unset — `enable()` points jax at `<checkout>/.jax_cache`, a fixed
+    path (the directory is part of every cache key through the XLA
+    side caches jax derives from it, so a path made from a pid, a
+    temporary name or the time would never hit).
 
-Layout under the root (docs/compile_cache.md):
+Processes may share the directory. The installed jax (0.9) writes an
+entry with a plain `write_bytes`; a reader that catches a half-written
+entry fails to deserialize it, warns (`Error reading persistent
+compilation cache entry`) and compiles — a lost hit, never a wrong
+program — and two writers of one key write the same bytes. The
+per-process staging directories and hard-link publishing this module
+once carried guarded against that torn read at the price of a cache
+directory named from the process id; they are gone.
 
-    <root>/jit_<name>-<key>-cache     committed executable (atomic)
-    <root>/jit_<name>-<key>-atime     LRU sidecar (8-byte timestamp)
-    <root>/.staging-p<proc>-<pid>/    per-process jax cache dir
-
-Staging dirs of dead processes are adopted (their finished entries
-published) and swept on the next `enable()` — the same dead-uncommitted
-sweep the snapshot GC does.
+Entries are jax's: `<dir>/jit_<name>-<key>-cache` (+ `-atime` when an
+eviction limit is set). Only programs that took at least
+`jax_persistent_cache_min_compile_time_secs` (jax's default: 1 s) to
+compile are written. See docs/compile_cache.md.
 """
 
 from __future__ import annotations
 
-import atexit
 import logging
 import os
-import shutil
-import time
-from typing import Dict, List, Optional
+from typing import Dict, Optional
 
 log = logging.getLogger("bigdl_tpu")
 
+_ENV = "JAX_COMPILATION_CACHE_DIR"
 _CACHE_SUFFIX = "-cache"
 _ATIME_SUFFIX = "-atime"
-_STAGING_PREFIX = ".staging-p"
-
-_state: Dict[str, Optional[str]] = {"root": None, "staging": None}
-_atexit_registered = False
-
-
-def _default_root() -> str:
-    from bigdl_tpu.utils import config
-    return config.get("COMPILE_CACHE")
+# <checkout>/bigdl_tpu/compilecache/cache.py -> <checkout>
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def _process_index() -> int:
-    from bigdl_tpu.utils.runtime import process_index
-    return process_index()
-
-
-def _entries(d: str) -> List[str]:
-    try:
-        names = os.listdir(d)
-    except OSError:
-        return []
-    return sorted(n for n in names if n.endswith(_CACHE_SUFFIX))
-
-
-def _link_or_copy(src: str, dst: str) -> None:
-    try:
-        os.link(src, dst)
-    except OSError:                      # cross-device / unsupported FS
-        shutil.copy2(src, dst)
-
-
-def _seed_staging(root: str, staging: str) -> int:
-    """Populate a fresh staging dir with the root's committed entries so
-    jax's cache lookups hit them. Hardlinks for the (immutable) `-cache`
-    payloads; `-atime` sidecars are COPIED — jax rewrites them in place
-    on every hit, and a hardlinked inode would tear the root's copy."""
-    n = 0
-    for name in _entries(root):
-        dst = os.path.join(staging, name)
-        if os.path.exists(dst):
-            continue
-        _link_or_copy(os.path.join(root, name), dst)
-        atime = name[: -len(_CACHE_SUFFIX)] + _ATIME_SUFFIX
-        src_atime = os.path.join(root, atime)
-        dst_atime = os.path.join(staging, atime)
-        if os.path.exists(src_atime):
-            shutil.copy2(src_atime, dst_atime)
-        else:
-            with open(dst_atime, "wb") as f:
-                f.write(time.time_ns().to_bytes(8, "little"))
-        n += 1
-    return n
-
-
-def _publish(staging: str, root: str) -> int:
-    """Atomically commit staging entries the root doesn't have yet.
-    Commit order mirrors the snapshot COMMIT marker: sidecar first, the
-    `-cache` entry last via `os.replace` — its appearance IS the commit."""
-    published = 0
-    for name in _entries(staging):
-        dst = os.path.join(root, name)
-        if os.path.exists(dst):          # same key == same executable
-            continue
-        src = os.path.join(staging, name)
-        key = name[: -len(_CACHE_SUFFIX)]
-        atime_src = os.path.join(staging, key + _ATIME_SUFFIX)
-        atime_dst = os.path.join(root, key + _ATIME_SUFFIX)
-        tmp = f"{dst}.tmp.{os.getpid()}"
-        try:
-            if not os.path.exists(atime_dst):
-                atmp = f"{atime_dst}.tmp.{os.getpid()}"
-                if os.path.exists(atime_src):
-                    shutil.copy2(atime_src, atmp)
-                else:
-                    with open(atmp, "wb") as f:
-                        f.write(time.time_ns().to_bytes(8, "little"))
-                os.replace(atmp, atime_dst)
-            _link_or_copy(src, tmp)
-            os.replace(tmp, dst)
-            published += 1
-        except OSError as e:             # cache is best-effort, never fatal
-            log.warning("compile-cache publish of %s failed: %s", name, e)
-            for leftover in (tmp,):
-                try:
-                    os.unlink(leftover)
-                except OSError:
-                    pass
-    return published
-
-
-def _staging_dirs(root: str) -> List[str]:
-    try:
-        names = os.listdir(root)
-    except OSError:
-        return []
-    return sorted(n for n in names if n.startswith(_STAGING_PREFIX))
-
-
-def _staging_pid(name: str) -> Optional[int]:
-    try:
-        return int(name.rsplit("-", 1)[1])
-    except (IndexError, ValueError):
-        return None
-
-
-def _pid_alive(pid: int) -> bool:
-    try:
-        os.kill(pid, 0)
-    except ProcessLookupError:
-        return False
-    except OSError:
-        return True                      # EPERM: alive, not ours
-    return True
-
-
-def _sweep_dead_staging(root: str) -> int:
-    """Adopt-and-remove staging dirs whose owner process is gone: their
-    finished entries are committed (they are complete files — jax wrote
-    and closed them), then the dir is deleted. The live-process dirs are
-    left alone."""
-    swept = 0
-    for name in _staging_dirs(root):
-        pid = _staging_pid(name)
-        if pid is None or _pid_alive(pid):
-            continue
-        d = os.path.join(root, name)
-        _publish(d, root)
-        shutil.rmtree(d, ignore_errors=True)
-        swept += 1
-    return swept
+def cache_dir() -> str:
+    """The directory the persistent cache lives in: the environment's
+    `JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache`."""
+    return os.environ.get(_ENV) or os.path.join(_CHECKOUT, ".jax_cache")
 
 
 def _reset_jax_cache() -> None:
     """Drop jax's initialized cache object so a config change takes
-    effect (jax lazily pins the cache at first use)."""
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:                    # noqa: BLE001 — best-effort
-        pass
+    effect (jax pins the cache at first use)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
-def enable(root: Optional[str] = None) -> Optional[str]:
-    """Turn the persistent compile cache on for this process. `root`
-    defaults to BIGDL_TPU_COMPILE_CACHE; empty/None disables (returns
-    None). Idempotent per root. Returns the staging dir jax writes to."""
-    root = root if root is not None else _default_root()
-    if not root:
-        return None
-    root = os.path.abspath(root)
-    if _state["root"] == root:
-        return _state["staging"]
-    os.makedirs(root, exist_ok=True)
-    _sweep_dead_staging(root)
-    staging = os.path.join(
-        root, f"{_STAGING_PREFIX}{_process_index()}-{os.getpid()}")
-    os.makedirs(staging, exist_ok=True)
-    seeded = _seed_staging(root, staging)
-
+def enable() -> str:
+    """Turn the persistent compile cache on for this process and return
+    its directory. Entry points call this once at start-up; idempotent."""
     import jax
-    _reset_jax_cache()
-    jax.config.update("jax_compilation_cache_dir", staging)
-    from bigdl_tpu.utils import config as _cfg
-    for flag, value in (
-            ("jax_persistent_cache_min_compile_time_secs",
-             _cfg.get("COMPILE_CACHE_MIN_COMPILE_S")),
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-            # jax's default derives an XLA autotune-cache dir FROM the
-            # compilation cache dir and serializes that PATH into every
-            # cache key — with per-process staging dirs (pid in the
-            # name) no two processes would ever share an entry. The
-            # autotune cache is GPU-only; disable the derivation so
-            # keys depend on the program, not on who compiled it.
-            ("jax_persistent_cache_enable_xla_caches", "none")):
-        try:
-            jax.config.update(flag, value)
-        except Exception:                # noqa: BLE001 — older jax
-            pass
-    _state.update(root=root, staging=staging)
-    global _atexit_registered
-    if not _atexit_registered:
-        atexit.register(sync)
-        _atexit_registered = True
-    log.info("compile cache enabled: %s (%d entries seeded)", root, seeded)
-    from bigdl_tpu import observe
-    observe.counter("compile_cache/seeded").inc(seeded)
-    return staging
-
-
-def ensure_enabled() -> Optional[str]:
-    """Knob-gated enable — the trainers call this at the top of
-    optimize()/precompile(); a no-op unless BIGDL_TPU_COMPILE_CACHE is
-    set (or enable() already ran)."""
-    if _state["root"] is not None:
-        return _state["staging"]
-    return enable()
+    path = cache_dir()
+    if jax.config.jax_compilation_cache_dir != path:
+        # jax read the environment variable at import: where that was
+        # set the two already agree, and `path` here is the fixed
+        # checkout directory
+        _reset_jax_cache()
+        jax.config.update("jax_compilation_cache_dir", path)
+        log.info("compile cache enabled: %s", path)
+    return path
 
 
 def enabled() -> bool:
-    return _state["root"] is not None
-
-
-def cache_dir() -> Optional[str]:
-    """The shared cache ROOT (not the per-process staging dir)."""
-    return _state["root"]
-
-
-def sync() -> int:
-    """Publish this process's freshly compiled entries to the shared
-    root (atomic renames). Trainers call this at the end of optimize()
-    and precompile(); also runs atexit. No-op when disabled."""
-    root, staging = _state["root"], _state["staging"]
-    if root is None or staging is None or not os.path.isdir(staging):
-        return 0
-    n = _publish(staging, root)
-    if n:
-        from bigdl_tpu import observe
-        observe.counter("compile_cache/published").inc(n)
-        log.info("compile cache: published %d new entr%s -> %s",
-                 n, "y" if n == 1 else "ies", root)
-    return n
+    import jax
+    return bool(jax.config.jax_compilation_cache_dir) \
+        and bool(jax.config.jax_enable_compilation_cache)
 
 
 def disable() -> None:
-    """Publish pending entries, detach jax from the cache, and remove
-    this process's staging dir (tests / explicit teardown)."""
-    if _state["root"] is None:
-        return
-    sync()
-    staging = _state["staging"]
-    _state.update(root=None, staging=None)
+    """Detach jax from the persistent cache (tests / explicit
+    teardown). Entries stay on disk."""
     import jax
-    _reset_jax_cache()
-    try:
+    if jax.config.jax_compilation_cache_dir is not None:
+        _reset_jax_cache()
         jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:                    # noqa: BLE001
-        pass
-    if staging:
-        shutil.rmtree(staging, ignore_errors=True)
 
 
-def stats(root: Optional[str] = None) -> Dict:
-    """Inventory of a cache root: committed entries, bytes, per-program
+def stats(path: Optional[str] = None) -> Dict:
+    """Inventory of a cache directory: entries, bytes, and per-program
     counts (cache keys are `jit_<fn-name>-<hash>`, so the program name
-    is recoverable), and per-staging-dir pending entries."""
-    root = os.path.abspath(root or _default_root() or "")
-    out: Dict = {"root": root, "entries": 0, "bytes": 0,
-                 "programs": {}, "staging": []}
-    if not root or not os.path.isdir(root):
+    is recoverable)."""
+    path = os.path.abspath(path or cache_dir())
+    out: Dict = {"root": path, "entries": 0, "bytes": 0, "programs": {}}
+    try:
+        names = sorted(os.listdir(path))
+    except OSError:
         return out
-    for name in _entries(root):
-        path = os.path.join(root, name)
+    for name in names:
+        if not name.endswith(_CACHE_SUFFIX):
+            continue
         try:
-            out["bytes"] += os.path.getsize(path)
+            out["bytes"] += os.path.getsize(os.path.join(path, name))
         except OSError:
             continue
         out["entries"] += 1
         prog = name[: -len(_CACHE_SUFFIX)].rsplit("-", 1)[0]
         out["programs"][prog] = out["programs"].get(prog, 0) + 1
-    for name in _staging_dirs(root):
-        d = os.path.join(root, name)
-        pid = _staging_pid(name)
-        pending = [e for e in _entries(d)
-                   if not os.path.exists(os.path.join(root, e))]
-        out["staging"].append({
-            "dir": name, "pid": pid,
-            "alive": bool(pid and _pid_alive(pid)),
-            "pending": len(pending)})
     return out
 
 
-def clear(root: Optional[str] = None) -> int:
-    """Remove every committed entry, sidecar, staging dir, and lockfile
-    under the root. Returns the number of committed entries removed."""
-    root = os.path.abspath(root or _default_root() or "")
-    if not root or not os.path.isdir(root):
+def clear(path: Optional[str] = None) -> int:
+    """Remove every entry, sidecar and lockfile under the directory.
+    Returns the number of entries removed."""
+    path = os.path.abspath(path or cache_dir())
+    try:
+        names = os.listdir(path)
+    except OSError:
         return 0
-    removed = len(_entries(root))
-    for name in os.listdir(root):
-        path = os.path.join(root, name)
-        if name.startswith(_STAGING_PREFIX):
-            shutil.rmtree(path, ignore_errors=True)
-        elif (name.endswith((_CACHE_SUFFIX, _ATIME_SUFFIX))
-              or name == ".lockfile" or ".tmp." in name):
+    removed = 0
+    for name in names:
+        if name.endswith((_CACHE_SUFFIX, _ATIME_SUFFIX)) \
+                or name == ".lockfile":
             try:
-                os.unlink(path)
+                os.unlink(os.path.join(path, name))
             except OSError:
-                pass
+                continue
+            removed += name.endswith(_CACHE_SUFFIX)
     return removed

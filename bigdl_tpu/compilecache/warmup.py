@@ -94,8 +94,6 @@ def precompile_buckets(jitted, params, state, feature_shape, dtype,
     import time as _time
     import jax
     import numpy as np
-    from bigdl_tpu import compilecache
-    compilecache.ensure_enabled()
 
     sh = None
     if mesh is not None:
@@ -130,7 +128,6 @@ def precompile_buckets(jitted, params, state, feature_shape, dtype,
         executables[b] = compiled
         results[b] = log_cost(f"{name}/bucket{b}", compiled,
                               _time.perf_counter() - t0)
-    compilecache.sync()                 # publish what warmup compiled
     return results, executables
 
 
@@ -143,12 +140,9 @@ def precompile_fixed(jitted, args_specs, *, name: str):
     is logged under `compile/<name>/...`; returns (cost_summary,
     executable)."""
     import time as _time
-    from bigdl_tpu import compilecache
-    compilecache.ensure_enabled()
     t0 = _time.perf_counter()
     compiled = jitted.lower(*args_specs).compile()
     summary = log_cost(name, compiled, _time.perf_counter() - t0)
-    compilecache.sync()
     return summary, compiled
 
 

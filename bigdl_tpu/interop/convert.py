@@ -132,8 +132,6 @@ def convert(input_path: str, output_path: str, module_path: str = None,
 
 
 def main(argv=None):
-    from bigdl_tpu.utils.platform import force_cpu_if_requested
-    force_cpu_if_requested()
     ap = argparse.ArgumentParser(prog="bigdl_tpu.interop.convert")
     ap.add_argument("--input", required=True)
     ap.add_argument("--output", required=True)

@@ -1108,7 +1108,7 @@ def llama_sp_apply(module, params, tokens, mesh, seq_axis="seq"):
     mesh carries one. tokens (B, T) with T % mesh.shape[seq_axis] == 0;
     returns (B, T, vocab) logits sharded over the sequence dim."""
     from jax.sharding import PartitionSpec as P
-    from bigdl_tpu.utils.compat import shard_map
+    from jax import shard_map
     from bigdl_tpu.parallel.mesh import composed_data_axis
     from bigdl_tpu.parallel.ring import RingAttention
 

@@ -7,7 +7,7 @@ Layers here are declarative configs; `Sequential.build()` runs them through
 the same builder table the HDF5/JSON importer uses
 (`interop/keras_loader._BUILDERS`), so `Dense(64)` after a `Conv2D` never
 needs its input dim spelled out — the round-1 facade required explicit dims
-everywhere (VERDICT weak item 10).
+everywhere.
 
     from bigdl_tpu import keras_layers as kl
     model = kl.Sequential(

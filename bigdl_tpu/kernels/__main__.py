@@ -9,8 +9,8 @@
 mirrors the bench.py kernel shapes) and publishes the winners; `stats`
 prints the committed table grouped by kernel plus staging dirs; `clear`
 removes everything under the root. DIR defaults to
-BIGDL_TPU_AUTOTUNE_CACHE (falling back to
-<BIGDL_TPU_COMPILE_CACHE>/autotune) — docs/kernels.md."""
+BIGDL_TPU_AUTOTUNE_CACHE (falling back to `<compile cache dir>/autotune`
+when the persistent compile cache is on) — docs/kernels.md."""
 
 from __future__ import annotations
 
@@ -67,7 +67,7 @@ def main(argv=None) -> int:
         return 0
     if not s["root"]:
         print("no autotune dir (set BIGDL_TPU_AUTOTUNE_CACHE / "
-              "BIGDL_TPU_COMPILE_CACHE or pass DIR)")
+              "JAX_COMPILATION_CACHE_DIR or pass DIR)")
         return 1
     print(f"autotune root: {s['root']}")
     print(f"committed:     {s['entries']} entries")

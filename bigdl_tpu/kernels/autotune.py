@@ -9,8 +9,8 @@ survive the process. The reference framework shipped its equivalents
 TPU-native answer is to SEARCH the small block-size space once per
 (kernel, shape, device) and persist the winner.
 
-Table discipline mirrors `compilecache/cache.py` exactly, and by default
-the table lives NEXT TO the XLA compile cache (`<root>/autotune/`):
+By default the table lives NEXT TO the XLA compile cache
+(`<compile cache dir>/autotune/`):
 
   * committed entries are one JSON file each
     (``tune_<kernel>-<key16>.json``), written into a per-process staging
@@ -97,9 +97,9 @@ def _default_root() -> Optional[str]:
     root = config.get("AUTOTUNE_CACHE")
     if root:
         return root
-    cc = config.get("COMPILE_CACHE")
-    if cc:
-        return os.path.join(cc, "autotune")
+    from bigdl_tpu import compilecache
+    if compilecache.enabled():
+        return os.path.join(compilecache.cache_dir(), "autotune")
     return None
 
 
@@ -139,7 +139,7 @@ def _pid_alive(pid: int) -> bool:
 
 def _publish(staging: str, root: str) -> int:
     """Atomically commit finished staging entries into the root: the
-    ``os.replace`` IS the commit (compilecache/cache.py discipline). The
+    ``os.replace`` IS the commit. The
     newer file wins on a racing key — both racers hold a complete entry
     for the same (kernel, shape, device), so either winner is valid."""
     published = 0
@@ -327,8 +327,8 @@ def _enabled() -> bool:
 
 def _time_once(fn: Callable, iters: int = 3) -> float:
     """Best-of-iters wall time of `fn()` (after one warmup call that
-    eats compile), with the result fetched to completion — the same
-    dispatch-overlap discipline as utils/sync.time_steps, sized for a
+    eats compile), each call waited to completion — the discipline of
+    utils/sync.time_steps, sized for a
     block-size comparison rather than a publishable benchmark."""
     import jax
     jax.block_until_ready(fn())          # compile + warm
@@ -363,10 +363,8 @@ def _search(kernel: str, shape: Dict, defaults: Dict) -> Dict:
     """Run the registered searcher: time every candidate config, return
     the winner record. Call sites usually sit INSIDE a jit trace (shapes
     are concrete at trace time); jax's trace state is thread-local, so
-    a mid-trace search hops to a worker thread whose state is clean and
+    the search always hops to a worker thread, whose state is clean, and
     the candidates run eagerly there."""
-    import threading
-    import jax
     from bigdl_tpu import observe
     searcher = _SEARCHERS.get(kernel)
     key = canonical_key(kernel, shape)
@@ -375,29 +373,25 @@ def _search(kernel: str, shape: Dict, defaults: Dict) -> Dict:
     if searcher is not None:
         candidates, make_runner = searcher
         with observe.phase(f"autotune/search/{kernel}", cat="kernel"):
-            if jax.core.trace_state_clean():
-                got, best_s, tried = _try_candidates(
-                    kernel, shape, candidates, make_runner)
-            else:
-                box: Dict = {}
+            box: Dict = {}
 
-                def run():
-                    try:
-                        box["out"] = _try_candidates(
-                            kernel, shape, candidates, make_runner)
-                    except Exception as e:   # noqa: BLE001
-                        box["err"] = e
-                from bigdl_tpu.utils.threads import spawn
-                # joined immediately: the hop exists only for a clean
-                # thread-local jax trace state, so non-daemon is safe
-                t = spawn(run, name="autotune-search", daemon=False)
-                t.join()
-                if "err" in box:
-                    log.warning("autotune search for %s failed: %s",
-                                key, box["err"])
-                    got, best_s, tried = None, None, 0
-                else:
-                    got, best_s, tried = box["out"]
+            def run():
+                try:
+                    box["out"] = _try_candidates(
+                        kernel, shape, candidates, make_runner)
+                except Exception as e:       # noqa: BLE001
+                    box["err"] = e
+            from bigdl_tpu.utils.threads import spawn
+            # joined immediately: the hop exists only for a clean
+            # thread-local jax trace state, so non-daemon is safe
+            t = spawn(run, name="autotune-search", daemon=False)
+            t.join()
+            if "err" in box:
+                log.warning("autotune search for %s failed: %s",
+                            key, box["err"])
+                got, best_s, tried = None, None, 0
+            else:
+                got, best_s, tried = box["out"]
             if got is not None:
                 best_cfg = got
     search_s = time.perf_counter() - t0

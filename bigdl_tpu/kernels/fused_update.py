@@ -19,7 +19,7 @@ the entire update is a single kernel:
     the same math as one fused elementwise expression — on the flat
     layout a whole model's update is ~15 ops instead of ~10 x n_leaves.
 
-Layouts (and what measurement taught us — BENCH_r11):
+Layouts (and what a CPU-mesh run of `bench.py kernels` showed, PR 11):
   * ``flat``  — concatenate all float leaves (cast to fp32), update the
     one flat vector through the Pallas kernel, split back (per-leaf
     dtype cast fused into the epilogue). This is the TPU layout: the

@@ -2,14 +2,10 @@
 
 The TPU MXU computes fp32 matmuls at JAX's DEFAULT precision by
 truncating multiplier inputs to bf16 (one pass) while accumulating in
-fp32. The round-4 real-chip deltas on the flash/CCE kernels (max rel
-0.13%) were attributed to this; these references make the attribution
-testable: the same math with every dot's operands rounded to bf16 and
-fp32 accumulation. The derived envelope justifies the real-chip
-tolerances in tests/test_kernels.py (REAL_CHIP_*_TOL) instead of one
-40-second observation, and the real-chip smokes compare against THIS
-reference tightly — if the accumulation-order hypothesis is wrong, the
-next live window fails loudly (VERDICT r4 weak #3 / item 7).
+fp32. These references are the same math with every dot's operands
+rounded to bf16 and fp32 accumulation, so the envelope a chip run of the
+flash/CCE kernels may sit from the fp32 reference is derived on the CPU:
+it pins the tolerances in tests/test_kernels.py (MXU_*_TOL).
 """
 
 from __future__ import annotations

@@ -83,7 +83,7 @@ class SeqParallelLM:
 
     # --------------------------------------------------------------- steps
     def _build(self, mesh: Mesh, what: str):
-        from bigdl_tpu.utils.compat import shard_map
+        from jax import shard_map
         from bigdl_tpu.parallel.mesh import DATA_AXIS
         n = mesh.shape[self.seq_axis]
         # compose with data parallelism when the mesh carries a 'data'

@@ -130,7 +130,7 @@ class MoELM:
         return world
 
     def _build_step(self, mesh: Mesh):
-        from bigdl_tpu.utils.compat import shard_map
+        from jax import shard_map
         ax = self.expert_axis
         dp = self._dp(mesh)
         baxes = self._batch_axes(mesh)

@@ -21,8 +21,6 @@ import sys
 
 import numpy as np
 
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
 
 def _seed_of(args) -> int:
     """--seed wins, else the BIGDL_TPU_SEED knob — the CLI trainers
@@ -86,12 +84,6 @@ def _common(p: argparse.ArgumentParser):
     p.add_argument("--checkpoint-keep-n", type=int, default=None,
                    help="retention: keep only the newest N committed "
                         "snapshots (BIGDL_TPU_CHECKPOINT_KEEP_N)")
-    p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persistent XLA compilation cache root: a warm "
-                        "run deserializes its step programs instead of "
-                        "recompiling (BIGDL_TPU_COMPILE_CACHE; inspect "
-                        "with `python -m bigdl_tpu.compilecache stats` — "
-                        "docs/compile_cache.md)")
     p.add_argument("--precompile", action="store_true",
                    help="AOT warmup: compile the train/eval programs "
                         "from shape specs before the first batch "
@@ -134,9 +126,6 @@ def _finish(opt, args, model, app):
     if getattr(args, "statusz_port", None):
         import os
         os.environ["BIGDL_TPU_STATUSZ_PORT"] = str(args.statusz_port)
-    if getattr(args, "compile_cache", None):
-        from bigdl_tpu import compilecache
-        compilecache.enable(args.compile_cache)
     if getattr(args, "precompile", False):
         import os
         os.environ["BIGDL_TPU_PRECOMPILE"] = "1"
@@ -534,7 +523,6 @@ def _train_ptb_moe(args, d, xs, ys):
 
 
 def main(argv=None):
-    force_cpu_if_requested()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
     # structured [p<index> <run-id>] prefix on every bigdl_tpu log line —
@@ -592,4 +580,10 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the process entry turns the persistent XLA cache on (at
+    # JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache —
+    # docs/compile_cache.md); main() called in-process leaves jax's
+    # configuration to its caller
+    from bigdl_tpu import compilecache
+    compilecache.enable()
     sys.exit(0 if main() is not None else 1)

@@ -45,7 +45,7 @@ attach exporters. The trainers call `ensure_started()` once per
 optimize() and `finish()` at the end — a disabled flight recorder costs
 one attribute check per span site.
 
-Span taxonomy (docs/observability.md): training spans (`train/*`,
+Span catalogue (docs/observability.md): training spans (`train/*`,
 `data/*`, `checkpoint/*`, `jit/compile`), resilience markers
 (`fault/*`, `preempt/*`, `retry`), and — since the serving subsystem —
 the serve family: `serve/pack` and `serve/dispatch` spans around each

@@ -118,10 +118,17 @@ class Optimizer:
                  optim_method: Optional[OptimMethod] = None,
                  seed: Optional[int] = None,
                  steps_per_call: Optional[int] = None,
-                 accum_steps: Optional[int] = None):
+                 accum_steps: Optional[int] = None,
+                 compute_dtype=None):
         from bigdl_tpu.utils import config
         if seed is None:
             seed = config.get("SEED")
+        if compute_dtype is None \
+                and config.get("COMPUTE_DTYPE") == "bfloat16":
+            # bf16 forward/backward over fp32 master weights (reference:
+            # FP16 wire compression + fp32 master copy)
+            compute_dtype = jnp.bfloat16
+        self.compute_dtype = compute_dtype
         if steps_per_call is None:
             steps_per_call = config.get("STEPS_PER_CALL")
         if accum_steps is None:
@@ -481,14 +488,14 @@ class Optimizer:
         return {"layout": "auto"}
 
     def _build_step(self) -> Callable:
-        return jax.jit(self._make_step(), donate_argnums=(0, 1, 2))
+        return jax.jit(self._make_step(self.compute_dtype),
+                       donate_argnums=(0, 1, 2))
 
     def _build_fused_step(self) -> Callable:
         # local trainer: jit with donation; the distributed trainer
         # overrides this with mesh shardings for the stacked batches
         return jax.jit(
-            self._make_fused_step(self.accum_steps,
-                                  getattr(self, "compute_dtype", None)),
+            self._make_fused_step(self.accum_steps, self.compute_dtype),
             donate_argnums=(0, 1, 2))
 
     # ------------------------------------------------- built-program cache
@@ -500,7 +507,7 @@ class Optimizer:
         from bigdl_tpu.kernels import fused_update as _fu
         dcn = self._dcn_config()
         return (kind, self.steps_per_call, self.accum_steps,
-                str(getattr(self, "compute_dtype", None)),
+                str(self.compute_dtype),
                 tuple(id(p) for p in self.grad_processors),
                 any(m._frozen for m in self.model.modules()),
                 # env-read at build: a test/process flipping the knob
@@ -790,7 +797,6 @@ class Optimizer:
         CLI: `--precompile`; knob: BIGDL_TPU_PRECOMPILE (optimize()
         then calls this automatically)."""
         import numpy as _np
-        from bigdl_tpu import compilecache
         from bigdl_tpu.compilecache import (key_sds, log_cost, scalar_sds,
                                             sds_like)
         if self._dcn_config() is not None:
@@ -802,7 +808,6 @@ class Optimizer:
                         "compiles on first dispatch")
             self._precompiled = True
             return {}
-        compilecache.ensure_enabled()
         observe.ensure_started()
         use_fused = self.steps_per_call > 1 or self.accum_steps > 1
         if sample_batch is None:
@@ -857,7 +862,6 @@ class Optimizer:
                 results["eval_step"] = log_cost(
                     "eval_step", e2.aot, time.perf_counter() - t0)
 
-        compilecache.sync()                # publish what warmup compiled
         self._precompiled = True
         return results
 
@@ -1012,10 +1016,9 @@ class Optimizer:
         observe.ensure_started()
         # run-shape gauges for /statusz (host-side ints, no syncs)
         observe.gauge("train/steps_per_call").set(self.steps_per_call)
-        # compile-latency subsystem (docs/compile_cache.md): persistent
-        # compilation cache + optional AOT warmup, both knob-gated
-        from bigdl_tpu import compilecache
-        compilecache.ensure_enabled()
+        # compile-latency subsystem (docs/compile_cache.md): optional
+        # AOT warmup (the persistent cache is the entry point's to
+        # enable — compilecache.enable())
         from bigdl_tpu.utils import config as _cfg
         if _cfg.get("PRECOMPILE") and not getattr(self, "_precompiled",
                                                   False):
@@ -1253,7 +1256,6 @@ class Optimizer:
 
         self._flush_metrics(st)
         self._finish_checkpoints()         # join any background snapshot
-        compilecache.sync()                # publish fresh cache entries
 
         trace_path = observe.finish()      # dump trace + final export flush
         if trace_path:
@@ -1557,7 +1559,7 @@ class Optimizer:
             if not hasattr(self, "_hist_grad_fn"):
                 from bigdl_tpu.core.module import cast_floating
                 model, criterion = self.model, self.criterion
-                compute_dtype = getattr(self, "compute_dtype", None)
+                compute_dtype = self.compute_dtype
                 processors = list(self.grad_processors)
                 frozen = any(m._frozen for m in model.modules())
 
@@ -1660,8 +1662,7 @@ class Optimizer:
                 ckpt.save_checkpoint(path, trees, meta)
             else:
                 self._checkpointer().save(path, trees, meta,
-                                          root=self.ckpt_path,
-                                          clone=self._step_donates())
+                                          root=self.ckpt_path)
         # per-save blocking stall: newest samples ride the bounded deque
         # (bench.py checkpoint mode), the full run's distribution lives
         # in the phase/train/checkpoint log-bucket histogram
@@ -1676,14 +1677,6 @@ class Optimizer:
             from bigdl_tpu.resilience.snapshot import AsyncCheckpointer
             self._ckpt_writer = AsyncCheckpointer()
         return self._ckpt_writer
-
-    def _step_donates(self) -> bool:
-        """Whether the jitted train step donates its tree buffers — the
-        async checkpointer must clone before a donating step can
-        invalidate them (resilience/snapshot.py). The local trainer
-        always donates; DistriOptimizer overrides with its
-        SUPPORTS_SHARDED_DONATION guard."""
-        return True
 
     def _snapshot_extra_meta(self) -> Dict:
         """Provenance recorded into the snapshot meta; the distributed

@@ -86,17 +86,11 @@ class DistriOptimizer(Optimizer):
                  accum_steps: Optional[int] = None):
         super().__init__(model, dataset, criterion, optim_method, seed=seed,
                          steps_per_call=steps_per_call,
-                         accum_steps=accum_steps)
-        if compute_dtype is None:
-            # reference: FP16 wire compression knob; here the bf16 policy
-            from bigdl_tpu.utils import config
-            import jax.numpy as _jnp
-            if config.get("COMPUTE_DTYPE") == "bfloat16":
-                compute_dtype = _jnp.bfloat16
+                         accum_steps=accum_steps,
+                         compute_dtype=compute_dtype)
         self.mesh = mesh if mesh is not None else Engine.mesh()
         self.rules = rules or ShardingRules()
         self.zero1 = zero1
-        self.compute_dtype = compute_dtype
         # composed slice×data ways — the global batch divides over BOTH
         # tiers of a two-tier mesh
         self._data_axis_size = data_axis_size(self.mesh)
@@ -214,12 +208,9 @@ class DistriOptimizer(Optimizer):
         p_sh = self._param_shardings(params_shape)
         s_sh = self._slot_shardings(slots_shape)
         rep = NamedSharding(self.mesh, P())
-        from bigdl_tpu.utils.compat import SUPPORTS_SHARDED_DONATION
         return jax.jit(
             step,
-            # old-jax GSPMD crashes aliasing donated buffers across the
-            # ZeRO-1 reshard — skip donation there (utils/compat.py)
-            donate_argnums=(0, 1, 2) if SUPPORTS_SHARDED_DONATION else (),
+            donate_argnums=(0, 1, 2),
             # model_state & batches: None = keep the layout _place_* chose
             in_shardings=(p_sh, None, s_sh, None, None, rep, rep, rep),
             out_shardings=(p_sh, None, s_sh, rep))
@@ -229,9 +220,7 @@ class DistriOptimizer(Optimizer):
         rules, slots per ZeRO-1, the stacked super-batch sharded on its
         batch dim (dim 1) over 'data', per-step (lr, neval, rng) stacks,
         the per-step valid mask (shape bucketing), and the stacked
-        per-step losses replicated. Same SUPPORTS_SHARDED_DONATION guard
-        as the single-step build — old-jax GSPMD crashes aliasing
-        donated buffers across the ZeRO-1 reshard."""
+        per-step losses replicated."""
         fused = self._make_fused_step(self.accum_steps, self.compute_dtype)
         params_shape, _ = jax.eval_shape(
             self.model.init, jax.random.PRNGKey(0))  # tpu-lint: disable=004
@@ -239,10 +228,9 @@ class DistriOptimizer(Optimizer):
         p_sh = self._param_shardings(params_shape)
         s_sh = self._slot_shardings(slots_shape)
         rep = NamedSharding(self.mesh, P())
-        from bigdl_tpu.utils.compat import SUPPORTS_SHARDED_DONATION
         return jax.jit(
             fused,
-            donate_argnums=(0, 1, 2) if SUPPORTS_SHARDED_DONATION else (),
+            donate_argnums=(0, 1, 2),
             in_shardings=(p_sh, None, s_sh, None, None, rep, rep, rep, rep),
             out_shardings=(p_sh, None, s_sh, rep))
 
@@ -538,11 +526,9 @@ class DistriOptimizer(Optimizer):
         s_sh = self._slot_shardings(slots_shape)
         ex_sh = self._exchange_shardings(cfg, params_shape)
         rep = NamedSharding(self.mesh, P())
-        from bigdl_tpu.utils.compat import SUPPORTS_SHARDED_DONATION
         return jax.jit(
             step,
-            donate_argnums=((0, 1, 2, 3) if SUPPORTS_SHARDED_DONATION
-                            else ()),
+            donate_argnums=(0, 1, 2, 3),
             in_shardings=(p_sh, None, s_sh, ex_sh, None, None, rep, rep,
                           rep),
             out_shardings=(p_sh, None, s_sh, ex_sh, rep))
@@ -557,11 +543,9 @@ class DistriOptimizer(Optimizer):
         s_sh = self._slot_shardings(slots_shape)
         ex_sh = self._exchange_shardings(cfg, params_shape)
         rep = NamedSharding(self.mesh, P())
-        from bigdl_tpu.utils.compat import SUPPORTS_SHARDED_DONATION
         return jax.jit(
             fused,
-            donate_argnums=((0, 1, 2, 3) if SUPPORTS_SHARDED_DONATION
-                            else ()),
+            donate_argnums=(0, 1, 2, 3),
             in_shardings=(p_sh, None, s_sh, ex_sh, None, None, rep, rep,
                           rep, rep),
             out_shardings=(p_sh, None, s_sh, ex_sh, rep))
@@ -650,13 +634,6 @@ class DistriOptimizer(Optimizer):
         return params, model_state, slots
 
     # ------------------------------------------------------------ resilience
-    def _step_donates(self):
-        # mirrors _build_step/_build_fused_step: donation is skipped on
-        # old-jax GSPMD (utils/compat.py), and then the async snapshot
-        # can read live buffers without a device-side clone
-        from bigdl_tpu.utils.compat import SUPPORTS_SHARDED_DONATION
-        return SUPPORTS_SHARDED_DONATION
-
     def _snapshot_extra_meta(self):
         """Snapshot provenance: record the source slice's layout so an
         elastic restore (resilience/elastic.py) can log the 8-device →

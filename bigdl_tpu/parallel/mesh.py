@@ -215,7 +215,7 @@ def cross_slice_accumulated_exchange(acc, mesh, *, compress: str = "",
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from bigdl_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     S = slice_axis_size(mesh)
     in_specs = jax.tree.map(lambda _: P(SLICE_AXIS), acc)

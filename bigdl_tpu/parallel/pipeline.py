@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from bigdl_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from bigdl_tpu.parallel.mesh import PIPE_AXIS
 

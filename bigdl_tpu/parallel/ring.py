@@ -80,7 +80,7 @@ def ring_self_attention(mesh: Mesh, q, k, v, *, causal: bool = False,
     """Host-level entry: shards (B, H, T, d) q/k/v over `seq_axis` along T
     (and batch over 'data' when present) and runs :func:`ring_attention`.
     """
-    from bigdl_tpu.utils.compat import shard_map
+    from jax import shard_map
     from bigdl_tpu.parallel.mesh import DATA_AXIS
 
     batch = DATA_AXIS if DATA_AXIS in mesh.axis_names else None

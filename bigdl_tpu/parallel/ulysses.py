@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from bigdl_tpu.utils.compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 
 from bigdl_tpu.parallel.ring import SEQ_AXIS
 

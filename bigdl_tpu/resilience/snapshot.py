@@ -132,10 +132,8 @@ class AsyncCheckpointer:
         writer read the LIVE buffers directly — only safe when the
         caller's train step does NOT donate them (the shard references
         held by the plan keep the buffers alive; a donating step would
-        invalidate them mid-read). The trainers pass their donation flag
-        (DistriOptimizer skips donation on old-jax GSPMD —
-        utils/compat.SUPPORTS_SHARDED_DONATION — and then the snapshot
-        stall drops to the piece-plan build alone)."""
+        invalidate them mid-read). Both trainers donate, so they keep
+        the default."""
         if self.async_mode:
             # buffer B (async dispatch) while buffer A's write drains
             if clone:

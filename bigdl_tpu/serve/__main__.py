@@ -366,7 +366,8 @@ def _router_main(args, replicas: int) -> int:
     from bigdl_tpu.serve import net as _net
     from bigdl_tpu.serve import router as _router
     procs, urls = _router.launch_replicas(
-        replicas, _child_cli_args(args))
+        replicas, _child_cli_args(args),
+        ready_timeout_s=args.replica_ready_s)
     front = None
     try:
         backend = _router.ReplicaRouter(urls)
@@ -468,6 +469,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="with --http: spawn N replica processes and "
                          "front them with the ReplicaRouter "
                          "(BIGDL_TPU_SERVE_REPLICAS)")
+    ap.add_argument("--replica-ready-s", type=float, default=600.0,
+                    help="with --replicas: seconds the launcher waits "
+                         "for every replica's READY line (start-up and "
+                         "compilation included)")
     ap.add_argument("--smoke", action="store_true",
                     help="self-drive concurrent clients, print one JSON "
                          "summary, exit (CI probe)")
@@ -486,8 +491,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             # jax — each replica subprocess owns a full engine.
             return _router_main(args, replicas)
 
-    from bigdl_tpu.utils.platform import force_cpu_if_requested
-    force_cpu_if_requested()
     import jax
     from bigdl_tpu.serve.engine import ServeEngine
 
@@ -556,4 +559,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    # persistent XLA cache at JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax_cache: a setting, not a backend — the --replicas
+    # parent still never initialises one (docs/compile_cache.md)
+    from bigdl_tpu import compilecache
+    compilecache.enable()
     sys.exit(main())

@@ -49,8 +49,8 @@ returns them to the free list; admission refuses with a block-level
 `CapacityError` capacity report when a request can never fit the pool.
 On top of the block table sits the **prefix cache**: whole prompt
 blocks finished by prefill are published under a chained token-hash
-key (stage-at-admit / commit-as-the-frontier-passes — compilecache's
-staging discipline applied to KV), so N requests sharing a system
+key (stage-at-admit / commit-as-the-frontier-passes), so N requests
+sharing a system
 prompt pay its prefill once; entries are refcounted, copy-on-write
 never triggers (matching is block-granular, the divergence block is
 always private), and unreferenced entries are retained up to a cap,
@@ -177,9 +177,8 @@ class PrefixCache:
     block-granular, so the divergence block is always private and
     copy-on-write never has to copy.
 
-    Lifecycle (the compilecache staging/commit discipline): a request
-    STAGES its chain keys at admission; as its prefill frontier passes
-    the end of block j the block is COMMITTED — published with refs=1
+    Lifecycle: a request STAGES its chain keys at admission; as its
+    prefill frontier passes the end of block j the block is COMMITTED — published with refs=1
     (the committer's own reference). Later requests `take()` committed
     runs (incref). Retire decrefs; at refs==0 the entry stays CACHED
     (evictable) up to `cap` unreferenced blocks — beyond it, and
@@ -350,15 +349,20 @@ class DecodeEntry:
         self.mesh = mesh
         self.num_slots = int(num_slots if num_slots is not None
                              else config.get("SERVE_DECODE_SLOTS"))
-        self.max_seq_len = int(max_seq_len if max_seq_len is not None
-                               else config.get("SERVE_MAX_SEQ_LEN"))
+        n_pos = getattr(model, "n_positions", None)
+        if max_seq_len is None:
+            # the default follows the model: a slot cache longer than
+            # the position table could never be used
+            max_seq_len = config.get("SERVE_MAX_SEQ_LEN")
+            if n_pos is not None:
+                max_seq_len = min(max_seq_len, n_pos)
+        self.max_seq_len = int(max_seq_len)
         self.prefill_chunk = int(
             prefill_chunk if prefill_chunk is not None
             else config.get("SERVE_PREFILL_CHUNK"))
         if self.num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got "
                              f"{self.num_slots}")
-        n_pos = getattr(model, "n_positions", None)
         if n_pos is not None and self.max_seq_len > n_pos:
             raise ValueError(
                 f"max_seq_len {self.max_seq_len} > the model's "
@@ -654,7 +658,10 @@ class DecodeEntry:
         if exe is not None:
             try:
                 return exe(*args)
-            except Exception:  # noqa: BLE001 — one-shot fallback
+            except (TypeError, ValueError):
+                # an argument-spec mismatch, raised before the caches
+                # are donated; a device error is not caught: the donated
+                # buffers are gone and a second try would hide it
                 log.warning("serve[%s]: decode prefill%d AOT executable "
                             "rejected live inputs; falling back to jit",
                             self.name, C)
@@ -673,7 +680,7 @@ class DecodeEntry:
         if self._aot_decode is not None:
             try:
                 return self._aot_decode(*args)
-            except Exception:  # noqa: BLE001 — one-shot fallback
+            except (TypeError, ValueError):   # see run_prefill
                 log.warning("serve[%s]: decode-step AOT executable "
                             "rejected live inputs; falling back to jit",
                             self.name)

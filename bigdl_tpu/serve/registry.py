@@ -200,7 +200,7 @@ class ModelEntry:
         if aot is not None:
             try:
                 return aot(p, s, xs, valid)
-            except Exception:  # noqa: BLE001 — one-shot fallback
+            except (TypeError, ValueError):  # spec mismatch, not a device error
                 log.warning("serve[%s]: AOT executable for bucket %d "
                             "rejected live inputs; falling back to jit",
                             self.name, xs.shape[0])

@@ -463,14 +463,21 @@ def _iter_sse(resp):
 
 # ------------------------------------------------------ replica launcher
 def launch_replicas(n: int, cli_args: Sequence[str], *,
-                    env: Optional[dict] = None,
-                    ready_timeout_s: float = 120.0):
+                    ready_timeout_s: float,
+                    env: Optional[dict] = None):
     """Spawn `n` `python -m bigdl_tpu.serve --http` replica processes
-    (ephemeral ports) and wait for each one's READY line. Returns
-    `(procs, urls)`; pair with :func:`stop_replicas`. Used by the CLI
-    `--replicas` mode, bench.py serve_net, and the failover tests —
-    the multihost_worker subprocess launch pattern."""
+    (ephemeral ports) and wait up to `ready_timeout_s` — the caller's
+    budget for start-up AND compilation, all replicas together — for
+    each one's READY line. Returns `(procs, urls)`; pair with
+    :func:`stop_replicas`. Used by the CLI `--replicas` mode, bench.py
+    serve_net, and the failover tests.
+
+    A replica runs on the platform the parent was given (its
+    environment plus `env`; nothing here picks a backend) and writes
+    to the parent's stderr, so one that cannot get a device, crashes or
+    is still compiling says so where the operator is looking."""
     import os
+    import select
     import subprocess
     import sys
     procs, urls = [], []
@@ -478,19 +485,21 @@ def launch_replicas(n: int, cli_args: Sequence[str], *,
         for i in range(n):
             cmd = [sys.executable, "-m", "bigdl_tpu.serve", "--http",
                    "--http-port", "0", *cli_args]
-            e = dict(os.environ)
-            e.update(env or {})
-            e.setdefault("JAX_PLATFORMS", "cpu")
             procs.append(subprocess.Popen(
-                cmd, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-                stdin=subprocess.PIPE, env=e, text=True))
+                cmd, stdout=subprocess.PIPE, stdin=subprocess.PIPE,
+                env={**os.environ, **(env or {})}, text=True))
         deadline = time.monotonic() + ready_timeout_s
         for i, p in enumerate(procs):
-            line = p.stdout.readline()
-            if time.monotonic() > deadline or not line:
+            # READY is the child's first stdout line, printed whole
+            ready, _, _ = select.select(
+                [p.stdout], [], [], max(0.0, deadline - time.monotonic()))
+            line = p.stdout.readline() if ready else ""
+            if not line:
                 raise RuntimeError(
-                    f"replica {i} never printed READY (rc="
-                    f"{p.poll()})")
+                    f"replica {i} printed no READY line within "
+                    f"{ready_timeout_s:g}s (rc={p.poll()}; rc=None "
+                    f"means still starting or compiling) — its own "
+                    f"messages are on stderr")
             info = json.loads(line)
             if not info.get("ready"):
                 raise RuntimeError(f"replica {i} bad READY: {info}")

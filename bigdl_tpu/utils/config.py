@@ -41,15 +41,11 @@ def _register(name, default, parse, doc):
     _REGISTRY[name] = Knob(name, default, parse, doc)
 
 
-# reference: bigdl.localMode / bigdl.coreNumber — here device selection
-_register("FORCE_CPU", False, _bool,
-          "Run on host CPU even when a TPU plugin is present "
-          "(utils/platform.py; reference: bigdl.localMode)")
 _register("SEED", 1, int,
           "Global default RNG seed for trainers "
           "(reference: RandomGenerator defaults)")
 _register("COMPUTE_DTYPE", "", str,
-          "Forward/backward compute dtype for the distributed trainer: "
+          "Forward/backward compute dtype of the trainers: "
           "'' (fp32) or 'bfloat16' (reference: FP16 wire compression, "
           "parameters/FP16CompressedTensor.scala — bf16 is the TPU form)")
 _register("PREFETCH_SIZE", 2, int,
@@ -197,17 +193,6 @@ _register("RUN_ID", "", str,
           "Run id stamped into log prefixes, traces, and JSONL records; "
           "set the same value on every host of a multihost job "
           "(utils/runtime.py; '' derives one per process)")
-_register("COMPILE_CACHE", "", str,
-          "Persistent XLA compilation cache root directory "
-          "(compilecache/cache.py): jitted programs are staged per "
-          "process and published with atomic renames, so a second run "
-          "of the same config skips the XLA compile entirely. '' "
-          "disables. CLI: python -m bigdl_tpu.compilecache {stats,clear}")
-_register("COMPILE_CACHE_MIN_COMPILE_S", 0.0, float,
-          "Only persist programs whose XLA compile took at least this "
-          "many seconds (maps to jax_persistent_cache_min_compile_time_"
-          "secs; 0.0 caches everything — the default, so tiny step "
-          "programs warm too)")
 _register("PRECOMPILE", False, _bool,
           "AOT warmup: trainers call precompile() at the top of "
           "optimize(), compiling the step/eval programs from shape specs "
@@ -230,9 +215,9 @@ _register("AUTOTUNE", False, _bool,
           "behavior. CLI: python -m bigdl_tpu.kernels {tune,stats,clear}")
 _register("AUTOTUNE_CACHE", "", str,
           "Autotune table root directory. '' derives "
-          "<BIGDL_TPU_COMPILE_CACHE>/autotune when the compile cache is "
-          "configured (the table lives next to the XLA cache, same "
-          "atomic-publish discipline), else the table is in-memory only "
+          "<compile cache dir>/autotune when the persistent compile "
+          "cache is on (compilecache.enable() or "
+          "JAX_COMPILATION_CACHE_DIR), else the table is in-memory only "
           "for this process")
 _register("SERVE_MAX_BATCH", 256, int,
           "Online serving: the largest shape bucket (rows) the engine "
@@ -530,16 +515,6 @@ _register("SANITIZE_HOLD_MS", 250.0, float,
           "lock held longer than this many milliseconds files a "
           "long-hold report (a sleeping/IO-bound lock holder "
           "serializes every other participant)")
-_register("BENCH_LOCK_FILE", "/tmp/bigdl_tpu_bench.lock", str,
-          "Lockfile serializing bench.py against tools/tpu_watch.sh so "
-          "the harness cannot pollute the CPU trend series (ADVICE r5 #5)")
-_register("BENCH_LOCK_WAIT_S", 600, int,
-          "Max seconds bench.py waits for the bench lockfile before "
-          "proceeding anyway (annotated in the JSON)")
-_register("BENCH_CONTENDED_LOADAVG", 1.5, float,
-          "loadavg_1m threshold above which bench.py marks its JSON "
-          "record {contended: true} — a loaded host masquerades as a "
-          "code regression otherwise (ROUND5_NOTES.md r4→r3 scare)")
 
 
 def get(name: str):

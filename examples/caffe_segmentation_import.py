@@ -5,7 +5,7 @@ Slice/Eltwise-with-coefficients fusion, Tile, NCHW Reshape — then run it,
 quantize the conv trunk to int8, and round-trip the net through our own
 prototxt+caffemodel writer.
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/caffe_segmentation_import.py
+    JAX_PLATFORMS=cpu python examples/caffe_segmentation_import.py
 """
 
 import os
@@ -13,10 +13,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402

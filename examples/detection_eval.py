@@ -4,17 +4,13 @@ optim/ValidationMethod.scala:230-756 MeanAveragePrecision family):
 run the MaskRCNN-style inference model on a synthetic image, then score
 detections with VOC and COCO-style mAP.
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/detection_eval.py
+    JAX_PLATFORMS=cpu python examples/detection_eval.py
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402

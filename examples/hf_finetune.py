@@ -3,7 +3,7 @@ GPT-2 onto this framework's primitives, verify logits parity against the
 torch forward, fine-tune it on a tiny corpus with the standard Optimizer
 facade, and save/reload through the durable model format.
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/hf_finetune.py
+    JAX_PLATFORMS=cpu python examples/hf_finetune.py
 
 (The model is random-init because this environment has no network; with
 downloads available, `GPT2LMHeadModel.from_pretrained("gpt2")` drops in
@@ -14,10 +14,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import numpy as np                                            # noqa: E402
 import torch                                                  # noqa: E402

@@ -3,7 +3,7 @@ Caffe-prototxt models, then fine-tune one of them (reference workflows:
 pyspark/bigdl/contrib/onnx/onnx_loader.py, pyspark/bigdl/keras/converter.py,
 utils/tf/TensorflowLoader.scala, utils/caffe/CaffeLoader.scala).
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/import_models.py
+    JAX_PLATFORMS=cpu python examples/import_models.py
 """
 
 import json
@@ -12,10 +12,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import h5py                                                   # noqa: E402
 import jax                                                    # noqa: E402

@@ -5,7 +5,7 @@ models/rnn/ PTBWordLM; generation via nn/SequenceBeamSearch.scala).
 Hermetic: a synthetic Markov corpus stands in for the PTB download
 (zero-egress image); pass --model transformer for the attention variant.
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/language_model.py
+    JAX_PLATFORMS=cpu python examples/language_model.py
 """
 
 import argparse
@@ -13,10 +13,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import numpy as np                                           # noqa: E402
 import jax                                                   # noqa: E402

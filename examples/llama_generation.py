@@ -5,7 +5,7 @@ forward, beam-generate with and without the grouped-KV cache (identical
 outputs, O(L) vs O(L^2) per step), and fine-tune through the imported
 weights.
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/llama_generation.py
+    JAX_PLATFORMS=cpu python examples/llama_generation.py
 
 (Random-init weights — no network in this environment; with downloads,
 `LlamaForCausalLM.from_pretrained(...)` drops in unchanged.)"""
@@ -14,10 +14,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import numpy as np                                            # noqa: E402
 import torch                                                  # noqa: E402

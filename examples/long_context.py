@@ -4,7 +4,7 @@ T/N of the sequence), pipeline-parallel 1F1B with the cut-cross-entropy
 fused head, and the flash-attention kernel as a drop-in MHA backend.
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-    BIGDL_TPU_FORCE_CPU=1 python examples/long_context.py
+    JAX_PLATFORMS=cpu python examples/long_context.py
 """
 
 import os
@@ -13,10 +13,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import numpy as np                                            # noqa: E402
 import jax                                                    # noqa: E402

@@ -3,7 +3,7 @@
 DLImageTransformer, DLClassifier over Spark DataFrames; here columnar
 frames, no Spark).
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/ml_pipeline.py
+    JAX_PLATFORMS=cpu python examples/ml_pipeline.py
 """
 
 import os
@@ -11,10 +11,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import numpy as np                                           # noqa: E402
 import bigdl_tpu.nn as nn                                    # noqa: E402

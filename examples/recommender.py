@@ -6,17 +6,13 @@ Hermetic: synthetic MovieLens-shaped ratings with latent block structure;
 the NCF tower must learn the user-group x item-group preference and rank
 held-out positives above sampled negatives.
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/recommender.py
+    JAX_PLATFORMS=cpu python examples/recommender.py
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import numpy as np                                           # noqa: E402
 import jax                                                   # noqa: E402

@@ -2,17 +2,13 @@
 optim/PredictionService.scala:56-66 — a blocking-queue pool of model
 instances serving concurrent requests).
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/serving.py
+    JAX_PLATFORMS=cpu python examples/serving.py
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 from concurrent.futures import ThreadPoolExecutor            # noqa: E402
 import jax                                                   # noqa: E402

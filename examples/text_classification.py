@@ -2,7 +2,7 @@
 (reference: example/textclassification — GloVe embeddings + CNN; here
 hermetic synthetic data + trained embeddings).
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/text_classification.py
+    JAX_PLATFORMS=cpu python examples/text_classification.py
 """
 
 import numpy as np
@@ -11,10 +11,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import jax                                                   # noqa: E402
 import bigdl_tpu.nn as nn                                    # noqa: E402

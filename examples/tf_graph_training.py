@@ -6,7 +6,7 @@ A frozen GraphDef (here produced by our own exporter standing in for a
 TF-authored .pb — zero-egress image) is loaded by TFTrainingSession and
 fine-tuned end-to-end.
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/tf_graph_training.py
+    JAX_PLATFORMS=cpu python examples/tf_graph_training.py
 """
 
 import os
@@ -14,10 +14,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import numpy as np                                           # noqa: E402
 import jax                                                   # noqa: E402

@@ -3,17 +3,13 @@
 trees with GloVe embeddings; here synthetic trees + learned embeddings so
 the example runs hermetically).
 
-    BIGDL_TPU_FORCE_CPU=1 python examples/tree_lstm_sentiment.py
+    JAX_PLATFORMS=cpu python examples/tree_lstm_sentiment.py
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
 
 import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402

@@ -1,0 +1,161 @@
+"""Compile-only tests for the chip: the TPU's compiler is installed here
+and compiles for a v5e that is DESCRIBED, not attached
+(`jax.experimental.topologies`). The kernels and the decode step of the
+main path are lowered at the widths chip_smoke.py runs them at, so a
+slice off the tiling, a kernel over its fast-memory budget or a program
+over 16 GB of HBM fails here, on the CPU, before it costs chip time.
+Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a fixture of THIS file (never at import,
+in a skipif, a parametrize or conftest.py): only one process may hold the
+TPU library, and every xdist worker imports every test file."""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16 * 1024 ** 3        # one v5e chip
+
+# GPT-2 XL (Radford et al. 2019): the width chip_smoke.py serves
+XL = dict(vocab=50257, positions=1024, d=1600, heads=25, layers=48)
+RESNET50_PARAMS = 25_557_032
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    # read by the TPU library as it loads: no compiler logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:              # noqa: BLE001 — no libtpu here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A sharding on one described chip, with the persistent compile
+    cache off for the module: an entry compiled for a described device
+    cannot be read back without one, and would only warn."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert used < HBM_BYTES, m
+    return used
+
+
+def test_flash_attention_fwd_bwd_at_gpt2_xl_heads(one_chip):
+    from bigdl_tpu.kernels.flash_attention import flash_attention
+    qkv = _sds(one_chip, (4, XL["heads"], 1024, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    fwd = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True)
+                  ).lower(qkv, qkv, qkv).compile()
+    assert "tpu_custom_call" in fwd.as_text()       # the Pallas kernel
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))
+                  ).lower(qkv, qkv, qkv).compile()
+    _fits(fwd)
+    _fits(bwd)
+
+
+def test_cut_cross_entropy_fwd_bwd_at_gpt2_vocab(one_chip):
+    from bigdl_tpu.kernels.cut_cross_entropy import cut_cross_entropy
+    h = _sds(one_chip, (4096, XL["d"]), jnp.bfloat16)
+    w = _sds(one_chip, (XL["vocab"], XL["d"]), jnp.bfloat16)
+    labels = _sds(one_chip, (4096,), jnp.int32)
+
+    def loss(h, w, labels):
+        return cut_cross_entropy(h, w, labels).mean()
+
+    fwd = jax.jit(loss).lower(h, w, labels).compile()
+    assert "tpu_custom_call" in fwd.as_text()
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1))
+                  ).lower(h, w, labels).compile()
+    assert "tpu_custom_call" in bwd.as_text()
+    _fits(fwd)
+    _fits(bwd)
+
+
+def test_int8_matmul_at_gpt2_xl_ffn(one_chip):
+    from bigdl_tpu.kernels.quantized_matmul import int8_matmul
+    m, k, n = 8, XL["d"], 4 * XL["d"]
+    c = jax.jit(int8_matmul).lower(
+        _sds(one_chip, (m, k), jnp.int8), _sds(one_chip, (k, n), jnp.int8),
+        _sds(one_chip, (m, 1), jnp.float32),
+        _sds(one_chip, (1, n), jnp.float32)).compile()
+    assert "tpu_custom_call" in c.as_text()
+    _fits(c)
+
+
+def test_fused_update_at_resnet50_parameter_count(one_chip):
+    """SGD+momentum, the trainer of chip_smoke.py, through the flat
+    Pallas engine — the branch `layout='auto'` takes on a TPU and no CPU
+    test reaches without interpret mode."""
+    from bigdl_tpu.kernels import fused_update
+    from bigdl_tpu.optim.method import SGD
+    kind, hyper = fused_update.describe(SGD(0.1, momentum=0.9))
+    vec = _sds(one_chip, (RESNET50_PARAMS,), jnp.float32)
+    scalar = _sds(one_chip, (), jnp.float32)
+
+    def update(p, g, velocity, lr):
+        return fused_update.flat_update(kind, hyper, p, g, (velocity,),
+                                        lr, jnp.int32(3), use_pallas=True)
+
+    c = jax.jit(update, donate_argnums=(0, 2)).lower(
+        vec, vec, vec, scalar).compile()
+    assert "tpu_custom_call" in c.as_text()
+    _fits(c)
+
+
+def test_gpt2_xl_paged_decode_step_fits_one_chip(one_chip, monkeypatch):
+    """The decode program DecodeEntry jits, all 48 layers, at the slots
+    and KV pool chip_smoke.py registers — with the cache donation that
+    `_build` turns on only off the CPU. The compiler counts arguments +
+    temporaries against the chip's HBM and refuses what does not fit."""
+    import chip_smoke
+    from bigdl_tpu.interop.huggingface import GPT2LM
+    from bigdl_tpu.serve.decode import DecodeEntry
+    model = GPT2LM(XL["vocab"], XL["positions"], XL["d"], XL["heads"],
+                   XL["layers"], eos_id=XL["vocab"] - 1)
+    params, _ = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), params)
+    # steer the one platform question _build asks; nothing else is patched
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    entry = DecodeEntry("xl", model, params, **chip_smoke.SERVE_KV)
+    monkeypatch.undo()
+    assert entry.paged
+    S = entry.num_slots
+    caches = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda p: model.make_paged_slot_caches(
+            p, entry.pool_blocks, entry.kv_block), params))
+    vec = _sds(one_chip, (S,), np.int32)
+    c = entry._jit_decode.lower(
+        params, caches, vec, vec, _sds(one_chip, (S,), np.bool_),
+        _sds(one_chip, (S, entry.blocks_per_slot), np.int32)).compile()
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes >= entry.kv_cache_bytes   # pool donated
+    _fits(c)

@@ -1,9 +1,11 @@
 """Compile-latency subsystem (bigdl_tpu/compilecache/ —
-docs/compile_cache.md): persistent-cache publish/seed/sweep discipline +
-CLI, AOT precompile() on both trainers, single-variant shape bucketing
-(padded valid-mask tails, epoch lengths % K in {0, 1, K-1}), and the
-retrace-hygiene contract that resume/retry reuses built step programs
-(compile count stays flat across a crash-at-step-7 resume)."""
+docs/compile_cache.md): where the persistent cache lives (the
+environment's JAX_COMPILATION_CACHE_DIR, else the fixed
+<checkout>/.jax_cache) + CLI, AOT precompile() on both trainers,
+single-variant shape bucketing (padded valid-mask tails, epoch lengths
+% K in {0, 1, K-1}), and the retrace-hygiene contract that resume/retry
+reuses built step programs (compile count stays flat across a
+crash-at-step-7 resume)."""
 
 import os
 import subprocess
@@ -17,7 +19,6 @@ import jax.numpy as jnp
 
 import bigdl_tpu.nn as nn
 from bigdl_tpu import compilecache, observe
-from bigdl_tpu.compilecache import cache as cc
 from bigdl_tpu.dataset import ArrayDataSet
 from bigdl_tpu.optim.local import Optimizer
 from bigdl_tpu.optim.method import SGD, Adam
@@ -28,16 +29,33 @@ from bigdl_tpu.resilience import faults
 R = np.random.RandomState(0)
 X = R.randn(128, 6).astype(np.float32)
 Y = (X[:, 0] > 0).astype(np.int32)
+ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MIN_SECS = "jax_persistent_cache_min_compile_time_secs"
 
 
 @pytest.fixture
-def clean_cache():
-    """Detach any process-wide cache state before AND after each test."""
+def clean_cache(monkeypatch):
+    """Detach any process-wide cache state before AND after each test;
+    inside, even the tiniest program is persisted."""
     compilecache.disable()
     faults.configure("")
+    monkeypatch.delenv(ENV, raising=False)
+    min_secs = getattr(jax.config, MIN_SECS)
+    jax.config.update(MIN_SECS, 0.0)
     yield
     compilecache.disable()
+    jax.config.update(MIN_SECS, min_secs)
     faults.configure("")
+
+
+@pytest.fixture
+def env_cache(tmp_path, monkeypatch, clean_cache):
+    """The cache placed from outside, the way a caller places it."""
+    root = str(tmp_path / "cc")
+    monkeypatch.setenv(ENV, root)
+    assert compilecache.enable() == root
+    return root
 
 
 def _model():
@@ -70,110 +88,155 @@ def _assert_trees_equal(a, b):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
 
 
+def _entries(d):
+    return sorted(n for n in os.listdir(d) if n.endswith("-cache")) \
+        if os.path.isdir(d) else []
+
+
 # ------------------------------------------------------ cache mechanics
-def test_publish_is_atomic_pairs_and_stats(tmp_path, clean_cache):
-    """Fresh compiles land in the per-process staging dir; sync()
-    publishes them to the root as complete (-atime, -cache) pairs —
-    the -cache file's appearance IS the commit."""
+def test_env_dir_is_the_only_dir_ever_set(tmp_path, monkeypatch,
+                                          clean_cache):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is that directory, every
+    entry lands there, and nothing points jax anywhere else."""
     root = str(tmp_path / "cc")
-    staging = compilecache.enable(root)
-    assert staging and os.path.isdir(staging)
-    f = jax.jit(lambda x: x * 2.0 + 1.0)    # fresh fn -> fresh compile
-    f(jnp.ones((17,)))
-    published = compilecache.sync()
-    assert published >= 1
-    s = compilecache.stats(root)
-    assert s["entries"] == published
-    for name in os.listdir(root):
-        if name.endswith("-cache"):
-            key = name[: -len("-cache")]
-            assert os.path.exists(os.path.join(root, key + "-atime")), name
-            assert ".tmp." not in name
-    # idempotent: nothing new to publish
-    assert compilecache.sync() == 0
+    monkeypatch.setenv(ENV, root)
+    before = _entries(os.path.join(CHECKOUT, ".jax_cache"))
+    seen = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            seen.append(value)
+        return real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    assert compilecache.cache_dir() == root
+    assert compilecache.enable() == root
+    assert compilecache.enable() == root    # idempotent: no second update
+    assert jax.config.jax_compilation_cache_dir == root
+    assert compilecache.enabled()
+    jax.jit(lambda x: x * 2.0 + 1.0)(jnp.ones((17,)))
+    s = compilecache.stats()
+    assert s["root"] == root and s["entries"] >= 1
+    assert s["entries"] == len(_entries(root))
+    assert seen == [root]
+    assert _entries(os.path.join(CHECKOUT, ".jax_cache")) == before
+    assert os.listdir(tmp_path) == ["cc"]   # no sibling staging dir
 
 
-def test_reenable_seeds_staging_from_root(tmp_path, clean_cache):
-    root = str(tmp_path / "cc")
-    compilecache.enable(root)
+def test_unset_env_is_the_fixed_checkout_dir(clean_cache):
+    """No environment variable: <checkout>/.jax_cache, a path with no
+    process id, temporary name or time in it — and entries land there."""
+    want = os.path.join(CHECKOUT, ".jax_cache")
+    assert compilecache.cache_dir() == want
+    assert compilecache.enable() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert str(os.getpid()) not in want and "tmp" not in want.lower()
+    before = set(_entries(want))
+
+    def unique_fn_5519(x):
+        return (x * 1.75 - 0.5).sum()
+    jax.jit(unique_fn_5519)(jnp.arange(33, dtype=jnp.float32))
+    new = [n for n in _entries(want) if n not in before]
+    for n in new:                           # leave the checkout as found
+        os.unlink(os.path.join(want, n))
+    assert any("unique_fn_5519" in n for n in new), new
+
+
+def test_disable_detaches_and_reenable_hits(env_cache):
+    """disable() leaves the entries on disk; a later enable() — a
+    restarted process, here a fresh jit of the same program — reads
+    them back instead of compiling."""
+    observe.ensure_started()
     jax.jit(lambda x: x - 3.5)(jnp.ones((11,)))
-    compilecache.disable()                  # publishes + removes staging
-    n = compilecache.stats(root)["entries"]
+    n = compilecache.stats(env_cache)["entries"]
     assert n >= 1
-    staging = compilecache.enable(root)
-    seeded = [e for e in os.listdir(staging) if e.endswith("-cache")]
-    assert len(seeded) == n
+    compilecache.disable()
+    assert not compilecache.enabled()
+    assert jax.config.jax_compilation_cache_dir is None
+    assert compilecache.stats(env_cache)["entries"] == n
+    assert compilecache.enable() == env_cache
+    hits = observe.counter("jit/cache_hits").value
+    jax.jit(lambda x: x - 3.5)(jnp.ones((11,)))
+    assert observe.counter("jit/cache_hits").value == hits + 1
+    assert compilecache.stats(env_cache)["entries"] == n
 
 
-def test_dead_staging_dir_adopted_and_swept(tmp_path, clean_cache):
-    """A staging dir whose owner pid is gone is adopted (its finished
-    entries committed to the root) and removed on the next enable()."""
-    root = tmp_path / "cc"
-    dead = root / ".staging-p0-999999999"   # pid far beyond pid_max
-    dead.mkdir(parents=True)
-    (dead / "jit_ghost-abc123-cache").write_bytes(b"executable-bytes")
-    compilecache.enable(str(root))
-    assert not dead.exists()
-    assert (root / "jit_ghost-abc123-cache").exists()
-    assert (root / "jit_ghost-abc123-atime").exists()
-    s = compilecache.stats(str(root))
-    assert s["programs"].get("jit_ghost") == 1
+def test_torn_entry_warns_and_recompiles(env_cache):
+    """What the deleted per-process staging guarded against: a reader
+    meets a half-written entry. jax refuses it, warns, and compiles —
+    a lost hit, never a wrong program."""
+    def make():                 # a fresh function object = a fresh jit
+        def unique_fn_9142(x):
+            return x * 0.25 + 9.0
+        return unique_fn_9142
+    want = np.asarray(jax.jit(make())(jnp.ones((13,))))
+    (name,) = [n for n in _entries(env_cache) if "unique_fn_9142" in n]
+    path = os.path.join(env_cache, name)
+    blob = open(path, "rb").read()
+    with open(path, "wb") as f:
+        f.write(blob[: len(blob) // 2])
+    with pytest.warns(UserWarning, match="Error reading persistent "
+                                         "compilation cache entry"):
+        got = np.asarray(jax.jit(make())(jnp.ones((13,))))
+    np.testing.assert_array_equal(got, want)
 
 
-def test_stats_and_clear_cli(tmp_path, clean_cache, capsys):
+def test_stats_and_clear_cli(env_cache, capsys):
     from bigdl_tpu.compilecache.__main__ import main
-    root = str(tmp_path / "cc")
-    compilecache.enable(root)
     jax.jit(lambda x: x / 7.0)(jnp.ones((5,)))
     compilecache.disable()
-    assert main(["stats", root]) == 0
+    assert main(["stats", env_cache]) == 0
     out = capsys.readouterr().out
-    assert "cache root:" in out and "committed:" in out
-    assert main(["stats", root, "--json"]) == 0
+    assert "cache dir:" in out and "entries:" in out
+    assert main(["stats", "--json"]) == 0   # DIR defaults to the env's
     import json
     s = json.loads(capsys.readouterr().out)
-    assert s["entries"] >= 1
-    assert main(["clear", root]) == 0
+    assert s["root"] == env_cache and s["entries"] >= 1
+    assert main(["clear", env_cache]) == 0
     assert "cleared" in capsys.readouterr().out
-    assert compilecache.stats(root)["entries"] == 0
-    assert [n for n in os.listdir(root)] == []
+    assert compilecache.stats(env_cache)["entries"] == 0
+    assert [n for n in os.listdir(env_cache)] == []
 
 
 @pytest.mark.tier2
 def test_warm_process_hits_persistent_cache(tmp_path, clean_cache):
-    """Two processes, same cache root: the second deserializes instead
-    of compiling (jax reports the retrieval through its monitoring
-    events — the jit/cache_hit_compiles counter observe keeps)."""
+    """Two processes given the same directory from outside: jax itself
+    reads the variable, the second process deserializes instead of
+    compiling (the jit/cache_hit_compiles counter observe keeps), and
+    neither writes anywhere else."""
     child = (
         "import os, sys\n"
-        "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
         "import jax, jax.numpy as jnp\n"
         "from bigdl_tpu import compilecache, observe\n"
         "observe.ensure_started()\n"
-        "compilecache.enable(sys.argv[1])\n"
+        "assert compilecache.enable() == sys.argv[1]\n"
+        "assert jax.config.jax_compilation_cache_dir == sys.argv[1]\n"
         "def unique_fn_7731(x):\n"
         "    return (x * 3.25 + 17.0).sum() - 0.125\n"
         "jax.jit(unique_fn_7731)(jnp.arange(4096, dtype=jnp.float32))\n"
-        "compilecache.sync()\n"
         "print('HITS', int(observe.counter('jit/cache_hit_compiles')"
         ".value))\n")
     root = str(tmp_path / "cc")
-    env = {**os.environ, "XLA_FLAGS": ""}
+    before = _entries(os.path.join(CHECKOUT, ".jax_cache"))
+    env = {**os.environ, "XLA_FLAGS": "", "JAX_PLATFORMS": "cpu",
+           ENV: root, "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
     outs = []
     for _ in range(2):
         r = subprocess.run([sys.executable, "-c", child, root],
                            capture_output=True, text=True, env=env,
-                           timeout=300)
+                           cwd=CHECKOUT, timeout=300)
         assert r.returncode == 0, r.stderr
         outs.append(r.stdout)
     assert compilecache.stats(root)["programs"].get("jit_unique_fn_7731") == 1
     assert "HITS 0" in outs[0]
     hits = int(outs[1].split("HITS")[1].strip().split()[0])
     assert hits >= 1, outs[1]
+    assert _entries(os.path.join(CHECKOUT, ".jax_cache")) == before
 
 
 # ------------------------------------------------------------ precompile
-def test_precompile_unfused_attaches_aot_and_costs(tmp_path, clean_cache):
+def test_precompile_unfused_attaches_aot_and_costs(clean_cache):
     opt = _opt(K=1, val=True)
     res = opt.precompile()
     assert "train_step" in res and "eval_step" in res
@@ -210,13 +273,12 @@ def test_precompile_knob_runs_automatically(clean_cache, monkeypatch):
     assert getattr(opt, "_precompiled", False)
 
 
-def test_single_variant_per_config_including_tail(tmp_path, clean_cache):
+def test_single_variant_per_config_including_tail(env_cache):
     """Acceptance: a fused run whose epochs END IN A TAIL (5 batches,
     K=4) compiles exactly ONE train-step program — the padded valid-mask
     super-batch serves full groups and tails alike. The persistent cache
     counts program variants by name."""
-    root = str(tmp_path / "cc")
-    compilecache.enable(root)
+    root = env_cache
     opt = _opt(n_rows=80, K=4)             # 5 batches/epoch: 4 + tail(1)
     opt.set_end_when(Trigger.max_epoch(2))
     opt.optimize()
@@ -225,14 +287,13 @@ def test_single_variant_per_config_including_tail(tmp_path, clean_cache):
     assert progs.get("jit_bigdl_fused_train_step") == 1, progs
 
 
-def test_precompile_distri_sharded_specs(tmp_path, clean_cache):
+def test_precompile_distri_sharded_specs(env_cache):
     """DistriOptimizer precompile: the AOT specs carry mesh shardings
     (TP params, ZeRO-1 slots, data-sharded super-batch), so the
     precompiled executable accepts the live sharded trees — and the run
     still compiles exactly one fused train-step variant."""
     from bigdl_tpu.parallel import DistriOptimizer, create_mesh
-    root = str(tmp_path / "cc")
-    compilecache.enable(root)
+    root = env_cache
     mesh = create_mesh(drop_trivial_axes=True)
     ds = ArrayDataSet(X[:80], Y[:80], 16, drop_last=True, shuffle=False)
     opt = DistriOptimizer(_model(), ds, nn.ClassNLLCriterion(),

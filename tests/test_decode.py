@@ -668,3 +668,18 @@ def test_cli_decode_smoke(capsys):
     assert rec["slots"] == 4
     assert rec["tokens"] >= rec["retired"] >= 6
     assert 0.0 < rec["slot_occupancy_mean"] <= 1.0
+
+
+def test_cli_decode_smoke_default_lengths(capsys):
+    """`python -m bigdl_tpu.serve --decode --smoke` as documented: with
+    no --max-seq-len the slot cache follows the demo model's 256
+    positions instead of outrunning them with the knob's 1024."""
+    from bigdl_tpu.serve.__main__ import main
+    from bigdl_tpu.utils import config
+    assert config.get("SERVE_MAX_SEQ_LEN") > 256
+    rc = main(["--decode", "--smoke", "--smoke-threads", "2",
+               "--smoke-requests", "2", "--max-new", "4",
+               "--prefill-chunk", "4"])
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0 and rec["errors"] == []
+    assert rec["requests_ok"] == rec["requests_sent"] == 4

@@ -32,8 +32,7 @@ def test_all_examples_enumerated():
 @pytest.mark.examples
 @pytest.mark.parametrize("name", EXAMPLES)
 def test_example_runs_clean(name, tmp_path):
-    env = dict(os.environ)
-    env["BIGDL_TPU_FORCE_CPU"] = "1"
+    env = dict(os.environ)          # conftest's JAX_PLATFORMS=cpu rides along
     # hermetic: examples that write (checkpoints, exports) go to tmp
     env.setdefault("TMPDIR", str(tmp_path))
     r = subprocess.run(

@@ -3,7 +3,7 @@ the /healthz /metrics /statusz /tracez endpoints served DURING a live
 optimize(), the step-time anomaly watchdog (baseline, sustained-regression
 incident, phase attribution, recovery), crash forensics bundles + the
 doctor CLI, percentile error bars of the log-bucket histograms, and the
-span-taxonomy doc-rot check."""
+span-catalogue doc-rot check."""
 
 import json
 import math
@@ -364,7 +364,7 @@ def test_serve_slo_from_snapshot(clean_plane):
     assert "serve:" in out and "m1" in out and "shed 1" in out
 
 
-# ------------------------------------------------- span-taxonomy doc rot
+# ------------------------------------------------- span-catalogue doc rot
 _NAME_CALL = re.compile(
     r'(?:counter|gauge|histogram|phase|span|instant)\(\s*(f?)"([^"]+)"')
 
@@ -375,7 +375,7 @@ def _emitted_names():
         for m in _NAME_CALL.finditer(p.read_text()):
             is_f, name = m.groups()
             if "/" not in name:
-                continue                 # ad-hoc/user names are not taxonomy
+                continue                 # ad-hoc/user names are not catalogued
             if is_f:
                 name = re.sub(r"\{[^}]*\}", "*", name)
                 name = re.sub(r"\*+", "*", name)
@@ -383,9 +383,9 @@ def _emitted_names():
     return names
 
 
-def test_span_taxonomy_documented():
+def test_span_catalogue_documented():
     """Every span/counter/gauge/histogram name emitted anywhere in the
-    codebase must appear in docs/observability.md — the taxonomy table
+    codebase must appear in docs/observability.md — the catalogue table
     cannot silently rot. F-string name segments are wildcarded
     (serve/<model>/latency_ms appears as serve/*/latency_ms)."""
     names = _emitted_names()

@@ -17,8 +17,8 @@ def test_config_defaults_and_env_override(monkeypatch):
     assert config.get("SEED") == 1
     monkeypatch.setenv("BIGDL_TPU_SEED", "42")
     assert config.get("SEED") == 42
-    monkeypatch.setenv("BIGDL_TPU_FORCE_CPU", "true")
-    assert config.get("FORCE_CPU") is True
+    monkeypatch.setenv("BIGDL_TPU_CHECK_SINGLETON", "true")
+    assert config.get("CHECK_SINGLETON") is True
     out = config.print_config()
     assert "BIGDL_TPU_SEED = 42 (set)" in out
     assert "BIGDL_TPU_FAILURE_RETRY_TIMES" in out
@@ -67,13 +67,11 @@ def test_config_knobs_are_wired(monkeypatch):
     monkeypatch.setenv("BIGDL_TPU_LOG_THROUGHPUT_EVERY", "5")
     opt2 = Optimizer(nn.Linear(2, 2), ds, nn.MSECriterion())
     assert opt2._log_every == 5
-    # FORCE_CPU honors false
-    monkeypatch.setenv("BIGDL_TPU_FORCE_CPU", "false")
-    from bigdl_tpu.utils import platform
-    monkeypatch.setenv("XLA_FLAGS", "")
-    assert platform.cpu_requested() is False
-    monkeypatch.setenv("BIGDL_TPU_FORCE_CPU", "1")
-    assert platform.cpu_requested() is True
+    # a bool knob honors false
+    monkeypatch.setenv("BIGDL_TPU_CHECK_SINGLETON", "false")
+    assert config.get("CHECK_SINGLETON") is False
+    monkeypatch.setenv("BIGDL_TPU_CHECK_SINGLETON", "1")
+    assert config.get("CHECK_SINGLETON") is True
 
 
 def test_optimize_with_retry_recovers(tmp_path, monkeypatch):

@@ -1,4 +1,4 @@
-"""Full-scale int8 accuracy evidence (VERDICT r4 item 6; reference claim:
+"""Full-scale int8 accuracy evidence (reference claim:
 whitepaper.md:192-196 "<0.1% accuracy drop on SSD/VGG16/VGG19"):
 VGG-16 at width_mult=1.0 / spatial=224 and ResNet-50 at 224, random-init
 + calibrated — the measurement is about QUANTIZATION error (fp32-vs-int8
@@ -15,8 +15,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested  # noqa: E402
 
 
 def measure(model, params, state, x, calib_x, weight_block=64):
@@ -58,7 +56,6 @@ def main():
     ap.add_argument("--calib", type=int, default=16)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
-    force_cpu_if_requested()
 
     import time
 

@@ -1,6 +1,6 @@
 """Assemble PERF_r{N}.json: the scaling + loader battery on the virtual
-8-device CPU mesh (re-run each round per VERDICT r4 weak #2 — substantial
-trainer/parallelism changes need refreshed plumbing-overhead numbers).
+8-device CPU mesh (re-run when trainer/parallelism changes need refreshed
+plumbing-overhead numbers).
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python tools/perf_battery.py --round 5
@@ -20,10 +20,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bigdl_tpu.utils.platform import force_cpu_if_requested  # noqa: E402
-
-force_cpu_if_requested()
 
 
 def main():
