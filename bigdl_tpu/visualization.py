@@ -281,7 +281,8 @@ class EventWriter:
         while not self._stop.is_set() or not self._q.empty():
             try:
                 ev = self._q.get(timeout=self.flush_secs)
-                self._fh.write(frame_record(ev))
+                if ev is not None:
+                    self._fh.write(frame_record(ev))
             except queue.Empty:
                 pass
             if self._q.empty():
@@ -289,6 +290,8 @@ class EventWriter:
 
     def close(self):
         self._stop.set()
+        self._q.put(None)       # wake the writer: it may be `flush_secs`
+        #                         into a wait on an empty queue
         self._thread.join(timeout=10)
         self._fh.flush()
         self._fh.close()

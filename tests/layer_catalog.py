@@ -20,6 +20,9 @@ Entry conventions:
   train_rng -> apply with training=True and a fixed rng (stochastic layers).
   post      -> map the raw output to comparable/differentiable arrays
                (e.g. SparseCOO.to_dense).
+  host      -> the layer takes or makes a SparseCOO on the host (numpy) and
+               cannot be traced: a sweep that jits its forwards runs these
+               eagerly.
 """
 
 from __future__ import annotations
@@ -90,7 +93,7 @@ class E:
     """One catalog entry."""
 
     def __init__(self, build, inputs, *, grad=True, ser=True,
-                 train_rng=False, post=None, kwargs=None):
+                 train_rng=False, post=None, kwargs=None, host=False):
         self.build = build
         self.inputs = inputs
         self.grad = grad
@@ -98,6 +101,7 @@ class E:
         self.train_rng = train_rng
         self.post = post
         self.kwargs = kwargs or {}
+        self.host = host
 
 
 _dense = lambda o: o.to_dense() if isinstance(o, SparseCOO) else o
@@ -156,14 +160,15 @@ MODULES = {
     "LookupTable": E(lambda: nn.LookupTable(11, 6),
                      lambda: (ints(11, 3, 4),)),
     "LookupTableSparse": E(lambda: nn.LookupTableSparse(16, 5),
-                           lambda: (sparse(3, 16, 4),)),
+                           lambda: (sparse(3, 16, 4),), host=True),
     "SparseLinear": E(lambda: nn.SparseLinear(16, 5),
-                      lambda: (sparse(3, 16, 4),)),
+                      lambda: (sparse(3, 16, 4),), host=True),
     "SparseJoinTable": E(lambda: nn.SparseJoinTable(),
                          lambda: (sparse(3, 8, 3), sparse(3, 6, 2, seed=1)),
-                         grad=False, post=_dense),
+                         grad=False, post=_dense, host=True),
     "DenseToSparse": E(lambda: nn.DenseToSparse(4),
-                       lambda: (x(3, 8),), grad=False, post=_dense),
+                       lambda: (x(3, 8),), grad=False, post=_dense,
+                       host=True),
     # ---- convolutions
     "SpatialConvolution": E(
         lambda: nn.SpatialConvolution(2, 3, 3, 3, pad_w=1, pad_h=1),

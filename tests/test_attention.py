@@ -101,7 +101,8 @@ def test_transformer_lm_forward_and_train():
                         num_layers=2, mode="lm")
     params, state = model.init(jax.random.PRNGKey(5))
     tokens = jnp.asarray(np.random.RandomState(6).randint(0, 50, (4, 12)))
-    logits, _ = model.apply(params, state, tokens)
+    # (forward and loss jitted: one program each, not one per eager op)
+    logits, _ = jax.jit(model.apply)(params, state, tokens)
     assert logits.shape == (4, 12, 50)
 
     # a couple of steps of next-token training must reduce loss
@@ -114,6 +115,7 @@ def test_transformer_lm_forward_and_train():
         return -jnp.mean(jnp.take_along_axis(
             lp, targets[:, :-1, None], axis=-1))
 
+    loss_fn = jax.jit(loss_fn)
     l0 = float(loss_fn(params))
     opt_step = jax.jit(lambda p: jax.tree.map(
         lambda a, g: a - 0.1 * g, p, jax.grad(loss_fn)(p)))
@@ -128,7 +130,7 @@ def test_transformer_encdec():
     params, state = model.init(jax.random.PRNGKey(7))
     src = jnp.asarray(np.random.RandomState(8).randint(0, 30, (2, 7)))
     tgt = jnp.asarray(np.random.RandomState(9).randint(0, 30, (2, 5)))
-    logits, _ = model.apply(params, state, (src, tgt))
+    logits, _ = jax.jit(model.apply)(params, state, (src, tgt))
     assert logits.shape == (2, 5, 30)
 
 
@@ -139,8 +141,8 @@ def test_transformer_blockwise_impl_matches_dense():
     blockw = Transformer(**kw, attn_impl="blockwise", block_size=8)
     params, state = dense.init(jax.random.PRNGKey(10))
     tokens = jnp.asarray(np.random.RandomState(11).randint(0, 40, (2, 32)))
-    ld, _ = dense.apply(params, state, tokens)
-    lb, _ = blockw.apply(params, state, tokens)
+    ld, _ = jax.jit(dense.apply)(params, state, tokens)
+    lb, _ = jax.jit(blockw.apply)(params, state, tokens)
     np.testing.assert_allclose(np.asarray(lb), np.asarray(ld),
                                rtol=3e-5, atol=3e-5)
 
@@ -205,9 +207,12 @@ def test_transformer_lm_cached_generate_matches_full_forward():
                                   beam_size=1, eos_id=0)
     assert seqs.shape == (2, 1, 5 + n_new)
 
+    # (the rollout's forward jitted: one program per length, where the
+    # eager forward compiles every op anew at each of the six lengths)
+    forward = jax.jit(lambda toks: model.apply(params, state, toks))
     cur = np.asarray(prompt)
     for _ in range(n_new):
-        logits, _ = model.apply(params, state, jnp.asarray(cur))
+        logits, _ = forward(jnp.asarray(cur))
         nxt = np.asarray(jnp.argmax(logits[:, -1, :], -1), np.int32)
         cur = np.concatenate([cur, nxt[:, None]], axis=1)
     assert not (cur[:, 5:] == 0).any()        # pin: no eos in rollout
@@ -241,7 +246,8 @@ def test_gqa_rope_composes_with_blockwise_and_flash():
         m = MultiHeadAttention(32, 8, num_kv_heads=2, rope_theta=10000.0,
                                block_size=32, **kw)
         p, s = m.init(jax.random.PRNGKey(0))
-        out, _ = m.apply(p, s, x, causal=True)
+        out, _ = jax.jit(lambda p, s, x: m.apply(p, s, x, causal=True))(
+            p, s, x)
         outs[impl] = np.asarray(out)
     np.testing.assert_allclose(outs["blockwise"], outs["dense"],
                                rtol=1e-5, atol=1e-5)

@@ -91,9 +91,9 @@ def test_dp_ep_matches_dense_and_pure_ep():
     xt = jnp.asarray(toks)
     yt = jnp.asarray(np.roll(toks, -1, axis=1))
 
-    dense_loss, _ = lm.dense_objective(params, xt, yt)
-    g_dense = jax.grad(
-        lambda p: lm.dense_objective(p, xt, yt)[0])(params)
+    # (the dense reference jitted: one program, not one per eager op)
+    dense_loss, g_dense = jax.jit(jax.value_and_grad(
+        lambda p: lm.dense_objective(p, xt, yt)[0]))(params)
     mesh_ep = Mesh(np.asarray(jax.devices()[:4]).reshape(4), ("expert",))
     l1, ce1, _, g1 = lm.loss_and_grads(params, xt, yt, mesh_ep)
     mesh2 = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),

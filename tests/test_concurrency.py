@@ -309,7 +309,7 @@ def test_process_exits_cleanly_with_full_plane_on(tmp_path, plane):
     code = _EXIT_AUDIT.format(tmp=str(tmp_path))
     t0 = time.monotonic()
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=120, cwd=ROOT)
+                       text=True, timeout=100, cwd=ROOT)
     wall = time.monotonic() - t0
     assert r.returncode == 0, r.stderr[-2000:]
     assert "REPORTS=0" in r.stdout, (r.stdout, r.stderr[-2000:])

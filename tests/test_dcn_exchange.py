@@ -251,17 +251,44 @@ def test_exchange_primitive_error_feedback_exact(compress):
 
 def test_int8_ef_training_tracks_uncompressed(monkeypatch):
     """Error feedback keeps int8-compressed training close to the exact
-    exchange at equal step count."""
-    monkeypatch.setenv("BIGDL_TPU_SLICE_EXCHANGE_EVERY", "4")
-    mesh = _two_tier()
-    exact = _trainer(mesh, end=12)
-    p_exact, _ = exact.optimize()
-    monkeypatch.setenv("BIGDL_TPU_SLICE_GRAD_COMPRESS", "int8")
-    comp = _trainer(mesh, end=12)
-    p_comp, _ = comp.optimize()
-    assert abs(exact.state["loss"] - comp.state["loss"]) < 5e-3
-    for a, b in zip(_leaves(p_exact), _leaves(p_comp)):
-        np.testing.assert_allclose(a, b, atol=5e-3, rtol=0.0)
+    exchange at equal step count, and it is the feedback that does it.
+
+    Under SGD, because that is where the promise holds on any host: what
+    the quantiser drops re-enters the next window, so the updates applied
+    so far differ from the exact ones by one window's residual, however
+    many windows went by. (Under Adam a quantised gradient near zero
+    moves its weight by a whole `lr` per exchange whichever way it
+    rounds: 3 of 32 weights came out 0.023 apart on some hosts.) The
+    control drops the residual and walks away from the exact run."""
+    from bigdl_tpu.parallel import mesh as mesh_mod
+
+    def train(compress):
+        monkeypatch.setenv("BIGDL_TPU_SLICE_EXCHANGE_EVERY", "4")
+        monkeypatch.setenv("BIGDL_TPU_SLICE_GRAD_COMPRESS", compress)
+        opt = _trainer(_two_tier(), method=SGD(0.5), end=32)
+        params, _ = opt.optimize()
+        return _leaves(params), opt.state["loss"]
+
+    def gap(a, b):
+        return max(float(np.abs(x - y).max()) for x, y in zip(a, b))
+
+    p_exact, loss_exact = train("")
+    p_comp, loss_comp = train("int8")
+
+    exchange = mesh_mod.cross_slice_accumulated_exchange
+
+    def without_feedback(acc, mesh, **kw):
+        mean, resid, rnorm = exchange(acc, mesh, **kw)
+        return mean, jax.tree.map(jnp.zeros_like, resid), rnorm
+
+    monkeypatch.setattr(mesh_mod, "cross_slice_accumulated_exchange",
+                        without_feedback)
+    p_open, _ = train("int8")
+
+    # measured in PR 24: 1.7e-4 with the feedback, 7.5e-4 without
+    assert abs(loss_exact - loss_comp) < 5e-3
+    assert gap(p_exact, p_comp) < 4e-4
+    assert gap(p_exact, p_open) > 4e-4
 
 
 def test_wire_bytes_accounting():

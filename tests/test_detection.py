@@ -77,7 +77,10 @@ def test_priorbox_normalized():
 def test_roi_align_constant_region():
     feat = jnp.ones((1, 16, 16, 3)) * 5.0
     boxes = jnp.asarray([[2.0, 2.0, 10.0, 10.0]])
-    out = roi_align(feat, boxes, jnp.asarray([0]), (4, 4))
+    # (jitted, as the other calls below: one program, where the eager
+    # call compiles each op of the sampling grid on its own)
+    out = jax.jit(lambda f, b: roi_align(f, b, jnp.asarray([0]), (4, 4)))(
+        feat, boxes)
     assert out.shape == (1, 4, 4, 3)
     np.testing.assert_allclose(np.asarray(out), 5.0, rtol=1e-5)
 
@@ -90,7 +93,7 @@ def test_roi_align_gradient_flows():
     def f(feat):
         return roi_align(feat, boxes, jnp.asarray([0]), (2, 2)).sum()
 
-    g = jax.grad(f)(feat)
+    g = jax.jit(jax.grad(f))(feat)
     assert float(jnp.abs(g).sum()) > 0
 
 
@@ -99,7 +102,7 @@ def test_fpn_shapes():
     params, state = fpn.init(jax.random.PRNGKey(0))
     c3 = jnp.zeros((1, 8, 8, 8))
     c4 = jnp.zeros((1, 4, 4, 16))
-    outs, _ = fpn.apply(params, state, (c3, c4))
+    outs, _ = jax.jit(fpn.apply)(params, state, (c3, c4))
     assert outs[0].shape == (1, 8, 8, 4)
     assert outs[1].shape == (1, 4, 4, 4)
 
@@ -109,7 +112,7 @@ def test_detection_output_ssd():
     loc = jnp.zeros((2, 4))
     conf = jnp.asarray([[0.1, 0.9], [0.8, 0.2]])
     head = DetectionOutputSSD(n_classes=2, top_k=2, background_id=0)
-    boxes, scores, valid = head.forward({}, priors, loc, conf)
+    boxes, scores, valid = jax.jit(head.forward)({}, priors, loc, conf)
     assert boxes.shape == (2, 2, 4)
     assert not bool(valid[0].any())          # background zeroed
     assert bool(valid[1, 0])
@@ -184,7 +187,7 @@ def test_pooler_level_assignment():
         [0, 0, 56, 56],        # 1/4 size  -> level index 0
         [0, 0, 1000, 1000],    # huge      -> clipped to coarsest (3)
     ], jnp.float32)
-    out = pooler.forward({}, feats, boxes)
+    out = jax.jit(pooler.forward)({}, feats, boxes)
     lvl = np.asarray(out)[:, 0, 0, 0]
     np.testing.assert_allclose(lvl, [2.0, 0.0, 3.0])
 
@@ -201,16 +204,17 @@ def test_assign_anchor_targets_matching_rules():
         jnp.float32)
     gt = jnp.asarray([[0, 0, 10, 10], [0, 0, 0, 0]], jnp.float32)
     valid = jnp.asarray([True, False])
-    labels, targets = assign_anchor_targets(anchors, gt, valid,
-                                            pos_iou=0.7, neg_iou=0.3)
+    labels, targets = jax.jit(lambda a, g, v: assign_anchor_targets(
+        a, g, v, pos_iou=0.7, neg_iou=0.3))(anchors, gt, valid)
     assert labels.tolist() == [1, 1, 0, -1]
     assert bool(jnp.isfinite(targets).all())
     np.testing.assert_allclose(np.asarray(targets[0]), [0, 0, 0, 0],
                                atol=1e-6)
     # no anchor clears pos_iou for a small gt: its best anchor is forced
     gt2 = jnp.asarray([[0, 0, 4, 4]], jnp.float32)
-    labels2, _ = assign_anchor_targets(
-        anchors, gt2, jnp.asarray([True]), pos_iou=0.9, neg_iou=0.0)
+    labels2, _ = jax.jit(lambda a, g, v: assign_anchor_targets(
+        a, g, v, pos_iou=0.9, neg_iou=0.0))(anchors, gt2,
+                                            jnp.asarray([True]))
     assert int(labels2[0]) == 1
 
 
@@ -263,8 +267,8 @@ def test_force_positive_survives_padded_gt_rows():
     anchors = jnp.asarray([[0, 0, 4, 4], [20, 20, 30, 30]], jnp.float32)
     gt = jnp.asarray([[0, 0, 2, 2], [0, 0, 0, 0]], jnp.float32)
     valid = jnp.asarray([True, False])
-    labels, _ = assign_anchor_targets(anchors, gt, valid,
-                                      pos_iou=0.9, neg_iou=0.0)
+    labels, _ = jax.jit(lambda a, g, v: assign_anchor_targets(
+        a, g, v, pos_iou=0.9, neg_iou=0.0))(anchors, gt, valid)
     # gt0's only overlapping anchor (index 0, the same index every padded
     # column argmaxes to) must stay force-positive
     assert int(labels[0]) == 1

@@ -6,8 +6,9 @@ same module as the library, so `mvn test` breaks if an example rots);
 the analogue here is to actually *run* each `examples/*.py` hermetically
 in a subprocess and assert a clean exit.
 
-Marked `examples` so a quick inner-loop run can deselect them
-(`-m 'not examples'`); the default full-suite run includes them.
+The runs are `slow`: each is a subprocess that trains a model (6 to 85 s,
+342 s together in PR 24's sequential run), so the tier every PR is held to
+does not run them (`-m slow` does).
 """
 
 import os
@@ -29,7 +30,7 @@ def test_all_examples_enumerated():
     assert len(EXAMPLES) >= 10
 
 
-@pytest.mark.examples
+@pytest.mark.slow        # a subprocess that trains (6-85 s each)
 @pytest.mark.parametrize("name", EXAMPLES)
 def test_example_runs_clean(name, tmp_path):
     env = dict(os.environ)          # conftest's JAX_PLATFORMS=cpu rides along

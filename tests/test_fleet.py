@@ -609,7 +609,7 @@ def test_observe_fleet_cli_smoke():
     planes, merged payload asserted, rc 0."""
     r = subprocess.run(
         [sys.executable, "-m", "bigdl_tpu.observe", "fleet", "--json"],
-        capture_output=True, text=True, timeout=240,
+        capture_output=True, text=True, timeout=100,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert r.returncode == 0, r.stdout + r.stderr
     doc = json.loads(r.stdout.strip().splitlines()[-1])

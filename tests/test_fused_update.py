@@ -321,7 +321,7 @@ def test_autotune_fresh_process_warm_start_zero_searches(
     env = {**os.environ, "XLA_FLAGS": "", "BIGDL_TPU_AUTOTUNE": "1",
            "BIGDL_TPU_AUTOTUNE_CACHE": root}
     r = subprocess.run([sys.executable, "-c", child], env=env,
-                       capture_output=True, text=True, timeout=300)
+                       capture_output=True, text=True, timeout=100)
     assert r.returncode == 0, r.stderr
     assert "SEARCHES 0" in r.stdout
 

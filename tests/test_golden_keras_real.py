@@ -155,6 +155,8 @@ def test_real_keras_spatial_dropout_inference(tmp_path):
     _golden(model, R.rand(2, 8, 3).astype(np.float32), tmp_path)
 
 
+@pytest.mark.slow        # real Keras builds VGG-16 at its published widths,
+#                          then calibrated int8 (35 s at the parent, 22 s now)
 def test_real_keras_vgg16_import_and_int8(tmp_path):
     """The actual VGG-16 topology (BASELINE config 5) built by real
     Keras at 64×64: import parity, then calibrated int8 with full argmax

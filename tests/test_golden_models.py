@@ -177,7 +177,10 @@ def test_resnet50_through_onnx_importer_matches_torch():
 
     xin = r.randn(1, 3, 96, 96).astype(np.float32) * 0.5
     module, params, state, _ = load_onnx(model)
-    got, _ = module.apply(params, state, jnp.asarray(xin), training=False)
+    # (jitted: one program, where the eager forward compiles each of the
+    # imported graph's ~175 nodes on its own)
+    got, _ = jax.jit(lambda p, s, x: module.apply(p, s, x, training=False))(
+        params, state, jnp.asarray(xin))
     with torch.no_grad():
         want = tm(torch.from_numpy(xin)).numpy()
     assert np.asarray(got).shape == want.shape == (1, 100)

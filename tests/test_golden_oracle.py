@@ -137,9 +137,11 @@ def test_roi_align_matches_numpy_bilinear():
     boxes = np.asarray([[2.0, 1.0, 14.0, 13.0], [0.0, 0.0, 8.0, 6.0]],
                        np.float32)
     layer = nn.RoiAlign((3, 3), spatial_scale=0.5, sampling_ratio=2)
-    got = np.asarray(layer.forward({}, jnp.asarray(feat),
-                                   jnp.asarray(boxes),
-                                   jnp.zeros((2,), jnp.int32)))
+    # (jitted, as the two pipelines below: one program, where the eager
+    # call compiles each op of the sampling grid or the NMS loop on its own)
+    got = np.asarray(jax.jit(layer.forward)(
+        {}, jnp.asarray(feat), jnp.asarray(boxes),
+        jnp.zeros((2,), jnp.int32)))
     for k in range(2):
         want = np_roi_align(feat[0], boxes[k], (3, 3), 0.5, 2)
         np.testing.assert_allclose(got[k], want, rtol=1e-4, atol=1e-5)
@@ -157,9 +159,8 @@ def test_detection_output_ssd_matches_numpy_pipeline():
 
     head = nn.DetectionOutputSSD(n_classes=3, iou_threshold=0.45, top_k=5,
                                  conf_threshold=0.01, background_id=0)
-    boxes, scores, valid = head.forward({}, jnp.asarray(priors),
-                                        jnp.asarray(loc),
-                                        jnp.asarray(conf))
+    boxes, scores, valid = jax.jit(head.forward)(
+        {}, jnp.asarray(priors), jnp.asarray(loc), jnp.asarray(conf))
     decoded = np_decode(priors, loc)
     for cls in (1, 2):                       # non-background classes
         s = conf[:, cls].copy()
@@ -203,8 +204,9 @@ def test_region_proposal_matches_numpy_pipeline():
                            post_nms_top_n=6, nms_thresh=0.6, min_size=0)
     params, state = rp.init(jax.random.PRNGKey(5))
     feat = R.randn(1, 8, 8, 4).astype(np.float32) * 2.0
-    (props, valid), _ = rp.apply(params, state, (jnp.asarray(feat),),
-                                 (64, 64))
+    (props, valid), _ = jax.jit(
+        lambda p, s, f: rp.apply(p, s, f, (64, 64)))(
+            params, state, (jnp.asarray(feat),))
 
     # --- numpy re-derivation
     p = jax.tree.map(np.asarray, params)

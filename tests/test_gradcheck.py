@@ -75,9 +75,11 @@ def test_flash_attention_custom_vjp_gradcheck():
         return jnp.sum(flash_attention(q, k, a, block_q=8, block_k=8,
                                        causal=True, interpret=True) ** 2)
 
-    check_gradients(obj_q, q, max_entries=16)
-    check_gradients(obj_k, k, max_entries=16)
-    check_gradients(obj_v, v, max_entries=16)
+    # jitted: the checker calls each objective 33 times, and the
+    # interpreted kernel is traced anew on every call that is not
+    check_gradients(jax.jit(obj_q), q, max_entries=16)
+    check_gradients(jax.jit(obj_k), k, max_entries=16)
+    check_gradients(jax.jit(obj_v), v, max_entries=16)
 
 
 def test_lstm_recurrence_gradcheck():
@@ -91,7 +93,7 @@ def test_lstm_recurrence_gradcheck():
         out = out[0] if isinstance(out, tuple) else out
         return jnp.sum(out ** 2)
 
-    check_gradients(obj, x, max_entries=24)
+    check_gradients(jax.jit(obj), x, max_entries=24)
 
 
 def test_nms_selection_gradient_flows_to_selected_boxes():
@@ -105,4 +107,5 @@ def test_nms_selection_gradient_flows_to_selected_boxes():
         idx, valid = nms(b, scores, 0.5, 2)
         return jnp.sum(jnp.where(valid[:, None], b[idx], 0.0) ** 2)
 
-    check_gradients(obj, boxes, max_entries=12, eps=1e-2, rtol=8e-2)
+    check_gradients(jax.jit(obj), boxes, max_entries=12, eps=1e-2,
+                    rtol=8e-2)

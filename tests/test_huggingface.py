@@ -161,10 +161,12 @@ def test_gpt2_generate_beam1_matches_greedy_rollout():
                                    n_new, beam_size=1)
     assert seqs.shape == (2, 1, 4 + n_new)
 
-    # hand greedy
+    # hand greedy (jitted: one program per length, where the eager
+    # forward compiles every op anew at each of the six lengths)
+    forward = jax.jit(lambda toks: module.apply(params, state, toks))
     cur = prompt.copy()
     for _ in range(n_new):
-        logits, _ = module.apply(params, state, jnp.asarray(cur))
+        logits, _ = forward(jnp.asarray(cur))
         nxt = np.asarray(jnp.argmax(logits[:, -1, :], -1), np.int32)
         cur = np.concatenate([cur, nxt[:, None]], axis=1)
     # pin the semantics: no eos emitted in this deterministic rollout, so
@@ -439,11 +441,12 @@ def test_llama_flash_attention_backend_and_int8():
     module, params, state = from_llama(hf)
     toks = jnp.asarray(
         np.random.RandomState(5).randint(0, 128, (2, 64)), jnp.int32)
-    want, _ = module.apply(params, state, toks)
+    # (the three forwards jitted: one program each, not one per eager op)
+    want, _ = jax.jit(module.apply)(params, state, toks)
 
     flash = from_llama(hf, attn_impl=PallasFlashAttention(
         block_q=32, block_k=32, interpret=True))[0]
-    got, _ = flash.apply(params, state, toks)
+    got, _ = jax.jit(flash.apply)(params, state, toks)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-2, atol=2e-3)
 
@@ -451,7 +454,7 @@ def test_llama_flash_attention_backend_and_int8():
     blk = qmod.children()["l0"].children()
     assert isinstance(blk["gate"], QuantizedLinear)
     assert isinstance(blk["down"], QuantizedLinear)
-    qlogits, _ = qmod.apply(qparams, state, toks)
+    qlogits, _ = jax.jit(qmod.apply)(qparams, state, toks)
     agree = float((np.asarray(qlogits).argmax(-1)
                    == np.asarray(want).argmax(-1)).mean())
     assert agree > 0.97, agree
@@ -488,10 +491,12 @@ def test_llama_tensor_parallel_training():
     assert params["l0"]["down"]["weight"].sharding.spec == P("model", None)
 
     # sharded-params forward == plain forward on the initial weights
-    want, _ = model.apply(params0, state0, jnp.asarray(toks))
+    # (jitted: the eager sharded forward partitions op by op)
+    forward = jax.jit(
+        lambda p: model.apply(p, state0, jnp.asarray(toks))[0])
+    want = forward(params0)
     from bigdl_tpu.parallel.sharding import shard_tree
-    sharded0 = shard_tree(params0, mesh, rules.tree_specs(params0))
-    got, _ = model.apply(sharded0, state0, jnp.asarray(toks))
+    got = forward(shard_tree(params0, mesh, rules.tree_specs(params0)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -538,56 +543,63 @@ def test_llama_sp_apply_refuses_dense_backend():
         llama_sp_apply(dense, params, toks, mesh)
 
 
-def test_gpt2_and_encoder_tp_rules_shard_and_match():
+def _tp_case_gpt2():
+    from bigdl_tpu.interop.huggingface import GPT2LM, gpt2_tp_rules
+    gpt = GPT2LM(31, 16, 16, 2, 1)
+    toks = jnp.asarray(np.random.RandomState(0).randint(0, 31, (2, 8)),
+                       jnp.int32)
+    return (gpt, jax.random.PRNGKey(0), (toks,), gpt2_tp_rules(),
+            [(("h0", "attn", "wq"), (None, "model")),
+             (("h0", "ffn", "w2", "weight"), ("model", None))])
+
+
+def _tp_case_bert():
+    from bigdl_tpu.interop.huggingface import BertEncoder, encoder_tp_rules
+    bert = BertEncoder(31, 16, 2, 16, 2, 1, 32)
+    toks = jnp.asarray(np.random.RandomState(0).randint(0, 31, (2, 8)),
+                       jnp.int32)
+    mask = jnp.ones((2, 8), jnp.int32)
+    types = jnp.zeros((2, 8), jnp.int32)
+    return (bert, jax.random.PRNGKey(1), (toks, mask, types),
+            encoder_tp_rules(),
+            [(("attn0", "wq"), (None, "model")),
+             (("ffn0", "w1", "weight"), (None, "model"))])
+
+
+def _tp_case_vit():
+    from bigdl_tpu.interop.huggingface import ViTEncoder, encoder_tp_rules
+    vit = ViTEncoder(16, 8, 1, 16, 2, 32, 1)
+    imgs = jnp.asarray(np.random.RandomState(2).randn(2, 16, 16, 1),
+                       jnp.float32)
+    return (vit, jax.random.PRNGKey(2), (imgs,), encoder_tp_rules(),
+            [(("h0", "attn", "wq"), (None, "model"))])
+
+
+@pytest.mark.parametrize("case", [_tp_case_gpt2, _tp_case_bert,
+                                  _tp_case_vit],
+                         ids=["gpt2", "bert", "vit"])
+def test_gpt2_and_encoder_tp_rules_shard_and_match(case):
     """Megatron TP rules for the other bridges: GPT-2, BERT, and ViT
     params shard over 'model', and the sharded forward equals the
-    unsharded one."""
+    unsharded one (both jitted: one program each, where the eager
+    forward partitions and compiles op by op)."""
     from jax.sharding import PartitionSpec as P
-    from bigdl_tpu.interop.huggingface import (BertEncoder, GPT2LM,
-                                               ViTEncoder,
-                                               encoder_tp_rules,
-                                               gpt2_tp_rules)
     from bigdl_tpu.parallel import create_mesh
     from bigdl_tpu.parallel.sharding import shard_tree
 
     mesh = create_mesh(data=4, model=2, drop_trivial_axes=False)
-
-    gpt = GPT2LM(31, 16, 16, 2, 1)
-    gp, gs = gpt.init(jax.random.PRNGKey(0))
-    toks = jnp.asarray(np.random.RandomState(0).randint(0, 31, (2, 8)),
-                       jnp.int32)
-    want, _ = gpt.apply(gp, gs, toks)
-    specs = gpt2_tp_rules().tree_specs(gp)
-    assert specs["h0"]["attn"]["wq"] == P(None, "model")
-    assert specs["h0"]["ffn"]["w2"]["weight"] == P("model", None)
-    sharded = shard_tree(gp, mesh, specs)
-    got, _ = gpt.apply(sharded, gs, toks)
+    module, key, inputs, rules, expected = case()
+    params, state = module.init(key)
+    forward = jax.jit(lambda p: module.apply(p, state, *inputs)[0])
+    want = forward(params)
+    specs = rules.tree_specs(params)
+    for path, spec in expected:
+        leaf = specs
+        for k in path:
+            leaf = leaf[k]
+        assert leaf == P(*spec), (path, leaf)
+    got = forward(shard_tree(params, mesh, specs))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-    bert = BertEncoder(31, 16, 2, 16, 2, 1, 32)
-    bp, bs = bert.init(jax.random.PRNGKey(1))
-    bspecs = encoder_tp_rules().tree_specs(bp)
-    assert bspecs["attn0"]["wq"] == P(None, "model")
-    assert bspecs["ffn0"]["w1"]["weight"] == P(None, "model")
-    mask = jnp.ones((2, 8), jnp.int32)
-    types = jnp.zeros((2, 8), jnp.int32)
-    bwant, _ = bert.apply(bp, bs, toks, mask, types)
-    bsharded = shard_tree(bp, mesh, bspecs)
-    bgot, _ = bert.apply(bsharded, bs, toks, mask, types)
-    np.testing.assert_allclose(np.asarray(bgot), np.asarray(bwant),
-                               rtol=2e-5, atol=2e-5)
-
-    vit = ViTEncoder(16, 8, 1, 16, 2, 32, 1)
-    vp, vs = vit.init(jax.random.PRNGKey(2))
-    vspecs = encoder_tp_rules().tree_specs(vp)
-    assert vspecs["h0"]["attn"]["wq"] == P(None, "model")
-    imgs = jnp.asarray(np.random.RandomState(2).randn(2, 16, 16, 1),
-                       jnp.float32)
-    vwant, _ = vit.apply(vp, vs, imgs)
-    vsharded = shard_tree(vp, mesh, vspecs)
-    vgot, _ = vit.apply(vsharded, vs, imgs)
-    np.testing.assert_allclose(np.asarray(vgot), np.asarray(vwant),
                                rtol=2e-5, atol=2e-5)
 
 

@@ -101,12 +101,13 @@ def test_tf_converted_model_is_trainable():
                               rng=jax.random.PRNGKey(0))
         return crit.forward(out, y)
 
-    l0, grads = jax.value_and_grad(loss_fn)(params)
+    # (jitted: one program each, not one per eager op of the backward)
+    l0, grads = jax.jit(jax.value_and_grad(loss_fn))(params)
     # gradients flow to the imported conv weight
     gnorm = sum(float(jnp.abs(g).sum()) for g in jax.tree.leaves(grads))
     assert gnorm > 0
     p2 = jax.tree.map(lambda p, g: p - 0.05 * g, params, grads)
-    assert float(loss_fn(p2)) < float(l0)
+    assert float(jax.jit(loss_fn)(p2)) < float(l0)
 
 
 def test_tf_convert_unsupported_op_raises():
@@ -244,8 +245,11 @@ def test_caffe_import_then_quantize(tmp_path):
     cn = load(str(proto), str(cm))
     qmodule, qparams = quantize(cn.module, cn.params)
     x = jnp.asarray(r.randn(2, 8, 8, 3), jnp.float32)
-    fp, _ = cn.module.apply(cn.params, cn.state, x, training=False)
-    q8, _ = qmodule.apply(qparams, cn.state, x, training=False)
+    # (both forwards jitted: one program each, not one per eager op)
+    fp, _ = jax.jit(lambda p, s, x: cn.module.apply(
+        p, s, x, training=False))(cn.params, cn.state, x)
+    q8, _ = jax.jit(lambda p, s, x: qmodule.apply(
+        p, s, x, training=False))(qparams, cn.state, x)
     # int8 path approximates fp32 within quantization error
     assert np.abs(np.asarray(fp) - np.asarray(q8)).max() < 0.15
     assert np.argmax(fp, -1).tolist() == np.argmax(q8, -1).tolist()

@@ -69,6 +69,7 @@ def _logits(mod, p, s, xte):
     return np.concatenate(outs)
 
 
+@pytest.mark.slow        # trains ResNet-20 to a 0.95 floor first (23 + 31 s)
 def test_int8_top1_delta_on_trained_model(trained_resnet20):
     model, params, state, xtr, xte, yte = trained_resnet20
     lf = _logits(model, params, state, xte)
@@ -97,6 +98,7 @@ def test_int8_top1_delta_on_trained_model(trained_resnet20):
         assert rel < 0.05, (name, rel)     # logits stay close, not just argmax
 
 
+@pytest.mark.slow        # needs the same trained weights
 def test_blocked_scales_reduce_weight_error(trained_resnet20):
     """Granularity ladder: per-tensor > per-channel > per-window RMS
     reconstruction error (BigQuant's motivation for windowed min/max)."""

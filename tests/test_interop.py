@@ -83,7 +83,11 @@ def test_event_file_roundtrip(tmp_path):
     import time
     time.sleep(0.2)
     got = s.read_scalar("Loss")
+    t0 = time.monotonic()
     s.close()
+    # an idle writer is woken, not waited for: close() used to take what
+    # was left of the writer's 5 s wait on its empty queue
+    assert time.monotonic() - t0 < 1.0
     assert [g[0] for g in got] == [0, 1, 2, 3, 4]
     np.testing.assert_allclose([g[1] for g in got],
                                [1.0, 0.5, 1 / 3, 0.25, 0.2], rtol=1e-6)

@@ -9,6 +9,7 @@ import h5py
 import numpy as np
 import pytest
 import torch
+import torch.nn.functional as F
 
 import jax
 import jax.numpy as jnp
@@ -397,3 +398,294 @@ def test_keras1_tail_guardrails():
     assert ad1([]) == ({}, {})
     with pytest.raises(NotImplementedError, match="implementation"):
         ad1([np.zeros((6, 6, 2), np.float32)])
+
+
+# ======================================================================
+# the paths that once raised NotImplementedError: SAME-padded 1D/3D pooling
+# and Conv3D, dilated grouped Conv2D, strided ConvLSTM2D, partial shared_axes
+# PReLU/SReLU, each against torch numerics (or direct numpy window math
+# where torch has no SAME mode)
+
+R = np.random.RandomState(3)
+
+
+def _load(tmp_path, layers, weights):
+    _write_h5(str(tmp_path / "w.h5"), weights)
+    mod, params, state = load_keras(_seq_json(layers),
+                                    str(tmp_path / "w.h5"))
+    return mod, params, state
+
+
+def _same_pad_1d(n, k, s):
+    total = max((-(-n // s) - 1) * s + k - n, 0)
+    return total // 2, total - total // 2
+
+
+def test_dilated_grouped_conv2d_matches_torch(tmp_path):
+    cin, cout, g, d = 4, 6, 2, 2
+    k = (R.randn(3, 3, cin // g, cout) * 0.3).astype(np.float32)
+    b = (R.randn(cout) * 0.1).astype(np.float32)
+    mod, params, state = _load(tmp_path, [
+        {"class_name": "Conv2D",
+         "config": {"name": "c", "filters": cout, "kernel_size": [3, 3],
+                    "dilation_rate": [d, d], "groups": g,
+                    "padding": "valid", "use_bias": True,
+                    "batch_input_shape": [None, 10, 10, cin]}},
+    ], {"c": [k, b]})
+    x = R.randn(2, 10, 10, cin).astype(np.float32)
+    got, _ = mod.apply(params, state, jnp.asarray(x))
+    want = F.conv2d(torch.from_numpy(x).permute(0, 3, 1, 2),
+                    torch.from_numpy(k).permute(3, 2, 0, 1),
+                    torch.from_numpy(b), dilation=d, groups=g)
+    np.testing.assert_allclose(np.asarray(got),
+                               want.permute(0, 2, 3, 1).numpy(),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_conv3d_same_matches_torch(tmp_path):
+    cin, cout = 2, 3
+    k = (R.randn(3, 3, 3, cin, cout) * 0.3).astype(np.float32)
+    b = (R.randn(cout) * 0.1).astype(np.float32)
+    mod, params, state = _load(tmp_path, [
+        {"class_name": "Conv3D",
+         "config": {"name": "c", "filters": cout,
+                    "kernel_size": [3, 3, 3], "strides": [2, 2, 2],
+                    "padding": "same", "use_bias": True,
+                    "batch_input_shape": [None, 7, 7, 7, cin]}},
+    ], {"c": [k, b]})
+    x = R.randn(1, 7, 7, 7, cin).astype(np.float32)
+    got, _ = mod.apply(params, state, jnp.asarray(x))
+    assert got.shape == (1, 4, 4, 4, cout)
+    # torch: explicit asymmetric SAME pad then VALID conv
+    pads = [_same_pad_1d(7, 3, 2)] * 3
+    xt = torch.from_numpy(x).permute(0, 4, 1, 2, 3)
+    # F.pad takes (w_lo, w_hi, h_lo, h_hi, d_lo, d_hi)
+    xt = F.pad(xt, (pads[2][0], pads[2][1], pads[1][0], pads[1][1],
+                    pads[0][0], pads[0][1]))
+    want = F.conv3d(xt, torch.from_numpy(k).permute(4, 3, 0, 1, 2),
+                    torch.from_numpy(b), stride=2)
+    np.testing.assert_allclose(np.asarray(got),
+                               want.permute(0, 2, 3, 4, 1).numpy(),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_maxpool1d_same_matches_torch(tmp_path):
+    mod, params, state = _load(tmp_path, [
+        {"class_name": "MaxPooling1D",
+         "config": {"name": "p", "pool_size": [3], "strides": [2],
+                    "padding": "same",
+                    "batch_input_shape": [None, 9, 2]}},
+    ], {})
+    x = R.randn(2, 9, 2).astype(np.float32)
+    got, _ = mod.apply(params, state, jnp.asarray(x))
+    assert got.shape == (2, 5, 2)
+    lo, hi = _same_pad_1d(9, 3, 2)
+    xt = F.pad(torch.from_numpy(x).permute(0, 2, 1), (lo, hi),
+               value=float("-inf"))
+    want = F.max_pool1d(xt, 3, 2).permute(0, 2, 1).numpy()
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
+
+
+def test_avgpool1d_same_matches_manual_windows(tmp_path):
+    """keras/TF SAME avg pooling divides by the VALID element count per
+    window — no torch mode matches, so compare against direct window
+    math."""
+    mod, params, state = _load(tmp_path, [
+        {"class_name": "AveragePooling1D",
+         "config": {"name": "p", "pool_size": [3], "strides": [2],
+                    "padding": "same",
+                    "batch_input_shape": [None, 8, 2]}},
+    ], {})
+    x = R.randn(1, 8, 2).astype(np.float32)
+    got, _ = mod.apply(params, state, jnp.asarray(x))
+    lo, _hi = _same_pad_1d(8, 3, 2)
+    want = np.zeros((1, 4, 2))
+    for i in range(4):
+        s, e = max(i * 2 - lo, 0), min(i * 2 - lo + 3, 8)
+        want[:, i] = x[:, s:e].mean(axis=1)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5)
+
+
+def test_pool3d_same_matches_manual_windows(tmp_path):
+    for cls in ("MaxPooling3D", "AveragePooling3D"):
+        mod, params, state = _load(tmp_path, [
+            {"class_name": cls,
+             "config": {"name": "p", "pool_size": [2, 2, 2],
+                        "strides": [2, 2, 2], "padding": "same",
+                        "batch_input_shape": [None, 5, 5, 5, 1]}},
+        ], {})
+        x = R.randn(1, 5, 5, 5, 1).astype(np.float32)
+        got, _ = mod.apply(params, state, jnp.asarray(x))
+        assert got.shape == (1, 3, 3, 3, 1)
+        agg = np.max if cls.startswith("Max") else np.mean
+        want = np.zeros((1, 3, 3, 3, 1))
+        for i in range(3):
+            for j in range(3):
+                for l in range(3):
+                    want[0, i, j, l, 0] = agg(
+                        x[0, i * 2:i * 2 + 2, j * 2:j * 2 + 2,
+                          l * 2:l * 2 + 2, 0])
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
+                                   atol=1e-6, err_msg=cls)
+
+
+def test_convlstm2d_strided_matches_torch_recurrence(tmp_path):
+    """Strided ConvLSTM2D vs an independent torch implementation of the
+    keras recurrence (gate order i,f,c,o; input conv stride 2 SAME;
+    recurrent conv stride 1 SAME at the downsampled resolution)."""
+    cin, f, kk, T = 2, 3, 3, 3
+    kern = (R.randn(kk, kk, cin, 4 * f) * 0.2).astype(np.float32)
+    rec = (R.randn(kk, kk, f, 4 * f) * 0.2).astype(np.float32)
+    bias = (R.randn(4 * f) * 0.1).astype(np.float32)
+    mod, params, state = _load(tmp_path, [
+        {"class_name": "ConvLSTM2D",
+         "config": {"name": "cl", "filters": f, "kernel_size": [kk, kk],
+                    "strides": [2, 2], "padding": "same",
+                    "recurrent_activation": "sigmoid",
+                    "return_sequences": True,
+                    "batch_input_shape": [None, T, 8, 8, cin]}},
+    ], {"cl": [kern, rec, bias]})
+    x = R.randn(1, T, 8, 8, cin).astype(np.float32)
+    got, _ = mod.apply(params, state, jnp.asarray(x))
+    assert got.shape == (1, T, 4, 4, f)
+
+    # independent torch recurrence
+    def tconv(inp, w, stride):
+        # SAME pad for k=3: (1,1) at stride 1; TF SAME at stride 2 on even
+        # input: total pad = k - stride = 1 → (0,1)
+        n = inp.shape[-1]
+        lo, hi = _same_pad_1d(n, kk, stride)
+        inp = F.pad(inp, (lo, hi, lo, hi))
+        return F.conv2d(inp, w, stride=stride)
+
+    wk = torch.from_numpy(kern).permute(3, 2, 0, 1)
+    wr = torch.from_numpy(rec).permute(3, 2, 0, 1)
+    bt = torch.from_numpy(bias)
+    h = torch.zeros(1, f, 4, 4)
+    c = torch.zeros(1, f, 4, 4)
+    outs = []
+    for t in range(T):
+        xt = torch.from_numpy(x[:, t]).permute(0, 3, 1, 2)
+        gates = tconv(xt, wk, 2) + tconv(h, wr, 1) + bt[None, :, None, None]
+        i, fg, g, o = torch.split(gates, f, dim=1)
+        i, fg, o = torch.sigmoid(i), torch.sigmoid(fg), torch.sigmoid(o)
+        c = fg * c + i * torch.tanh(g)
+        h = o * torch.tanh(c)
+        outs.append(h.permute(0, 2, 3, 1).numpy())
+    want = np.stack(outs, axis=1)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+
+
+def test_convlstm2d_default_hard_sigmoid_matches_torch(tmp_path):
+    """keras defaults recurrent_activation='hard_sigmoid' — verify the
+    gates use clip(0.2x+0.5, 0, 1), not sigmoid (review finding r4)."""
+    cin, f, T = 1, 2, 2
+    kern = (R.randn(3, 3, cin, 4 * f) * 0.4).astype(np.float32)
+    rec = (R.randn(3, 3, f, 4 * f) * 0.4).astype(np.float32)
+    bias = (R.randn(4 * f) * 0.2).astype(np.float32)
+    mod, params, state = _load(tmp_path, [
+        {"class_name": "ConvLSTM2D",
+         "config": {"name": "cl", "filters": f, "kernel_size": [3, 3],
+                    "padding": "same", "return_sequences": True,
+                    "batch_input_shape": [None, T, 5, 5, cin]}},
+    ], {"cl": [kern, rec, bias]})
+    x = R.randn(1, T, 5, 5, cin).astype(np.float32)
+    got, _ = mod.apply(params, state, jnp.asarray(x))
+
+    def hsig(v):
+        return torch.clamp(0.2 * v + 0.5, 0.0, 1.0)
+
+    wk = torch.from_numpy(kern).permute(3, 2, 0, 1)
+    wr = torch.from_numpy(rec).permute(3, 2, 0, 1)
+    bt = torch.from_numpy(bias)
+    h = torch.zeros(1, f, 5, 5)
+    c = torch.zeros(1, f, 5, 5)
+    outs = []
+    for t in range(T):
+        xt = torch.from_numpy(x[:, t]).permute(0, 3, 1, 2)
+        gates = (F.conv2d(F.pad(xt, (1, 1, 1, 1)), wk)
+                 + F.conv2d(F.pad(h, (1, 1, 1, 1)), wr)
+                 + bt[None, :, None, None])
+        i, fg, g, o = torch.split(gates, f, dim=1)
+        c = hsig(fg) * c + hsig(i) * torch.tanh(g)
+        h = hsig(o) * torch.tanh(c)
+        outs.append(h.permute(0, 2, 3, 1).numpy())
+    np.testing.assert_allclose(np.asarray(got), np.stack(outs, 1),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_prelu_shared_axes_on_2d_input(tmp_path):
+    """PReLU(shared_axes=[1]) on (None, F): keras stores a single-element
+    alpha — must load as a broadcastable (1,) map (review finding r4)."""
+    alpha = np.asarray([0.31], np.float32)
+    mod, params, state = _load(tmp_path, [
+        {"class_name": "PReLU",
+         "config": {"name": "pr", "shared_axes": [1],
+                    "batch_input_shape": [None, 6]}},
+    ], {"pr": [alpha]})
+    x = R.randn(4, 6).astype(np.float32)
+    got, _ = mod.apply(params, state, jnp.asarray(x))
+    np.testing.assert_allclose(np.asarray(got),
+                               np.where(x >= 0, x, 0.31 * x), rtol=1e-6)
+
+
+def test_apply_update_honors_default_lr_decay():
+    """Default-schedule lr_decay must not be short-circuited by the
+    constant-LR fast path (review finding r4): trajectory must equal
+    manually computed lr/(1+neval*decay) SGD steps."""
+    from bigdl_tpu.optim.method import SGD, apply_update, init_update_slots
+    from bigdl_tpu.optim.schedule import Default
+    m = SGD(learning_rate=0.1, learning_rate_schedule=Default(0.5))
+    p = {"w": jnp.ones((3,))}
+    g = {"w": jnp.full((3,), 1.0)}
+    slots = init_update_slots(m, p)
+    want = 1.0
+    for step in range(3):
+        p, slots = apply_update(m, p, g, slots)
+        want -= 0.1 / (1 + step * 0.5)
+    np.testing.assert_allclose(np.asarray(p["w"]),
+                               np.full(3, want, np.float32), rtol=1e-6)
+
+
+def test_prelu_partial_shared_axes(tmp_path):
+    alpha = (R.rand(1, 5, 2).astype(np.float32)) * 0.5   # share H only
+    mod, params, state = _load(tmp_path, [
+        {"class_name": "PReLU",
+         "config": {"name": "pr", "shared_axes": [1],
+                    "batch_input_shape": [None, 4, 5, 2]}},
+    ], {"pr": [alpha]})
+    x = R.randn(3, 4, 5, 2).astype(np.float32)
+    got, _ = mod.apply(params, state, jnp.asarray(x))
+    want = np.where(x >= 0, x, x * alpha[None])
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
+    # and vs torch on the fully-shared-per-channel formulation
+    alpha_c = (R.rand(2).astype(np.float32)) * 0.5
+    mod2, p2, s2 = _load(tmp_path, [
+        {"class_name": "PReLU",
+         "config": {"name": "pr2", "shared_axes": [1, 2],
+                    "batch_input_shape": [None, 4, 5, 2]}},
+    ], {"pr2": [alpha_c.reshape(1, 1, 2)]})
+    got2, _ = mod2.apply(p2, s2, jnp.asarray(x))
+    want2 = F.prelu(torch.from_numpy(x).permute(0, 3, 1, 2),
+                    torch.from_numpy(alpha_c)).permute(0, 2, 3, 1).numpy()
+    np.testing.assert_allclose(np.asarray(got2), want2, rtol=1e-6)
+
+
+def test_srelu_partial_shared_axes(tmp_path):
+    shape = (4, 1, 2)                       # share W only
+    tl = (R.randn(*shape) * 0.1).astype(np.float32)
+    al = (R.rand(*shape).astype(np.float32))
+    tr = (R.rand(*shape).astype(np.float32))
+    ar = (R.rand(*shape).astype(np.float32))
+    mod, params, state = _load(tmp_path, [
+        {"class_name": "SReLU",
+         "config": {"name": "sr", "shared_axes": [2],
+                    "batch_input_shape": [None, 4, 5, 2]}},
+    ], {"sr": [tl, al, tr, ar]})
+    x = R.randn(3, 4, 5, 2).astype(np.float32)
+    got, _ = mod.apply(params, state, jnp.asarray(x))
+    # keras-1 reparameterization: t_right_actual = t_left + |t_right|
+    tra = tl + np.abs(tr)
+    y = np.where(x < tl, tl + al * (x - tl), x)
+    want = np.where(x > tra, tra + ar * (x - tra), y)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)

@@ -103,14 +103,16 @@ def test_binary_tree_lstm_trains():
     tree = jnp.asarray(np.tile(np.array([[0, 0, 1], [0, 0, 2],
                                          [1, 2, -1]]), (3, 1, 1)),
                        jnp.int32)
-    out, _ = m.apply(p, s, (x, tree))
+    # (forward and backward jitted: one program each, not one per eager
+    # op of the tree's unrolled node loop)
+    out, _ = jax.jit(m.apply)(p, s, (x, tree))
     assert out.shape == (3, 3, 8)
 
     def loss(p):
         o, _ = m.apply(p, s, (x, tree))
         return jnp.sum(o[:, -1] ** 2)      # root states
 
-    g = jax.grad(loss)(p)
+    g = jax.jit(jax.grad(loss))(p)
     gn = sum(float(jnp.abs(l).sum()) for l in jax.tree.leaves(g))
     assert gn > 0
     # grads reach the leaf projection too (through the composer)

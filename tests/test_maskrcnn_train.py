@@ -23,18 +23,26 @@ def test_resnet50_fpn_backbone_builds_and_runs():
     swaps in for the stand-in backbone."""
     t = resnet.trunk(50)
     assert t.channels == [256, 512, 1024, 2048]
-    p, s = t.init(jax.random.PRNGKey(0))
+    # the trunk alone in the abstract (its count and its four shapes are
+    # all that is asserted of it); it runs for real inside the model below
+    x = jnp.zeros((1, 64, 64, 3))
+
+    def trunk(key, x):
+        p, s = t.init(key)
+        return p, t.apply(p, s, x)[0]
+    p, outs = jax.eval_shape(trunk, jax.random.PRNGKey(0), x)
     from bigdl_tpu.core.module import count_params
     n = count_params(p)
     assert 23_000_000 < n < 24_000_000, n   # ResNet-50 minus the fc head
-    outs, _ = t.apply(p, s, jnp.zeros((1, 64, 64, 3)))
     assert [o.shape for o in outs] == [
         (1, 16, 16, 256), (1, 8, 8, 512), (1, 4, 4, 1024), (1, 2, 2, 2048)]
 
     m = maskrcnn.build(num_classes=3, backbone="resnet50",
                        pre_nms_topk=32, post_nms_topk=8, max_detections=4)
     mp, ms = m.init(jax.random.PRNGKey(1))
-    out, _ = m.apply(mp, ms, jnp.zeros((1, 64, 64, 3)))
+    # (jitted: one program, where the eager forward compiles each of the
+    # backbone's 53 convolutions on its own)
+    out, _ = jax.jit(m.apply)(mp, ms, x)
     assert out["boxes"].shape == (4, 4)
     assert out["masks"].shape == (4, 28, 28)
 
@@ -80,6 +88,7 @@ def _coco_json_from_eval(tmp_path, eds):
     return images, targets
 
 
+@pytest.mark.slow        # trains 24 epochs to a box and mask mAP floor (78 s)
 def test_maskrcnn_trains_to_map_floor(tmp_path):
     """Train all heads end to end on synthetic COCO-format shards, then
     assert box AND mask mAP@0.5 above a fixed floor on held-out images

@@ -453,7 +453,7 @@ def test_memz_cli_smoke_and_drift_gate():
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
         [sys.executable, "-m", "bigdl_tpu.observe", "memz", "--smoke",
-         "--json"], capture_output=True, text=True, env=env, timeout=120)
+         "--json"], capture_output=True, text=True, env=env, timeout=60)
     assert r.returncode == 0, r.stderr[-2000:]
     doc = json.loads(r.stdout)
     assert doc["ok"] is True
@@ -466,12 +466,12 @@ def test_memz_cli_smoke_and_drift_gate():
     r = subprocess.run(
         [sys.executable, "-m", "bigdl_tpu.observe", "memz", "--smoke",
          "--json", "--max-drift-pct", "-1"],
-        capture_output=True, text=True, env=env, timeout=120)
+        capture_output=True, text=True, env=env, timeout=30)
     assert r.returncode == 1
     # human table renders
     r = subprocess.run(
         [sys.executable, "-m", "bigdl_tpu.observe", "memz", "--smoke"],
-        capture_output=True, text=True, env=env, timeout=120)
+        capture_output=True, text=True, env=env, timeout=30)
     assert r.returncode == 0
     assert "serve/demo/kv_cache" in r.stdout
     assert "drift check" in r.stdout

@@ -11,10 +11,25 @@ from bigdl_tpu.models import autoencoder, inception, lenet, resnet, rnn, vgg
 
 
 def _fwd(model, x, training=False):
+    """Init + one forward, jitted: one program, where the eager forward
+    compiles every layer of a published-size model on its own."""
     params, state = model.init(jax.random.PRNGKey(0))
-    out, _ = model.apply(params, state, x, training=training,
-                         rng=jax.random.PRNGKey(1) if training else None)
+    rng = jax.random.PRNGKey(1) if training else None
+    out, _ = jax.jit(
+        lambda p, s, x: model.apply(p, s, x, training=training, rng=rng)
+    )(params, state, x)
     return out
+
+
+def _out_shape(model, x_shape):
+    """The output's shape from an abstract init + forward: the whole
+    model is traced, nothing is compiled, no weight is drawn. For the
+    published-size models of which a test asserts the shape alone."""
+    def run(key, x):
+        params, state = model.init(key)
+        return model.apply(params, state, x, training=False)[0]
+    return jax.eval_shape(run, jax.random.PRNGKey(0),
+                          jax.ShapeDtypeStruct(x_shape, jnp.float32)).shape
 
 
 def test_resnet_cifar():
@@ -25,9 +40,10 @@ def test_resnet_cifar():
 
 
 def test_resnet_imagenet_bottleneck():
-    x = jnp.zeros((1, 64, 64, 3))   # any spatial size ≥32 works (global pool)
-    out = _fwd(resnet.build(depth=50, class_num=7), x)
-    assert out.shape == (1, 7)
+    # any spatial size ≥32 works (global pool); ResNet-50's trunk runs for
+    # real in test_maskrcnn_train.py
+    assert _out_shape(resnet.build(depth=50, class_num=7),
+                      (1, 64, 64, 3)) == (1, 7)
 
 
 def test_resnet_basic_imagenet():
@@ -50,9 +66,8 @@ def test_vgg_cifar():
 
 
 def test_vgg16_imagenet():
-    x = jnp.zeros((1, 224, 224, 3))
-    out = _fwd(vgg.build(depth=16, class_num=6), x)
-    assert out.shape == (1, 6)
+    assert _out_shape(vgg.build(depth=16, class_num=6),
+                      (1, 224, 224, 3)) == (1, 6)
 
 
 def test_autoencoder():
@@ -112,13 +127,11 @@ def test_resnet_train_step_decreases_loss():
 
 
 def test_inception_v2():
-    x = jnp.asarray(np.random.RandomState(0).randn(1, 224, 224, 3),
-                    jnp.float32)
-    out = _fwd(inception.build_v2(class_num=11), x)
-    assert out.shape == (1, 11)
+    assert _out_shape(inception.build_v2(class_num=11),
+                      (1, 224, 224, 3)) == (1, 11)
     # BN-Inception has ~11.2M params at 1000 classes
     m = inception.build_v2(1000)
-    p, _ = m.init(jax.random.PRNGKey(0))
+    p, _ = jax.eval_shape(m.init, jax.random.PRNGKey(0))
     n = sum(int(l.size) for l in jax.tree.leaves(p))
     assert 10_500_000 < n < 12_000_000, n
 
@@ -198,7 +211,7 @@ def test_ptb_llama_cli_trains():
          "--model", "llama", "--hidden", "32", "--layers", "1",
          "--num-steps", "12", "--vocab-size", "64", "-b", "8",
          "--max-iter", "30"],
-        capture_output=True, text=True, timeout=600,
+        capture_output=True, text=True, timeout=100,
         env=dict(os.environ))
     assert r.returncode == 0, r.stderr[-800:]
     import re

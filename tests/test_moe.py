@@ -30,7 +30,8 @@ def test_moe_forward_and_aux():
     moe = MoE(d_model=16, d_ff=32, n_experts=4, capacity_factor=2.0)
     params, state = moe.init(jax.random.PRNGKey(0))
     x = jnp.asarray(np.random.RandomState(0).randn(2, 8, 16), jnp.float32)
-    out, ns = moe.apply(params, state, x)
+    out, ns = jax.jit(moe.apply)(params, state, x)   # one program, not
+    #                                                  one per eager op
     assert out.shape == (2, 8, 16)
     assert "load_balance" in ns["aux"] and "z_loss" in ns["aux"]
     assert np.isfinite(float(ns["aux"]["load_balance"]))
@@ -42,7 +43,8 @@ def test_expert_parallel_matches_local():
     moe = MoE(d_model=8, d_ff=16, n_experts=4, capacity_factor=4.0)
     params, state = moe.init(jax.random.PRNGKey(1))
     x = jnp.asarray(np.random.RandomState(1).randn(4, 16, 8), jnp.float32)
-    ref, _ = moe.apply(params, state, x)
+    ref, _ = jax.jit(moe.apply)(params, state, x)   # one program, not
+    #                                                 one per eager op
     mesh = _mesh(4)
     out, aux = expert_parallel_apply(moe, params, x, mesh)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -103,7 +105,8 @@ def test_topk_dispatch_semantics():
     probs = jnp.asarray([[0.6, 0.3, 0.1],
                          [0.1, 0.5, 0.4],
                          [0.45, 0.45, 0.1]], jnp.float32)
-    dispatch, combine, aux = topk_dispatch(probs, 2, capacity=3)
+    dispatch, combine, aux = jax.jit(
+        lambda p: topk_dispatch(p, 2, capacity=3))(probs)
     # every token dispatched exactly twice
     np.testing.assert_allclose(np.asarray(dispatch.sum(axis=(1, 2))),
                                [2, 2, 2])
@@ -132,7 +135,7 @@ def test_moe_top2_matches_manual_combine():
     params, state = moe.init(jax.random.PRNGKey(0))
     r = np.random.RandomState(1)
     x = jnp.asarray(r.randn(1, 5, 4), jnp.float32)
-    out, _ = moe.apply(params, state, x)
+    out, _ = jax.jit(moe.apply)(params, state, x)
 
     tokens = np.asarray(x).reshape(5, 4)
     probs = np.asarray(jax.nn.softmax(
@@ -154,7 +157,8 @@ def test_moe_top2_expert_parallel_matches_local():
               capacity_factor=4.0)
     params, state = moe.init(jax.random.PRNGKey(0))
     x = jnp.asarray(np.random.RandomState(2).randn(2, 8, 8), jnp.float32)
-    ref, _ = moe.apply(params, state, x)
+    ref, _ = jax.jit(moe.apply)(params, state, x)   # one program, not
+    #                                                 one per eager op
     out, aux = expert_parallel_apply(moe, params, x, _mesh(2))
     # EP enforces capacity per shard, so allow the generous factor to make
     # behavior identical, then require exact agreement

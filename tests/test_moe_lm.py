@@ -34,7 +34,8 @@ def test_moe_lm_matches_dense_loss_and_grads():
     def dense(p):
         total, (ce, aux) = lm.dense_objective(p, xt, yt)
         return total
-    want_loss, want_grads = jax.value_and_grad(dense)(params)
+    # (the dense reference jitted: one program, not one per eager op)
+    want_loss, want_grads = jax.jit(jax.value_and_grad(dense))(params)
     np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-5)
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -320,11 +320,16 @@ def test_optax_method_adapter_matches_optax_and_trains():
     tx = optax.adam(1e-2)
     p_ref = params
     opt_state = tx.init(p_ref)
-    for i in range(5):
+
+    @jax.jit            # one program, not one per eager op of five steps
+    def raw_step(p, opt_state):
         g = jax.grad(lambda p: crit.forward(
-            m.apply(p, state, jnp.asarray(x))[0], jnp.asarray(y)))(p_ref)
-        upd, opt_state = tx.update(g, opt_state, p_ref)
-        p_ref = jax.tree.map(lambda a, b: a + b, p_ref, upd)
+            m.apply(p, state, jnp.asarray(x))[0], jnp.asarray(y)))(p)
+        upd, opt_state = tx.update(g, opt_state, p)
+        return jax.tree.map(lambda a, b: a + b, p, upd), opt_state
+
+    for i in range(5):
+        p_ref, opt_state = raw_step(p_ref, opt_state)
 
     # the adapter inside the trainer (same data, one batch per iter)
     opt = (Optimizer(model(), [(x, y)], crit,

@@ -161,8 +161,10 @@ def test_int8_llama_serving_under_concurrency():
     r = np.random.RandomState(0)
     reqs = [r.randint(0, 48, (n, 12)).astype(np.int32)
             for n in (1, 3, 7, 2, 5, 4)]
-    want = [np.asarray(qmod.apply(qparams, state, jnp.asarray(q))[0])
-            for q in reqs]
+    # (the single-shot forwards jitted: one program a batch size, where
+    # the eager forward compiles every op anew at each of the six sizes)
+    single_shot = jax.jit(lambda q: qmod.apply(qparams, state, q)[0])
+    want = [np.asarray(single_shot(jnp.asarray(q))) for q in reqs]
 
     results = [None] * len(reqs)
     def client(i):
@@ -176,7 +178,8 @@ def test_int8_llama_serving_under_concurrency():
     for got, exp in zip(results, want):
         np.testing.assert_allclose(got, exp, rtol=1e-5, atol=1e-5)
 
-    fp_logits, _ = model.apply(params, state, jnp.asarray(reqs[2]))
+    fp_logits = jax.jit(lambda q: model.apply(params, state, q)[0])(
+        jnp.asarray(reqs[2]))
     agree = (results[2].argmax(-1)
              == np.asarray(fp_logits).argmax(-1)).mean()
     assert agree > 0.9, agree

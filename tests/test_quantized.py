@@ -61,8 +61,9 @@ def test_quantize_tree_walk_lenet():
 
     r = np.random.RandomState(0)
     x = jnp.asarray(r.randn(4, 28, 28, 1), jnp.float32)
-    ref, _ = model.apply(params, state, x)
-    out, _ = qmodel.apply(qparams, state, x)
+    # (both forwards jitted: one program each, not one per eager op)
+    ref, _ = jax.jit(model.apply)(params, state, x)
+    out, _ = jax.jit(qmodel.apply)(qparams, state, x)
     # log-probs argmax agreement — the <0.1% top-1 drop claim at model level
     assert (np.argmax(np.asarray(out), 1) ==
             np.argmax(np.asarray(ref), 1)).mean() == 1.0

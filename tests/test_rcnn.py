@@ -16,7 +16,10 @@ def test_region_proposal_shapes_and_validity():
                            post_nms_top_n=20)
     params, state = rp.init(jax.random.PRNGKey(0))
     feats = (jnp.ones((2, 16, 16, 8)), jnp.ones((2, 8, 8, 8)))
-    (props, valid), _ = rp.apply(params, state, feats, (128, 128))
+    # (jitted, as the heads below: one program, where the eager call
+    # compiles every op of the anchor, top-k and NMS loops on its own)
+    (props, valid), _ = jax.jit(
+        lambda p, s, f: rp.apply(p, s, f, (128, 128)))(params, state, feats)
     assert props.shape == (2, 20, 4)
     assert valid.shape == (2, 20)
     assert bool(valid.any())
@@ -38,8 +41,8 @@ def test_proposal_layer():
     na = prop.anchor.num  # 3 ratios x 1 scale
     cls_prob = jnp.asarray(r.rand(1, 8, 8, 2 * na).astype(np.float32))
     bbox = jnp.asarray(0.1 * r.randn(1, 8, 8, 4 * na).astype(np.float32))
-    (rois, valid), _ = prop.apply(params, state, cls_prob, bbox,
-                                  jnp.asarray([128.0, 128.0]))
+    (rois, valid), _ = jax.jit(prop.apply)(params, state, cls_prob, bbox,
+                                           jnp.asarray([128.0, 128.0]))
     assert rois.shape == (1, 10, 4)
     assert bool(valid.any())
 
@@ -52,8 +55,9 @@ def test_box_head_end_to_end():
     feats = [jnp.ones((1, 32, 32, 8)), jnp.ones((1, 16, 16, 8))]
     proposals = jnp.asarray([[0, 0, 32, 32], [8, 8, 96, 96],
                              [0, 0, 120, 120]], jnp.float32)
-    (boxes, scores, labels, valid), _ = bh.apply(
-        params, state, feats, proposals, (128, 128))
+    (boxes, scores, labels, valid), _ = jax.jit(
+        lambda p, s, f, pr: bh.apply(p, s, f, pr, (128, 128)))(
+            params, state, feats, proposals)
     assert boxes.shape == (8, 4)
     assert scores.shape == labels.shape == valid.shape == (8,)
     assert bool(valid.any())
@@ -69,7 +73,7 @@ def test_mask_head_shapes_and_range():
     feats = [jnp.ones((1, 32, 32, 8))]
     boxes = jnp.asarray([[0, 0, 64, 64], [16, 16, 80, 80]], jnp.float32)
     labels = jnp.asarray([1, 3], jnp.int32)
-    masks, _ = mh.apply(params, state, feats, boxes, labels)
+    masks, _ = jax.jit(mh.apply)(params, state, feats, boxes, labels)
     assert masks.shape == (2, 14, 14)   # deconv doubles the resolution
     assert float(masks.min()) >= 0.0 and float(masks.max()) <= 1.0
 
@@ -83,7 +87,7 @@ def test_detection_output_frcnn():
     rois = rois.at[:, 2:].set(rois[:, :2] + 20)
     det = nn.DetectionOutputFrcnn(nms_thresh=0.3, n_classes=c,
                                   max_per_image=10, score_thresh=0.0)
-    boxes, scores, labels, valid = det.forward(
+    boxes, scores, labels, valid = jax.jit(det.forward)(
         {}, probs, deltas, rois, jnp.asarray([100.0, 100.0]))
     assert boxes.shape == (10, 4)
     assert bool(valid.any())
@@ -128,7 +132,8 @@ def test_region_proposal_min_size_filters_degenerate_boxes():
                            post_nms_top_n=4, min_size=10_000)
     params, state = rp.init(jax.random.PRNGKey(0))
     feats = (jnp.ones((1, 8, 8, 4)),)
-    (props, valid), _ = rp.apply(params, state, feats, (64, 64))
+    (props, valid), _ = jax.jit(
+        lambda p, s, f: rp.apply(p, s, f, (64, 64)))(params, state, feats)
     assert not bool(valid.any())
 
 

@@ -40,12 +40,18 @@ def test_module_roundtrip(name, tmp_path):
     kw = dict(e.kwargs)
     if e.train_rng:
         kw.update(training=True, rng=jax.random.PRNGKey(42))
-    want, _ = mod.apply(params, state, *inputs, **kw)
 
+    def forward(m, p, s):
+        """One program a module, not one per eager op (the detection
+        heads' NMS loops alone were 25 s of this file)."""
+        def run(p, s, *xs):
+            return m.apply(p, s, *xs, **kw)[0]
+        return (run if e.host else jax.jit(run))(p, s, *inputs)
+
+    want = forward(mod, params, state)
     path = str(tmp_path / f"{name}.bigdl-tpu")
     save_module(path, mod, params, state)
-    mod2, p2, s2 = load_module(path)
-    got, _ = mod2.apply(p2, s2, *inputs, **kw)
+    got = forward(*load_module(path))
     if e.post:
         want, got = e.post(want), e.post(got)
     _assert_tree_equal(want, got)

@@ -629,7 +629,7 @@ def test_sigkill_mid_stream_resumes_on_survivor_no_duplicates():
     survivor's non-streamed answer."""
     from bigdl_tpu.serve.router import (ReplicaRouter, launch_replicas,
                                         stop_replicas)
-    procs, urls = launch_replicas(2, REPLICA_ARGS, ready_timeout_s=120)
+    procs, urls = launch_replicas(2, REPLICA_ARGS, ready_timeout_s=100)
     try:
         r = ReplicaRouter(urls, retries=2, health_ttl_s=0.05)
         prompt = [5, 9, 2, 11]

@@ -416,3 +416,25 @@ def test_catalog_layer_passes_check(name):
                           apply_kwargs=entry.kwargs or None)
     errors = [i for i in issues if i.severity == "error"]
     assert not errors, "\n".join(str(i) for i in errors)
+
+
+# ===================================================== the tier's own shape
+
+def test_tier_has_one_marker_put_in_the_tests_own_file():
+    """`slow` is the only marker that selects (docs/testing.md), and a
+    test gets it in the file that defines it: conftest.py marks nothing,
+    so no table of module names can drift from what the tier runs."""
+    import glob
+    import re
+    allowed = {"slow", "parametrize", "xfail", "skip", "skipif"}
+    used = {}
+    for path in glob.glob(os.path.join(ROOT, "tests", "*.py")):
+        with open(path) as f:
+            for mark in re.findall(r"\bmark\.(\w+)", f.read()):
+                used.setdefault(mark, os.path.basename(path))
+    assert set(used) <= allowed, {m: used[m] for m in set(used) - allowed}
+    with open(os.path.join(ROOT, "tests", "conftest.py")) as f:
+        conftest = f.read()
+    assert "add_marker" not in conftest
+    assert "pytest_collection_modifyitems" not in conftest
+    assert re.findall(r'"markers",\s*"(\w+):', conftest) == ["slow"]

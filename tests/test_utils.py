@@ -162,3 +162,39 @@ def test_device_memory_summary_and_profile(tmp_path):
     p = memory_profile(str(tmp_path / "mem.pprof"))
     import os
     assert os.path.getsize(p) > 0
+
+
+def test_a_test_past_its_limit_fails_and_the_next_still_runs(tmp_path):
+    """tests/conftest.py's limit, set to 1 s through the same hooks: the
+    test that sleeps past it fails, the test after it runs and passes."""
+    import subprocess
+    import sys
+    tier_conftest = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                 "conftest.py")
+    (tmp_path / "conftest.py").write_text(
+        "import importlib.util\n"
+        f"spec = importlib.util.spec_from_file_location('tier', "
+        f"{tier_conftest!r})\n"
+        "tier = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(tier)\n"
+        "tier.TEST_LIMIT_S = 1.0\n"
+        "from_tier = ('pytest_runtest_setup', 'pytest_runtest_call',\n"
+        "             'pytest_runtest_teardown', 'pytest_configure')\n"
+        "globals().update({n: getattr(tier, n) for n in from_tier})\n")
+    (tmp_path / "test_two.py").write_text(
+        "import time\n"
+        "import pytest\n"
+        "def test_hangs():\n"
+        "    time.sleep(30)\n"
+        "def test_next():\n"
+        "    pass\n"
+        "@pytest.mark.slow\n"
+        "def test_slow_keeps_its_time():\n"
+        "    time.sleep(1.5)\n")
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "--rootdir", str(tmp_path), str(tmp_path)],
+        capture_output=True, text=True, timeout=60, cwd=str(tmp_path))
+    assert r.returncode == 1, r.stdout + r.stderr
+    assert "1 failed, 2 passed" in r.stdout, r.stdout
+    assert "test_hangs: call took more than 1 s" in r.stdout, r.stdout
