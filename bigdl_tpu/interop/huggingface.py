@@ -271,38 +271,34 @@ class GPT2LM(Module):
     # -------------------------------------------------- paged KV decoding
     # The PAGED decode-serving contract (serve/decode.py BlockPool):
     # same slot-batch semantics, but K/V live in a shared pool of
-    # fixed-size blocks addressed through a per-slot block table
-    # (nn/attention.paged_slot_cached_attend). Per-row numerics stay
-    # bit-identical to the dense slot path (the paged-vs-dense oracle in
-    # tests/test_decode.py). Inactive rows and padded prefill tails
-    # scatter with mode='drop' instead of _restore_inactive — they never
-    # touch the pool.
+    # fixed-size blocks addressed through a per-slot block table and
+    # attention reads the pool where it lies
+    # (nn/attention.paged_slot_cached_attend). Per row the same lanes are
+    # attended as by the dense slot path, summed in pool order (the
+    # paged-vs-dense oracle in tests/test_decode.py). Inactive rows and
+    # padded prefill tails are left out of the write instead of
+    # _restore_inactive - they never touch the pool.
     def make_paged_slot_caches(self, params, num_blocks: int, block: int):
-        """Zero per-layer KV pools of (num_blocks, block, H, hd) — the
-        shared block pool the decode engine's BlockPool allocates out
-        of."""
+        """One zero KV pool per layer (nn/attention.make_paged_kv_pool) -
+        the shared block pool the decode engine's BlockPool allocates
+        out of."""
+        from bigdl_tpu.nn.attention import make_paged_kv_pool
         H = self.children()["h0"].attn.num_heads
-        hd = self.d_model // H
-        dtype = params["wte"].dtype
-        zeros = lambda: jnp.zeros(                         # noqa: E731
-            (num_blocks, block, H, hd), dtype)
-        return (tuple(zeros() for _ in range(self.num_layers)),
-                tuple(zeros() for _ in range(self.num_layers)))
+        return tuple(make_paged_kv_pool(num_blocks, block, H,
+                                        self.d_model // H,
+                                        params["wte"].dtype)
+                     for _ in range(self.num_layers))
 
     def _paged_slot_hidden(self, params, caches, tokens, positions,
                            block_table, lengths):
-        cks, cvs = caches
         pos = jnp.clip(positions, 0, self.n_positions - 1)
         x = params["wte"][tokens] + params["wpe"][pos]
-        new_ck, new_cv = [], []
+        pools = []
         for i in range(self.num_layers):
-            x, ck_i, cv_i = \
-                self.children()[f"h{i}"].paged_slot_cached_step(
-                    params[f"h{i}"], x, cks[i], cvs[i], pos,
-                    block_table, lengths)
-            new_ck.append(ck_i)
-            new_cv.append(cv_i)
-        return x, (tuple(new_ck), tuple(new_cv))
+            x, pool = self.children()[f"h{i}"].paged_slot_cached_step(
+                params[f"h{i}"], x, caches[i], pos, block_table, lengths)
+            pools.append(pool)
+        return x, tuple(pools)
 
     def paged_prefill(self, params, caches, tokens, positions,
                       block_table, lengths):
@@ -661,13 +657,14 @@ class LlamaBlock(Module):
         dn, _ = c["down"].apply(params["down"], {}, jax.nn.silu(g) * u)
         return x + dn, ck, cv
 
-    def paged_slot_cached_step(self, params, x, ck_pool, cv_pool,
-                               positions, block_table, lengths):
+    def paged_slot_cached_step(self, params, x, kv_pool, positions,
+                               block_table, lengths):
         """`slot_cached_step` against a PAGED grouped-KV pool
-        (nn/attention.paged_slot_cached_attend) — per-row RoPE as in the
-        dense slot path, K/V scattered into pool blocks through the
-        slot's block table. Bit-identical per row to slot_cached_step
-        with a dense cache row."""
+        (nn/attention.paged_slot_cached_attend) - per-row RoPE as in the
+        dense slot path, K/V written into pool blocks through the slot's
+        block table, the grouped query heads attending to the pool where
+        it lies. Per row the same lanes as slot_cached_step with a dense
+        cache row."""
         from bigdl_tpu.nn.attention import (rotary_embedding,
                                             paged_slot_cached_attend)
         c = self.children()
@@ -689,14 +686,14 @@ class LlamaBlock(Module):
                              positions)
         k = rotary_embedding(k.transpose(0, 2, 1, 3), attn.rope_theta,
                              positions).transpose(0, 2, 1, 3)
-        a, ck_pool, cv_pool = paged_slot_cached_attend(
-            q, k, v, ck_pool, cv_pool, positions, block_table, lengths)
+        a, kv_pool = paged_slot_cached_attend(
+            q, k, v, kv_pool, positions, block_table, lengths)
         x = x + a @ at["wo"]
         h, _ = c["ln2"].apply(params["ln2"], {}, x)
         g, _ = c["gate"].apply(params["gate"], {}, h)
         u, _ = c["up"].apply(params["up"], {}, h)
         dn, _ = c["down"].apply(params["down"], {}, jax.nn.silu(g) * u)
-        return x + dn, ck_pool, cv_pool
+        return x + dn, kv_pool
 
 
 class LlamaLM(Module):
@@ -848,31 +845,29 @@ class LlamaLM(Module):
 
     # -------------------------------------------------- paged KV decoding
     # Same paged contract as GPT2LM (serve/decode.py BlockPool): grouped
-    # KV pools, per-row RoPE offsets, scatter-drop for inactive rows and
-    # padded tails.
+    # KV pools, per-row RoPE offsets, inactive rows and padded tails left
+    # out of the write.
     def make_paged_slot_caches(self, params, num_blocks: int, block: int):
-        """Zero per-layer grouped-KV pools (num_blocks, block, KV, hd)."""
+        """One zero grouped-KV pool per layer
+        (nn/attention.make_paged_kv_pool)."""
+        from bigdl_tpu.nn.attention import make_paged_kv_pool
         attn0 = self.children()["l0"].children()["attn"]
         KV = attn0.num_kv_heads or attn0.num_heads
-        dtype = params["embed"].dtype
-        zeros = lambda: jnp.zeros(                         # noqa: E731
-            (num_blocks, block, KV, attn0.head_dim), dtype)
-        return (tuple(zeros() for _ in range(self.num_layers)),
-                tuple(zeros() for _ in range(self.num_layers)))
+        return tuple(make_paged_kv_pool(num_blocks, block, KV,
+                                        attn0.head_dim,
+                                        params["embed"].dtype)
+                     for _ in range(self.num_layers))
 
     def _paged_slot_hidden(self, params, caches, tokens, positions,
                            block_table, lengths):
-        cks, cvs = caches
         x = params["embed"][tokens]
-        new_ck, new_cv = [], []
+        pools = []
         for i in range(self.num_layers):
-            x, ck_i, cv_i = \
-                self.children()[f"l{i}"].paged_slot_cached_step(
-                    params[f"l{i}"], x, cks[i], cvs[i], positions,
-                    block_table, lengths)
-            new_ck.append(ck_i)
-            new_cv.append(cv_i)
-        return x, (tuple(new_ck), tuple(new_cv))
+            x, pool = self.children()[f"l{i}"].paged_slot_cached_step(
+                params[f"l{i}"], x, caches[i], positions, block_table,
+                lengths)
+            pools.append(pool)
+        return x, tuple(pools)
 
     def paged_prefill(self, params, caches, tokens, positions,
                       block_table, lengths):

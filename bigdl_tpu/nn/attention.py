@@ -220,61 +220,99 @@ def slot_cached_attend(q_heads, k_chunk, v_chunk, ck, cv, positions):
     return a.transpose(0, 2, 1, 3).reshape(N, T, H * hd), ck, cv
 
 
-def paged_slot_cached_attend(q_heads, k_chunk, v_chunk, ck_pool, cv_pool,
-                             positions, block_table, lengths):
-    """`slot_cached_attend` over a PAGED KV pool (vLLM's PagedAttention
-    discipline): instead of one dense (N, L, Hc, hd) cache row per slot,
-    K/V live in a shared pool of fixed-size blocks (P, B, Hc, hd) and
-    each slot owns an int32 `block_table` row (N, M) mapping its m-th
-    logical block to a pool block (-1 = not acquired). Lane m*B+b of the
-    gathered sequence is absolute position m*B+b of the slot — the same
-    logical layout as the dense row, so the same NEG_INF frontier mask
-    applies and per-row numerics stay bit-identical to the dense path
-    (the paged-vs-dense oracle in tests/test_decode.py): lanes past the
-    frontier — including whole unacquired blocks — are masked before the
-    softmax and their exp underflows to exactly 0.0, so stale pool pages
-    contribute nothing.
+# The paged KV pool's layout is decided here and nowhere else: one array
+# per layer, (Hc, P, B, 2*hd) = (KV heads, pool blocks, tokens a block,
+# K's head_dim lanes then V's). Heads lead because they are the batch
+# dimension of both attention matmuls; the block dimension comes next so
+# that a whole block is one contiguous (B, 2*hd) window per head, which the
+# TPU compiler scatters in place; K and V share the minor dimension so that
+# a 64-wide head fills the 128 lanes of a tile and the device keeps the
+# array row-major (a (…, 64) minor dimension makes it pick another layout
+# and relay the pool out around every write and read). serve/decode.py
+# shards PAGED_POOL_BLOCK_AXIS under kv_shard.
+PAGED_POOL_BLOCK_AXIS = 1
 
-    `lengths` (N,) int32 is the count of VALID leading tokens in this
-    chunk per row (0 for inactive rows): padded tail tokens of a
-    rounded-up prefill bucket and inactive rows scatter with mode='drop'
-    instead of landing in the pool — the paged analogue of the dense
-    path's tolerated-garbage + `_restore_inactive` discipline, required
-    here because a padded write could land past the slot's reserved
-    blocks.
+
+def make_paged_kv_pool(num_blocks: int, block: int, kv_heads: int,
+                       head_dim: int, dtype):
+    """One layer's zero paged KV pool, (Hc, P, B, 2*hd)."""
+    return jnp.zeros((kv_heads, num_blocks, block, 2 * head_dim), dtype)
+
+
+def paged_slot_cached_attend(q_heads, k_chunk, v_chunk, kv_pool, positions,
+                             block_table, lengths):
+    """`slot_cached_attend` over a PAGED KV pool (vLLM's PagedAttention
+    discipline), served from the pool where it lies: instead of one dense
+    (N, L, Hc, hd) cache row per slot, K/V live in a shared pool of
+    fixed-size blocks (`make_paged_kv_pool`) and each slot owns an int32
+    `block_table` row (N, M) mapping its m-th logical block to a pool
+    block (-1 = not acquired). No per-slot copy of K or V is made:
+
+    * **write** - the chunk's K/V go into the (at most
+      ceil((T-1)/B)+1) blocks a row's run of T positions straddles, one
+      whole block a window: the touched blocks are read, the chunk's
+      lanes put over them, and the blocks scattered back along the block
+      dimension, which the donated pool takes in place.
+    * **read** - every query attends over the WHOLE pool under an
+      ownership mask: lane (p, b) is absolute position m*B+b of slot n
+      iff `block_table[n, m] == p` (a block shared through the prefix
+      cache is owned by several slots), and is visible to the query at
+      position q iff it is owned and m*B+b <= q. Unowned, stale and
+      future lanes are masked to NEG_INF *before* the softmax and their
+      exp underflows to exactly 0.0, as in the dense path; only the
+      order of summation differs from it (pool order, not position
+      order).
+
+    `positions` (N, T) int32 are a row's absolute positions, consecutive
+    from `positions[:, 0]`; `lengths` (N,) int32 is the count of VALID
+    leading tokens in this chunk per row (0 for inactive rows): padded
+    tail tokens of a rounded-up prefill bucket, inactive rows and
+    unacquired blocks are left out of the write - the paged analogue of
+    the dense path's tolerated-garbage + `_restore_inactive` discipline,
+    required here because a padded write could land past the slot's
+    reserved blocks.
 
     q_heads (N, H, T, hd); k_chunk/v_chunk (N, T, Hc, hd), Hc == H or a
-    grouped divisor (GQA). Returns ((N, T, H*hd), new_ck_pool,
-    new_cv_pool)."""
+    grouped divisor (GQA: the H/Hc query heads of a group attend to its
+    one KV head without a repeated copy of it). Returns ((N, T, H*hd),
+    new_kv_pool)."""
     N, H, T, hd = q_heads.shape
-    P, B, Hc, _ = ck_pool.shape
+    Hc, P, B, _ = kv_pool.shape
     M = block_table.shape[1]
-    L = M * B
-    # -- scatter this chunk's K/V into the slots' pages ---------------
-    valid = jnp.arange(T)[None, :] < lengths[:, None]           # (N, T)
-    tok_block = jnp.clip(positions // B, 0, M - 1)
-    blk = jnp.take_along_axis(block_table, tok_block, axis=1)   # (N, T)
-    flat = blk * B + positions % B
-    # invalid lanes (padding, inactive rows, unacquired blocks) are
-    # pointed out of range so mode='drop' discards them
-    flat = jnp.where(valid & (blk >= 0), flat, P * B).reshape(-1)
-    ck_pool = ck_pool.reshape(P * B, Hc, hd).at[flat].set(
-        k_chunk.reshape(N * T, Hc, hd), mode="drop").reshape(ck_pool.shape)
-    cv_pool = cv_pool.reshape(P * B, Hc, hd).at[flat].set(
-        v_chunk.reshape(N * T, Hc, hd), mode="drop").reshape(cv_pool.shape)
-    # -- gather each slot's pages into its logical sequence -----------
-    safe = jnp.clip(block_table, 0, P - 1)      # -1 rows: masked anyway
-    fk = ck_pool[safe].reshape(N, L, Hc, hd).transpose(0, 2, 1, 3)
-    fv = cv_pool[safe].reshape(N, L, Hc, hd).transpose(0, 2, 1, 3)
-    if Hc != H:
-        fk = jnp.repeat(fk, H // Hc, axis=1)
-        fv = jnp.repeat(fv, H // Hc, axis=1)
-    # (N, 1, T, L): per-row causal-over-cache frontier, as in the dense
-    # slot path — L here is M*B >= max_seq_len; the extra tail lanes are
-    # always masked
-    mask = (jnp.arange(L)[None, None, :] <= positions[:, :, None])[:, None]
-    a = dot_product_attention(q_heads, fk, fv, mask)
-    return a.transpose(0, 2, 1, 3).reshape(N, T, H * hd), ck_pool, cv_pool
+    # -- write the chunk into the blocks it straddles ------------------
+    nb = -(-(T - 1) // B) + 1
+    start = positions[:, 0]
+    m = start[:, None] // B + jnp.arange(nb)                    # (N, nb)
+    blk = jnp.take_along_axis(block_table, jnp.clip(m, 0, M - 1), axis=1)
+    # chunk index of lane b of the j-th touched block
+    t = (m * B - start[:, None])[:, :, None] + jnp.arange(B)    # (N, nb, B)
+    live = ((t >= 0) & (t < lengths[:, None, None])
+            & ((m < M) & (blk >= 0))[:, :, None])
+    # windows with nothing to write are pointed out of range and dropped
+    ids = jnp.where(live.any(-1), blk, P).reshape(-1)
+    kv = jnp.concatenate([k_chunk, v_chunk], axis=-1)       # (N, T, Hc, 2hd)
+    new = jnp.take_along_axis(
+        kv, jnp.clip(t, 0, T - 1).reshape(N, nb * B, 1, 1), axis=1)
+    new = new.reshape(N * nb, B, Hc, 2 * hd).transpose(2, 0, 1, 3)
+    old = kv_pool[:, jnp.clip(ids, 0, P - 1)]             # (Hc, N*nb, B, 2hd)
+    kv_pool = kv_pool.at[:, ids].set(
+        jnp.where(live.reshape(N * nb, B)[None, :, :, None], new, old),
+        mode="drop")
+    # -- attend over the pool under the ownership mask -----------------
+    owns = block_table[:, :, None] == jnp.arange(P)             # (N, M, P)
+    logical = jnp.max(jnp.where(owns, jnp.arange(M)[None, :, None], -1),
+                      axis=1)                                   # (N, P)
+    lane_pos = jnp.where(logical[:, :, None] >= 0,
+                         logical[:, :, None] * B + jnp.arange(B),
+                         jnp.iinfo(jnp.int32).max).reshape(N, P * B)
+    mask = lane_pos[:, None, None, None, :] <= \
+        positions[:, None, None, :, None]                   # (N,1,1,T,P*B)
+    # K and V broadcast over the slots and over a group's query heads
+    lanes = kv_pool.reshape(Hc, 1, P * B, 2 * hd)
+    a = dot_product_attention(q_heads.reshape(N, Hc, H // Hc, T, hd),
+                              lanes[..., :hd], lanes[..., hd:], mask)
+    a = a.reshape(N, H, T, hd).transpose(0, 2, 1, 3).reshape(N, T, H * hd)
+    return a, kv_pool
 
 
 class MultiHeadAttention(Module):
@@ -524,14 +562,15 @@ class TransformerLayer(Module):
                               self.ln2.apply(params["ln2"], {}, x)[0])
         return x + f, ck, cv
 
-    def paged_slot_cached_step(self, params, x, ck_pool, cv_pool,
-                               positions, block_table, lengths):
+    def paged_slot_cached_step(self, params, x, kv_pool, positions,
+                               block_table, lengths):
         """`slot_cached_step` against a PAGED KV pool: same hand-rolled
-        projection chain, but K/V scatter into / gather from pool blocks
-        through the slot's block table (paged_slot_cached_attend).
-        Per-row numerics are bit-identical to `slot_cached_step` with a
-        dense cache row. Self-attention blocks only; same custom-
-        attn_impl refusal as cached_step."""
+        projection chain, but the chunk's K/V are written into the pool's
+        blocks through the slot's block table and attention reads the
+        pool where it lies (paged_slot_cached_attend). Per row the same
+        lanes are attended as by `slot_cached_step` with a dense cache
+        row, summed in pool order. Self-attention blocks only; same
+        custom-attn_impl refusal as cached_step."""
         if self.cross:
             raise ValueError("paged_slot_cached_step supports self-"
                              "attention decoder blocks only")
@@ -553,15 +592,15 @@ class TransformerLayer(Module):
         q = q.reshape(N, T, H, hd).transpose(0, 2, 1, 3)
         k = k.reshape(N, T, H, hd)
         v = v.reshape(N, T, H, hd)
-        a, ck_pool, cv_pool = paged_slot_cached_attend(
-            q, k, v, ck_pool, cv_pool, positions, block_table, lengths)
+        a, kv_pool = paged_slot_cached_attend(
+            q, k, v, kv_pool, positions, block_table, lengths)
         a = a @ at["wo"]
         if self.attn.bias:
             a = a + at["bo"]
         x = x + a
         f, _ = self.ffn.apply(params["ffn"], {},
                               self.ln2.apply(params["ln2"], {}, x)[0])
-        return x + f, ck_pool, cv_pool
+        return x + f, kv_pool
 
     def _apply(self, params, state, x, memory=None, *, mask=None,
                memory_mask=None, causal=False, training=False, rng=None):

@@ -40,9 +40,13 @@ GPT2LM and LlamaLM (interop/huggingface.py).
 **Paged KV (default)**: models carrying the paged contract
 (`make_paged_slot_caches` / `paged_prefill` / `paged_decode_step`)
 allocate the KV cache as a shared pool of fixed-size blocks
-(`BIGDL_TPU_SERVE_KV_BLOCK` tokens each) plus per-slot int32 block
-tables (vLLM's PagedAttention discipline, threaded through
-nn/attention.paged_slot_cached_attend): HBM cost follows LIVE
+(`BIGDL_TPU_SERVE_KV_BLOCK` tokens each, one array a layer in the layout
+of nn/attention.make_paged_kv_pool) plus per-slot int32 block tables
+(vLLM's PagedAttention discipline): the programs write a chunk's K/V
+into the donated pool's blocks in place and attend over the pool where
+it lies, under a mask of which slot owns which block
+(nn/attention.paged_slot_cached_attend), so no per-slot copy of the
+cache is ever made. HBM cost follows LIVE
 sequences, not the (num_slots x max_seq_len) worst case; slots acquire
 blocks lazily as their frontier crosses a block boundary and retire
 returns them to the free list; admission refuses with a block-level
@@ -482,12 +486,14 @@ class DecodeEntry:
             # not FLOPs. kv_shard=True additionally shards the paged
             # pool's BLOCK dimension over the data axis (the slot-dim
             # layout of the dense bucket, applied to its paged
-            # replacement) — the pool is the one decode resident worth
-            # splitting at real-chip scale.
+            # replacement; blocks stay whole on a device) — the pool is
+            # the one decode resident worth splitting at real-chip scale.
             sh_in = rep
             if self.kv_shard:
+                from bigdl_tpu.nn.attention import PAGED_POOL_BLOCK_AXIS
                 self._pool_sharding = NamedSharding(
-                    self.mesh, P(self._shard_axis))
+                    self.mesh, P(*[None] * PAGED_POOL_BLOCK_AXIS,
+                                 self._shard_axis))
                 cache_sh = self._pool_sharding
             else:
                 cache_sh = rep

@@ -253,3 +253,63 @@ def test_gqa_rope_composes_with_blockwise_and_flash():
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(outs["flash"], outs["dense"],
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("block", [1, 7, 16])
+@pytest.mark.parametrize("heads", [(4, 4), (4, 2)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("chunk", [1, 8])
+def test_paged_attend_over_poisoned_pool_matches_dense_rows(chunk, heads,
+                                                            block):
+    """`paged_slot_cached_attend` alone against `slot_cached_attend` on the
+    equivalent dense rows. The pool is 1e30 wherever a slot must not look:
+    in blocks no slot owns, in blocks the inactive slot once owned, and in
+    the lanes of owned blocks at and past a slot's frontier. Slots 0 and 1
+    share their first pool block (a prefix-cache hit), slot 1's chunk ends
+    in a padded tail, slot 2 is inactive. The attended lanes are the dense
+    path's, summed in pool order, hence the tolerance; the write is exact
+    and touches the chunk's valid lanes only."""
+    from bigdl_tpu.nn.attention import (make_paged_kv_pool,
+                                        paged_slot_cached_attend,
+                                        slot_cached_attend)
+    T, (H, Hc), B = chunk, heads, block
+    N, hd = 3, 8
+    M = -(-(2 * B + T + 6) // B)              # blocks a slot: room for all
+    L, P = M * B, N * M + 3
+    r = np.random.RandomState(100 * T + 10 * Hc + B)
+    starts = np.array([B + 2, B + 5, 0])      # both past the shared block
+    lengths = np.array([T, max(T - 3, 1), 0], np.int32)
+    free = list(r.permutation(P))
+    table = -np.ones((N, M), np.int32)
+    for n in range(2):
+        for m in range((starts[n] + T - 1) // B + 1):
+            table[n, m] = table[0, 0] if (n, m) == (1, 0) else free.pop()
+    ck = r.randn(N, L, Hc, hd).astype(np.float32)
+    cv = r.randn(N, L, Hc, hd).astype(np.float32)
+    ck[1, :B], cv[1, :B] = ck[0, :B], cv[0, :B]       # the shared prefix
+    pool = np.asarray(make_paged_kv_pool(P, B, Hc, hd, np.float32)) + 1e30
+    for n in range(2):
+        for pos in range(starts[n]):
+            pool[:, table[n, pos // B], pos % B] = np.concatenate(
+                [ck[n, pos], cv[n, pos]], -1)
+        ck[n, starts[n]:] = cv[n, starts[n]:] = 1e30  # stale past frontier
+    q = r.randn(N, H, T, hd).astype(np.float32)
+    kc = r.randn(N, T, Hc, hd).astype(np.float32)
+    vc = r.randn(N, T, Hc, hd).astype(np.float32)
+    positions = (starts[:, None] + np.arange(T)).astype(np.int32)
+    want, _, _ = slot_cached_attend(q, kc, vc, ck, cv, positions)
+    got, new_pool = jax.jit(paged_slot_cached_attend)(
+        q, kc, vc, pool, positions, table, lengths)
+    got, new_pool = np.asarray(got), np.asarray(new_pool)
+    written = 0
+    for n in range(N):
+        v = lengths[n]
+        np.testing.assert_allclose(got[n, :v], np.asarray(want)[n, :v],
+                                   rtol=2e-5, atol=2e-5)
+        for t in range(v):
+            pos = starts[n] + t
+            np.testing.assert_array_equal(
+                new_pool[:, table[n, pos // B], pos % B],
+                np.concatenate([kc[n, t], vc[n, t]], -1))
+            written += 1
+    changed = (new_pool != pool).any(axis=(0, 3))     # (P, B) lanes
+    assert changed.sum() == written == lengths.sum()

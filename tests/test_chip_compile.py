@@ -11,6 +11,7 @@ in a skipif, a parametrize or conftest.py): only one process may hold the
 TPU library, and every xdist worker imports every test file."""
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -130,11 +131,11 @@ def test_fused_update_at_resnet50_parameter_count(one_chip):
     _fits(c)
 
 
-def test_gpt2_xl_paged_decode_step_fits_one_chip(one_chip, monkeypatch):
-    """The decode program DecodeEntry jits, all 48 layers, at the slots
-    and KV pool chip_smoke.py registers — with the cache donation that
-    `_build` turns on only off the CPU. The compiler counts arguments +
-    temporaries against the chip's HBM and refuses what does not fit."""
+@pytest.fixture(scope="module")
+def xl_entry(one_chip):
+    """The DecodeEntry chip_smoke.py registers for GPT-2 XL, all 48
+    layers, with the shapes of its arguments on the described chip - and
+    with the cache donation that `_build` turns on only off the CPU."""
     import chip_smoke
     from bigdl_tpu.interop.huggingface import GPT2LM
     from bigdl_tpu.serve.decode import DecodeEntry
@@ -143,19 +144,69 @@ def test_gpt2_xl_paged_decode_step_fits_one_chip(one_chip, monkeypatch):
     params, _ = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), params)
     # steer the one platform question _build asks; nothing else is patched
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    entry = DecodeEntry("xl", model, params, **chip_smoke.SERVE_KV)
-    monkeypatch.undo()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        entry = DecodeEntry("xl", model, params, **chip_smoke.SERVE_KV)
     assert entry.paged
-    S = entry.num_slots
     caches = jax.tree.map(
         lambda a: _sds(one_chip, a.shape, a.dtype),
         jax.eval_shape(lambda p: model.make_paged_slot_caches(
             p, entry.pool_blocks, entry.kv_block), params))
+    return entry, params, caches
+
+
+_MOVES = re.compile(r"= \w+\[([\d,]*)\]\S* (copy|gather|transpose)\(")
+
+
+def _serves_from_the_pool(compiled, entry, caches):
+    """What `paged_slot_cached_attend` promises of the compiled program:
+    the pool is donated and updated in place, no pool is copied around the
+    write of the new tokens, and attention reads the pool's own buffer
+    (no per-slot copy of K or V, which was slots x blocks-a-slot x block
+    token lanes where the pool holds pool_blocks x block). The one array
+    larger than a layer's pool that either program still copies is the
+    tied token table (PERF.md section 7: the model head's, not this
+    layer's)."""
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= entry.kv_cache_bytes   # pool donated
+    _fits(compiled)
+    # temporaries were 4.67 GB (decode) and 2.33 GB (prefill 64) while the
+    # pool was relaid and gathered in every layer; the table's copy is
+    # 0.32 GB of what is left
+    assert m.temp_size_in_bytes < entry.kv_cache_bytes, m
+    pool = int(np.prod(caches[0].shape))
+    table = XL["vocab"] * XL["d"]
+    sizes = [(int(np.prod([int(d) for d in dims.split(",") if d])), op)
+             for dims, op in _MOVES.findall(compiled.as_text())]
+    assert len(sizes) > 50            # the pattern still reads this compiler
+    assert [s for s in sizes if s[0] > pool and s[0] != table] == []
+    # 0 pool-sized copies a layer: the write (a scatter of whole blocks
+    # along the block dimension) and the read (two matmuls batched over the
+    # leading head dimension) both take the pool row-major as it arrives
+    assert [s for s in sizes if s == (pool, "copy")] == []
+
+
+def test_gpt2_xl_paged_decode_step_fits_one_chip(one_chip, xl_entry):
+    """The decode program DecodeEntry jits, at the slots and KV pool
+    chip_smoke.py registers. The compiler counts arguments + temporaries
+    against the chip's HBM and refuses what does not fit."""
+    entry, params, caches = xl_entry
+    S = entry.num_slots
     vec = _sds(one_chip, (S,), np.int32)
     c = entry._jit_decode.lower(
         params, caches, vec, vec, _sds(one_chip, (S,), np.bool_),
         _sds(one_chip, (S, entry.blocks_per_slot), np.int32)).compile()
-    m = c.memory_analysis()
-    assert m.alias_size_in_bytes >= entry.kv_cache_bytes   # pool donated
-    _fits(c)
+    _serves_from_the_pool(c, entry, caches)
+
+
+def test_gpt2_xl_paged_prefill_chunk_fits_one_chip(one_chip, xl_entry):
+    """The largest prefill bucket of the same entry: the program that
+    writes the most blocks a call."""
+    entry, params, caches = xl_entry
+    S, C = entry.num_slots, entry.buckets[-1]
+    chunk = _sds(one_chip, (S, C), np.int32)
+    c = entry._jit_prefill.lower(
+        params, caches, chunk, chunk,
+        _sds(one_chip, (S, entry.blocks_per_slot), np.int32),
+        _sds(one_chip, (S,), np.int32)).compile()
+    _serves_from_the_pool(c, entry, caches)

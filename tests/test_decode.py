@@ -170,6 +170,9 @@ def _staggered_run(entry, submits, poison=False):
                         if r is None]
             if free:
                 idx = jnp.asarray(free)
+                if entry.paged:       # blocks lie along the pool's block axis
+                    from bigdl_tpu.nn.attention import PAGED_POOL_BLOCK_AXIS
+                    idx = (slice(None),) * PAGED_POOL_BLOCK_AXIS + (idx,)
                 sched._caches = jax.tree.map(
                     lambda a: a.at[idx].set(1e30), sched._caches)
         step += 1
@@ -466,7 +469,9 @@ def test_kv_shard_pool_sharding_asserted(lm):
                     kv_shard=True)
     e.precompile()                    # runs _assert_pool_sharding
     assert e._pool_sharding is not None
-    assert e._pool_sharding.spec == PartitionSpec(e._shard_axis)
+    from bigdl_tpu.nn.attention import PAGED_POOL_BLOCK_AXIS
+    assert e._pool_sharding.spec == PartitionSpec(
+        *[None] * PAGED_POOL_BLOCK_AXIS, e._shard_axis)
     assert e.pool_blocks % mesh.shape[e._shard_axis] == 0
     sched = DecodeScheduler(e, name="shrd", start=False)
     prompt = np.asarray([2, 3, 4, 5], np.int32)
