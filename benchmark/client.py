@@ -140,7 +140,7 @@ def main(argv=None):
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, required=True)
     ap.add_argument("--model", required=True)
-    ap.add_argument("--traffic", required=True)
+    ap.add_argument("--traffic", required=True, help="the mix's file")
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--vocab", type=int, required=True)
@@ -149,7 +149,7 @@ def main(argv=None):
     ap.add_argument("--timeout", type=float, default=120.0)
     ap.add_argument("--out", required=True)
     args = ap.parse_args(argv)
-    mix = trafficgen.load_mix(args.traffic)
+    mix = trafficgen.read_mix(args.traffic)
     run = Run(args, mix)
     bad = run.warm()
     if bad:

@@ -19,17 +19,22 @@ class NoChip(Exception):
     pass
 
 
+class Refused(ValueError):
+    """A name that the benchmark's directories do not hold, or a
+    configuration that asks its family for what it does not implement: the
+    run ends with this message and prints no result."""
+
+
 def load_json(*parts):
     with open(os.path.join(*parts)) as f:
         return json.load(f)
 
 
-def peaks_for(device_kind):
+def peaks_for(device_kind, dirs=(HERE,)):
     """The row of `benchmark/peaks/` that answers to this `device_kind`; a
     device that is in no row is an error, not a default."""
-    folder = os.path.join(HERE, "peaks")
-    for name in sorted(os.listdir(folder)):
-        row = load_json(folder, name)
+    for name in names_in(dirs, "peaks"):
+        row = load_json(found(dirs, "peaks", name + ".json", "chip"))
         if row["device_kind"] == device_kind:
             return row
     raise NoChip(f"device kind {device_kind!r} is in no file of "
@@ -78,7 +83,10 @@ def stop_trace(started):
         os.makedirs(os.path.dirname(keep) or ".", exist_ok=True)
         shutil.copy(pb, keep)
     shutil.rmtree(path, ignore_errors=True)
-    return R.reduce_trace(planes, span_s)
+    trace = R.reduce_trace(planes, span_s)
+    if trace is not None:
+        trace["span_at"], trace["span_s"] = t_started, span_s
+    return trace
 
 
 def device_block(dev, count, trace=None):
@@ -98,24 +106,75 @@ def free_device_memory():
     gc.collect()
 
 
-# What the benchmark's model builder, weights and plain reference implement.
-# A configuration that states anything else is refused: a new family, a new
-# activation or an untied head is code (a builder and a reference), not data.
-IMPLEMENTED = {"family": "gpt2", "activation_function": "gelu_new",
-               "tie_word_embeddings": True}
+def find(dirs, folder, filename):
+    """The first `<dir>/<folder>/<filename>` that exists, over the
+    directories `BENCHMARK.json` lists under `paths`; None if none does."""
+    for d in dirs:
+        path = os.path.join(d, folder, filename)
+        if os.path.isfile(path):
+            return path
+    return None
 
 
-def build_model(cfg):
-    """The program's model for a configuration's sizes, and its eos id."""
-    from bigdl_tpu.interop.huggingface import GPT2LM
-    for key, have in IMPLEMENTED.items():
-        if cfg.get(key) != have:
-            raise ValueError(f"configuration states {key}={cfg.get(key)!r}; "
-                             f"the benchmark implements {have!r} only")
-    eos = cfg["vocab_size"] - 1
-    return GPT2LM(cfg["vocab_size"], cfg["n_positions"], cfg["n_embd"],
-                  cfg["n_head"], cfg["n_layer"],
-                  ln_eps=cfg["layer_norm_epsilon"], eos_id=eos), eos
+def names_in(dirs, folder):
+    """The names (without endings) that `<dir>/<folder>/` hold."""
+    return sorted({os.path.splitext(f)[0] for d in dirs
+                   if os.path.isdir(os.path.join(d, folder))
+                   for f in os.listdir(os.path.join(d, folder))
+                   if not f.startswith(("_", "."))})
+
+
+def found(dirs, folder, filename, what):
+    """`find`, or the refusal that lists what `<folder>/` holds."""
+    path = find(dirs, folder, filename)
+    if path is None:
+        raise Refused(f"{what} {os.path.splitext(filename)[0]!r} is not in "
+                      f"{folder}/ of the benchmark (it holds "
+                      f"{names_in(dirs, folder)})")
+    return path
+
+
+_LOADED = {}        # path -> module: a plug-in is executed once a process
+
+
+def plug_in(dirs, folder, name, what):
+    """The module `<folder>/<name>` (one `.py` file, or a package with an
+    `__init__.py`) of the benchmark's directories, loaded from its file: a
+    family, a layer reader. A name they do not hold is refused with the
+    list of those they do."""
+    import importlib.util
+    path = find(dirs, os.path.join(folder, name), "__init__.py") \
+        or found(dirs, folder, name + ".py", what)
+    if path not in _LOADED:
+        spec = importlib.util.spec_from_file_location(
+            f"benchmark_{folder}_{name}", path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        _LOADED[path] = module
+    return _LOADED[path]
+
+
+def family_of(cfg, dirs=(HERE,)):
+    """The family a configuration names: the module that holds its model
+    builder, weights, plain reference and shape functions (PERF.md, section
+    3). It refuses a configuration that states what it does not implement."""
+    family = plug_in(dirs, "families", str(cfg.get("family")), "family")
+    try:
+        family.check(cfg)
+    except ValueError as e:
+        raise Refused(str(e)) from e
+    return family
+
+
+def control_of(family, control, faults=()):
+    """`--control <mode>` as the cell can run it: an arithmetic mode that the
+    family's reference implements, or one of the cell's own `faults`."""
+    if control is None or control in faults or control in family.MODES:
+        return control
+    raise Refused(f"control {control!r}: the family's reference has the "
+                  f"modes {list(family.MODES)}"
+                  + (f" and the cell the faults {list(faults)}"
+                     if faults else ""))
 
 
 def named(table, key, what):
@@ -127,14 +186,15 @@ def named(table, key, what):
 
 def layout_matches(model, params):
     """Fail loudly where the program's parameter tree is no longer the one
-    `benchmark/weights.py` makes (shapes and dtypes, by `eval_shape`)."""
+    the family's `program_params` makes (shapes and dtypes, by
+    `eval_shape`)."""
     import jax
     want, _ = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     a = jax.tree.map(lambda x: (tuple(x.shape), str(x.dtype)), want)
     b = jax.tree.map(lambda x: (tuple(x.shape), str(x.dtype)), params)
     if a != b:
         raise RuntimeError("the program's parameter layout is not the one "
-                           "benchmark/weights.py makes")
+                           "the family's program_params makes")
 
 
 class Checks:

@@ -2,9 +2,11 @@
 
 Kept with the benchmark so that every PR computes the same number in the
 same way: percentiles, pooled inter-token gaps, the busy union of a device
-trace, per-module device time, and the FLOPs a GPT-2 needs for a token
-(shape functions, not the compiler's cost analysis, which counts what XLA
-emitted). `benchmark/tests/test_reduce.py` checks each on the CPU.
+trace, per-module device time, and the FLOPs a window needed: the sum, over
+the tokens it processed, of what the configuration's family reckons for a
+token (shape functions in `benchmark/families/`, not the compiler's cost
+analysis, which counts what XLA emitted). `benchmark/tests/test_reduce.py`
+checks each on the CPU.
 """
 
 import glob
@@ -64,51 +66,29 @@ def serve_metrics(records, t0, seconds):
 
 
 # ----------------------------------------------------------------- FLOPs
-def gpt2_parameters(cfg):
-    """Parameter counts of a GPT-2 from its sizes alone."""
-    d, L = cfg["n_embd"], cfg["n_layer"]
-    per_layer = 12 * d * d + 13 * d
-    non_embedding = L * per_layer + 2 * d
-    embedding = cfg["vocab_size"] * d + cfg["n_positions"] * d
-    return {"non_embedding": non_embedding, "embedding": embedding,
-            "head": cfg["vocab_size"] * d,
-            "total": non_embedding + embedding}
-
-
-def serve_token_flops(cfg, position, logits):
-    """FLOPs the model needs to process one token at `position` (counting
-    from 0) through the cache: 2 a parameter outside the embeddings, the
-    head where logits are taken, and 4 d per layer per live position."""
-    p = gpt2_parameters(cfg)
-    live = position + 1
-    return (2.0 * p["non_embedding"] + (2.0 * p["head"] if logits else 0.0)
-            + 4.0 * cfg["n_embd"] * live * cfg["n_layer"])
-
-
-def serve_window_flops(cfg, records, t0, seconds):
-    """FLOPs needed for what the window processed: a request's prompt
-    (all but its last token go through prefill, without logits) counts if
-    its first token arrived in the window, and each output token if it did."""
+def window_tokens(records, t0, seconds):
+    """What a serving window processed, as (position, logits) of each token
+    that went through the model: a request's prompt (all but its last token
+    go through prefill, without logits) counts if its first token arrived
+    in the window, and each output token if it did."""
     inside = lambda s: t0 <= s < t0 + seconds                # noqa: E731
-    total = 0.0
+    out = []
     for r in records:
         P = r["prompt_len"]
         if r["stamps"] and inside(r["stamps"][0]):
-            total += sum(serve_token_flops(cfg, i, False)
-                         for i in range(P - 1))
-        for k, s in enumerate(r["stamps"]):
-            if inside(s):
-                total += serve_token_flops(cfg, P - 1 + k, True)
-    return total
+            out.extend((i, False) for i in range(P - 1))
+        out.extend((P - 1 + k, True) for k, s in enumerate(r["stamps"])
+                   if inside(s))
+    return out
 
 
-def train_token_flops(cfg, seq):
-    """FLOPs a trained token needs, forward and backward, recomputation not
-    counted: 6 a parameter outside the embeddings, 6 for the head, and the
-    causal half of attention (6 L d seq)."""
-    p = gpt2_parameters(cfg)
-    return (6.0 * p["non_embedding"] + 6.0 * p["head"]
-            + 6.0 * cfg["n_layer"] * cfg["n_embd"] * seq)
+def serve_window_flops(token_flops, records, t0, seconds):
+    """FLOPs needed for what the window processed: the family's
+    `token_flops(position, logits)` summed over `window_tokens`. (Every
+    term is a whole number far under 2**53, so the order of the sum does
+    not matter.)"""
+    return float(sum(token_flops(p, logits)
+                     for p, logits in window_tokens(records, t0, seconds)))
 
 
 # ----------------------------------------------------------------- trace
@@ -146,7 +126,7 @@ def short_name(name):
 
 def busy_union_ns(events):
     """Length of the union of the events' intervals."""
-    spans = sorted((s, s + d) for _, s, d in events)
+    spans = sorted((e[1], e[1] + e[2]) for e in events)
     total, cur_s, cur_e = 0.0, None, None
     for s, e in spans:
         if cur_e is None or s > cur_e:
@@ -163,7 +143,7 @@ def busy_union_ns(events):
 def idle_gaps(events, top=10):
     """The longest stretches in which no event ran, each named by the
     event that ended it (what the device was waiting to be given)."""
-    spans = sorted((s, s + d, n) for n, s, d in events)
+    spans = sorted((e[1], e[1] + e[2], e[0]) for e in events)
     gaps, end = [], None
     for s, e, name in spans:
         if end is not None and s > end:
@@ -184,9 +164,9 @@ def op_kind(name):
 def top_ops(events, top=10):
     """Device seconds by kind of op, the largest first."""
     by = {}
-    for name, _, d in events:
-        name = op_kind(name)
-        by[name] = by.get(name, 0.0) + d * 1e-9
+    for e in events:
+        name = op_kind(e[0])
+        by[name] = by.get(name, 0.0) + e[2] * 1e-9
     return [[n, t] for n, t in sorted(by.items(), key=lambda kv: -kv[1])[:top]]
 
 
@@ -205,8 +185,8 @@ def reduce_trace(planes, span_s=0.0):
             continue
         mods = lines.get(MODULES_LINE, [])
         every = ops + mods
-        lo = min(s for _, s, _ in every)
-        hi = max(s + d for _, s, d in every)
+        lo = min(e[1] for e in every)
+        hi = max(e[1] + e[2] for e in every)
         chips.append({"plane": name, "busy_s": busy_union_ns(ops) * 1e-9,
                       "window_s": max((hi - lo) * 1e-9, span_s), "ops": ops,
                       "modules": mods})
@@ -222,8 +202,17 @@ def reduce_trace(planes, span_s=0.0):
 def module_ms(trace, contains):
     """Mean device time of one run of the module whose name holds
     `contains`, over the runs the trace holds whole; None if it has none."""
-    runs = [d for c in trace["chips"] for n, _, d in c["modules"]
-            if contains in n]
+    runs = [e[2] for c in trace["chips"] for e in c["modules"]
+            if contains in e[0]]
     if not runs:
         return None
     return float(np.mean(runs)) * 1e-6
+
+
+def matching_op_seconds(trace, pattern):
+    """Device seconds, summed over the chips, of the `XLA Ops` events in
+    whose name the compiled regular expression is found. On the TPU an
+    event's name is its HLO instruction as text, without metadata:
+    `%<name> = <shape> <op>(<operands with shapes>), <attributes>`."""
+    return 1e-9 * sum(e[2] for c in trace["chips"] for e in c["ops"]
+                      if pattern.search(e[0]))

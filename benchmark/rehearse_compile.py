@@ -1,16 +1,16 @@
 """Compile a cell's programs for a described TPU v5e, without a chip.
 
-    JAX_PLATFORMS=cpu python3 benchmark/rehearse_compile.py train 16 8 4 2
-    JAX_PLATFORMS=cpu python3 benchmark/rehearse_compile.py serve
+    JAX_PLATFORMS=cpu python3 benchmark/rehearse_compile.py <config> [batch ...]
 
 Nothing runs: the TPU compiler installed beside JAX compiles for a device
 that is described and not attached, and `memory_analysis()` says whether
-the program fits the chip's 16 GB. `train` sizes the training batch of
-`gpt2-medium-train` (the largest of the given batches whose
-`bigdl_train_step` fits); `serve` compiles the paged decode step and the
-largest prefill bucket of `gpt2-xl-serve`. The figures are copied into the
-configuration files under `assumed` and into PERF.md by hand: a compile
-that passes is not a chip run.
+the program fits the chip's 16 GB. The argument is a configuration's name
+(`benchmark/configs/<name>.json`); the model is built by the
+configuration's family, as a cell builds it. For a configuration of the kind `train` the given batches (or 16, 8,
+4, 2) are tried and `bigdl_train_step` is compiled at each; for one of the
+kind `serve`, the paged decode step and the largest prefill bucket. The
+figures are copied into the configuration files under `assumed` and into
+PERF.md by hand: a compile that passes is not a chip run.
 """
 
 import json
@@ -20,14 +20,10 @@ import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 HERE = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, os.path.dirname(HERE))
+sys.path.insert(0, HERE)
+sys.path.insert(1, os.path.dirname(HERE))
 
 HBM = 16 * 2 ** 30
-
-
-def _cfg(name):
-    with open(os.path.join(HERE, "configs", name + ".json")) as f:
-        return json.load(f)
 
 
 def _report(what, compiled, seconds):
@@ -61,29 +57,31 @@ def main(argv):
     def on_chip(tree):
         return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
 
-    from bigdl_tpu.interop.huggingface import GPT2LM
-    if argv[0] == "train":
-        import bigdl_tpu.nn as nn
-        from bigdl_tpu.optim.local import Optimizer
-        from bigdl_tpu.optim.method import Adam
-        c = _cfg("gpt2-medium-train")
-        model = GPT2LM(c["vocab_size"], c["n_positions"], c["n_embd"],
-                       c["n_head"], c["n_layer"], eos_id=c["vocab_size"] - 1)
+    import common as C
+    import train_cell
+    try:
+        c = C.load_json(C.found([HERE], "configs", argv[0] + ".json",
+                                "configuration"))
+    except C.Refused as e:
+        raise SystemExit(f"rehearse_compile: {e}")
+    model, _ = C.family_of(c).build_model(c)
+    params, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = on_chip(jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, jnp.dtype(c["weights_dtype"])), params))
+    if c["kind"] == "train":
         t = c["trainer"]
-        seq = t["sequence"]
-        params, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        method = Adam(t["learning_rate"])
+        seq, compute = t["sequence"], jnp.dtype(t["compute_dtype"])
+        trainer, method, criterion = train_cell.trainer_parts(t)
         slots = jax.eval_shape(method.init_slots, params)
         for batch in [int(b) for b in argv[1:]] or [16, 8, 4, 2]:
-            opt = Optimizer(model, [], nn.TimeDistributedMaskCriterion(
-                nn.CrossEntropyCriterion(), padding_value=-1), method,
-                seed=0, compute_dtype=jnp.bfloat16)
-            step = jax.jit(opt._make_step(jnp.bfloat16),
+            opt = trainer(model, [], criterion, method, seed=0,
+                          compute_dtype=compute)
+            step = jax.jit(opt._make_step(compute),
                            donate_argnums=(0, 1, 2))
             t0 = time.perf_counter()
             try:
                 compiled = step.lower(
-                    on_chip(params), on_chip(state), on_chip(slots),
+                    params, on_chip(state), on_chip(slots),
                     sds((batch, seq), np.int32), sds((batch, seq), np.int32),
                     sds((), jnp.float32), sds((), jnp.int32),
                     on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
@@ -96,11 +94,6 @@ def main(argv):
                     time.perf_counter() - t0)
     else:
         from bigdl_tpu.serve.decode import DecodeEntry
-        c = _cfg("gpt2-xl-serve")
-        model = GPT2LM(c["vocab_size"], c["n_positions"], c["n_embd"],
-                       c["n_head"], c["n_layer"], eos_id=c["vocab_size"] - 1)
-        params, _ = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        params = on_chip(params)
         real = jax.default_backend
         jax.default_backend = lambda: "tpu"     # the one question _build asks
         try:
@@ -117,14 +110,16 @@ def main(argv):
         compiled = entry._jit_decode.lower(
             params, caches, vec, vec, sds((S,), np.bool_), table).compile()
         _report("paged decode step", compiled, time.perf_counter() - t0)
-        C = entry.buckets[-1]
+        chunk = entry.buckets[-1]
         t0 = time.perf_counter()
         compiled = entry._jit_prefill.lower(
-            params, caches, sds((S, C), np.int32), sds((S, C), np.int32),
-            table, vec).compile()
-        _report(f"paged prefill chunk {C}", compiled,
+            params, caches, sds((S, chunk), np.int32),
+            sds((S, chunk), np.int32), table, vec).compile()
+        _report(f"paged prefill chunk {chunk}", compiled,
                 time.perf_counter() - t0)
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:] or ["train"])
+    if len(sys.argv) < 2:
+        raise SystemExit(__doc__)
+    main(sys.argv[1:])

@@ -6,8 +6,10 @@
 One process, the only one to touch JAX (the load generator of a serving
 cell is a child that never imports it). The cell is looked up in
 `BENCHMARK.json` at the root of the checkout; its configuration, traffic
-mix, per-layer metrics and the chip's peaks are data files found by name
-(PERF.md says how to add one). Set-up makes the weights on the device from
+mix, per-layer metrics and the chip's peaks are data files, and its model
+family and the readers of its metrics are modules, all found by name in the
+directories that `BENCHMARK.json` lists under `paths` (PERF.md, section 3,
+says how to add one). Set-up makes the weights on the device from
 the seed and warms exactly the cell's programs; the window measures; then
 the timed path's output is compared with the plain reference. The last line
 of standard output is the result. Without a TPU whose `device_kind` is in
@@ -16,11 +18,13 @@ of standard output is the result. Without a TPU whose `device_kind` is in
 `--rehearse` runs the configuration's `tiny` block on whatever backend JAX
 finds, to rehearse the control flow on the CPU: its line says `"rehearsal":
 true` and `cpu`, and is never a measurement. `--control <mode>` puts the
-reference, computed in a lower precision (`bfloat16`, `fp8`) or on half of
-each batch (`half_batch`), in the program's place: the run's checks hold
-what the control reads, so `correct` has to come out false; what the
-program itself read is on the notes line. It is for setting limits and
-for showing that they bite, and the driver never passes it.
+reference, computed in a lower precision or with a fault planted, in the
+program's place: one of the modes that the configuration states under
+`controls` and that its family's reference (or, for a fault such as half of
+each batch, its kind of cell) implements. The run's checks hold what the
+control reads, so `correct` has to come out false; what the program itself
+read is on the notes line. It is for setting limits and for showing that
+they bite, and the driver never passes it.
 """
 
 import time
@@ -57,7 +61,10 @@ def load_cell(workload, rehearse):
     sizes = dict(cfg)
     if rehearse:
         sizes.update(cfg["tiny"])
-    cell = dict(cell, sizes=sizes, mix=trafficgen.load_mix(cell["traffic"]))
+    dirs = [os.path.join(ROOT, p) for p in bench["paths"]]
+    mix_file = C.found(dirs, "traffic", cell["traffic"] + ".json", "traffic")
+    cell = dict(cell, sizes=sizes, dirs=dirs, mix_file=mix_file,
+                mix=trafficgen.read_mix(mix_file))
 
     def reported(metric):
         return workload in metric.get("workloads", [workload])
@@ -73,10 +80,17 @@ def main(argv=None):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true")
-    ap.add_argument("--control", default=None,
-                    choices=("bfloat16", "fp8", "half_batch"))
+    ap.add_argument("--control", default=None)
     args = ap.parse_args(argv)
 
+    import common as C
+    try:
+        return run_cell(args)
+    except C.Refused as e:          # a name or a mode the benchmark lacks
+        raise SystemExit(f"benchmark: {e}")
+
+
+def run_cell(args):
     import common as C
     cell = load_cell(args.workload, args.rehearse)
     if args.control and args.control not in cell["sizes"]["controls"]:
@@ -94,7 +108,7 @@ def main(argv=None):
             raise SystemExit(f"benchmark: no TPU — JAX found {len(devices)} "
                              f"x {dev.platform} ({dev.device_kind})")
         try:
-            peaks = C.peaks_for(dev.device_kind)
+            peaks = C.peaks_for(dev.device_kind, cell["dirs"])
         except C.NoChip as e:
             raise SystemExit(f"benchmark: {e}")
     if len(devices) < cell["chips"]:
@@ -112,8 +126,7 @@ def main(argv=None):
         import train_cell as runner
     else:
         raise SystemExit(f"benchmark: configuration kind {kind!r}")
-    out = runner.run(env, cell)
-    return report(args, cell, out)
+    return report(args, cell, runner.run(env, cell))
 
 
 def report(args, cell, out):

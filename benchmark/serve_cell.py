@@ -2,6 +2,7 @@
 the client process, for `--seconds`; then the served tokens against the
 plain reference."""
 
+import functools
 import json
 import os
 import subprocess
@@ -14,9 +15,9 @@ import numpy as np
 import common as C
 import reduce as R
 import trafficgen
-import weights
 
-MODEL_NAME = "gpt2"
+# one word for every family: the histograms are found through `{model}`
+MODEL_NAME = "model"
 
 
 def _reader_thread(stream, lines, event):
@@ -65,8 +66,9 @@ def gap_numbers(gaps):
             "off_argmax_share": float((gaps > 0).mean())}
 
 
-def check_outputs(env, cfg, mix, requests, records, control=None):
-    """The served tokens against the reference (`reference.served_gaps`):
+def check_outputs(env, family, cfg, mix, requests, records, control=None):
+    """The served tokens against the family's reference
+    (`reference.served_gaps_of`):
     by how much a served token's logit lies below the reference's best, as
     the mean of its square over every served token of every request
     compared (the widest gap and the plain mean keep the program and its
@@ -80,13 +82,13 @@ def check_outputs(env, cfg, mix, requests, records, control=None):
     if not sample:
         return None
     longest = max(r["prompt_len"] + r["asked"] for r in sample)
-    pad_to = min(cfg["n_positions"], -(-longest // 64) * 64)
-    w = weights.stacked(env["seed"], cfg)
+    pad_to = min(family.sizes(cfg)["positions"], -(-longest // 64) * 64)
+    w = family.stacked(env["seed"], cfg)
+    served_gaps = reference.served_gaps_of(family, cfg, control)
     gaps, control_gaps, finite = [], [], True
     for r in sample:
         prompt = requests[r["index"]]["prompt"]
-        g = reference.served_gaps(w, cfg, prompt, r["tokens"], pad_to,
-                                  control_mode=control)
+        g = served_gaps(w, prompt, r["tokens"], pad_to)
         finite &= g["finite"]
         gaps.append(g["gap"])
         if control:
@@ -114,12 +116,15 @@ def run(env, cell):
 
     cfg, mix, seconds = cell["sizes"], cell["mix"], env["seconds"]
     dev = env["device"]
-    model, eos = C.build_model(cfg)
+    family = C.family_of(cfg, cell["dirs"])
+    control = C.control_of(family, env.get("control"))
+    model, eos = family.build_model(cfg)
+    vocab = family.sizes(cfg)["vocab"]
     reg = dict(cfg["register"])
-    requests = trafficgen.Requests(mix, env["seed"], cfg["vocab_size"], eos,
+    requests = trafficgen.Requests(mix, env["seed"], vocab, eos,
                                    reg["max_seq_len"])
-    params = weights.program_params(env["seed"], cfg,
-                                    jnp.dtype(cfg["weights_dtype"]))
+    params = family.program_params(env["seed"], cfg,
+                                   jnp.dtype(cfg["weights_dtype"]))
     C.layout_matches(model, params)
     _, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))  # no leaves
     compiles = env["compiles"]
@@ -138,9 +143,9 @@ def run(env, cell):
         child = subprocess.Popen(
             [sys.executable, os.path.join(C.HERE, "client.py"),
              "--host", front.host, "--port", str(front.port),
-             "--model", MODEL_NAME, "--traffic", cell["traffic"],
+             "--model", MODEL_NAME, "--traffic", cell["mix_file"],
              "--seed", str(env["seed"]), "--seconds", str(seconds),
-             "--vocab", str(cfg["vocab_size"]), "--eos", str(eos),
+             "--vocab", str(vocab), "--eos", str(eos),
              "--max-seq-len", str(reg["max_seq_len"]),
              "--out", records_path],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
@@ -191,14 +196,14 @@ def run(env, cell):
     m = R.serve_metrics(records, t0, seconds)
     in_window = [r for r in records if t0 <= r["sent"] < t0 + seconds]
     t_check = C.now()
-    checked = check_outputs(env, cfg, mix, requests, in_window,
-                            control=env.get("control"))
+    checked = check_outputs(env, family, cfg, mix, requests, in_window,
+                            control=control)
     check_s = C.now() - t_check
 
     checks = C.Checks()
     limits = cfg["limits"]
     # under `--control` the lower precision stands in the program's place
-    held = checked["control"] if checked and env.get("control") else checked
+    held = checked["control"] if checked and control else checked
     checks.at_most("served_gap_sq_mean",
                    held["gap_sq_mean"] if checked and checked["finite"]
                    else float("nan"), limits["served_gap_sq_mean"])
@@ -206,7 +211,14 @@ def run(env, cell):
     checks.at_most("compiles_in_window", compiled_in_window, 0)
     ctx = {"before": before, "after": after, "trace": trace, "client": m,
            "model_name": MODEL_NAME, "window_s": seconds, "peaks": env["peaks"],
-           "needed_flops": R.serve_window_flops(cfg, records, t0, seconds)}
+           "cfg": cfg, "family": family, "dirs": cell["dirs"],
+           "needed_flops": R.serve_window_flops(
+               functools.partial(family.serve_token_flops, cfg), records, t0,
+               seconds)}
+    if trace:       # what the traced span processed (the client's stamps
+        #             are on the same clock), for a kernel's roofline share
+        ctx["traced_work"] = {"tokens": R.window_tokens(
+            records, trace["span_at"], trace["span_s"])}
     # every statistic of the client's stamps: BENCHMARK.json names the ones
     # that are end-to-end metrics, the rest stay on the notes line
     end_to_end = dict({k: v for k, v in m.items()

@@ -123,22 +123,22 @@ def test_serving_control_in_bfloat16_in_the_programs_place_is_not_correct(
 
 def test_served_gaps_agree_with_the_logits_taken_whole():
     """The on-device reduction reads what the full logits say."""
+    import common as C
     import reference
-    import weights
+    gpt2 = C.plug_in((C.HERE,), "families", "gpt2", "family")
     cfg = {"vocab_size": 512, "n_positions": 32, "n_embd": 32, "n_head": 4,
            "n_layer": 2, "layer_norm_epsilon": 1e-5}
-    w = weights.stacked(3, cfg)
+    w = gpt2.stacked(3, cfg)
     rng = np.random.default_rng(3)
     prompt, served = rng.integers(0, 511, 9).tolist(), \
         rng.integers(0, 511, 7).tolist()
-    g = reference.served_gaps(w, cfg, prompt, served, 32,
-                              control_mode="bfloat16")
+    g = reference.served_gaps_of(gpt2, cfg, "bfloat16")(
+        w, prompt, served, 32)
     tokens = np.zeros((1, 32), np.int32)
     tokens[0, :16] = prompt + served
-    kw = dict(n_head=4, eps=1e-5)
-    ref = np.asarray(reference.logits_fn(w, tokens, mode="float32", **kw))[0]
-    low = np.asarray(reference.logits_fn(
-        w, tokens, mode="bfloat16", **kw))[0].astype(np.float32)
+    ref = np.asarray(gpt2.logits(w, cfg, tokens, "float32"))[0]
+    low = np.asarray(gpt2.logits(
+        w, cfg, tokens, "bfloat16"))[0].astype(np.float32)
     at = np.arange(8, 15)
     assert np.allclose(g["gap"], ref[at].max(-1) - ref[at, served], atol=1e-6)
     every = ref[:15].max(-1) - ref[np.arange(15), low[:15].argmax(-1)]

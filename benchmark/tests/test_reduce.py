@@ -3,16 +3,20 @@ gaps, the busy union and idle share of a trace, per-module device time (on
 hand-made events and on a recorded TPU trace), and the FLOP shape functions
 against the parameter counts the program's own models have."""
 
+import functools
 import json
 import os
+import re
 
 import numpy as np
 import pytest
 
+import common as C
 import reduce as R
 import readers
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+GPT2 = C.plug_in((C.HERE,), "families", "gpt2", "family")
 
 
 def test_percentile_is_linear_between_order_statistics():
@@ -160,7 +164,7 @@ def test_parameter_counts_match_the_programs_model_and_the_paper(
     from bigdl_tpu.interop.huggingface import GPT2LM
     cfg = json.load(open(os.path.join(
         os.path.dirname(HERE), "configs", config + ".json")))
-    p = R.gpt2_parameters(cfg)
+    p = GPT2.parameters(cfg)
     assert p["total"] == published
     model = GPT2LM(cfg["vocab_size"], cfg["n_positions"], cfg["n_embd"],
                    cfg["n_head"], cfg["n_layer"], eos_id=0)
@@ -171,20 +175,109 @@ def test_parameter_counts_match_the_programs_model_and_the_paper(
 
 def test_token_flops_follow_the_shape_functions():
     cfg = {"n_embd": 8, "n_layer": 2, "vocab_size": 11, "n_positions": 16}
-    p = R.gpt2_parameters(cfg)
+    p = GPT2.parameters(cfg)
+    token_flops = functools.partial(GPT2.serve_token_flops, cfg)
     assert p["non_embedding"] == 2 * (12 * 64 + 13 * 8) + 16
-    assert R.serve_token_flops(cfg, 0, False) == 2 * p["non_embedding"] \
-        + 4 * 8 * 1 * 2
-    assert R.serve_token_flops(cfg, 9, True) == 2 * p["non_embedding"] \
+    assert token_flops(0, False) == 2 * p["non_embedding"] + 4 * 8 * 1 * 2
+    assert token_flops(9, True) == 2 * p["non_embedding"] \
         + 2 * 88 + 4 * 8 * 10 * 2
-    assert R.train_token_flops(cfg, 16) == 6 * p["non_embedding"] \
+    assert GPT2.train_token_flops(cfg, 16) == 6 * p["non_embedding"] \
         + 6 * 88 + 6 * 2 * 8 * 16
     # a request of 3 prompt tokens and 2 outputs, all inside the window:
     # positions 0, 1 by prefill, 2 and 3 by decode steps with logits
     rec = _rec(1.0, [1.5, 1.6], prompt_len=3)
-    want = sum(R.serve_token_flops(cfg, i, False) for i in (0, 1)) \
-        + sum(R.serve_token_flops(cfg, i, True) for i in (2, 3))
-    assert R.serve_window_flops(cfg, [rec], 0.0, 10.0) == want
+    want = sum(token_flops(i, False) for i in (0, 1)) \
+        + sum(token_flops(i, True) for i in (2, 3))
+    assert R.window_tokens([rec], 0.0, 10.0) == [
+        (0, False), (1, False), (2, True), (3, True)]
+    assert R.serve_window_flops(token_flops, [rec], 0.0, 10.0) == want
     # its second token outside the window: that token alone is left out
-    assert R.serve_window_flops(cfg, [rec], 0.0, 1.55) == \
-        want - R.serve_token_flops(cfg, 3, True)
+    assert R.serve_window_flops(token_flops, [rec], 0.0, 1.55) == \
+        want - token_flops(3, True)
+
+
+def test_counter_delta_reads_what_a_counter_gained_in_the_window():
+    before = {"counters": {"hits": 10.0, "lookups": 40.0}}
+    after = {"counters": {"hits": 25.0, "lookups": 100.0, "born_inside": 7.0,
+                          "serve/m/evictions": 3.0}}
+    ctx = {"before": before, "after": after, "window_s": 5.0,
+           "model_name": "m"}
+    assert readers.counter_delta(ctx, "hits") == pytest.approx(15.0)
+    assert readers.counter_delta(ctx, "born_inside") == pytest.approx(7.0)
+    assert readers.counter_delta(ctx, "serve/{model}/evictions") == 3.0
+    assert readers.counter_delta(ctx, "hits", over="lookups",
+                                 scale=100.0) == pytest.approx(25.0)
+    assert readers.counter_delta(ctx, "hits", per_second=True) == \
+        pytest.approx(3.0)
+    assert readers.counter_delta(ctx, "never_counted") is None
+    assert readers.counter_delta(ctx, "hits", over="never_counted") is None
+    still = dict(ctx, after=dict(after, counters=dict(after["counters"],
+                                                      lookups=40.0)))
+    assert readers.counter_delta(still, "hits", over="lookups") is None
+
+
+class _Family:
+    """Reckons a stated amount of work for one kernel and none for others."""
+
+    @staticmethod
+    def kernel_work(cfg, kernel, tokens):
+        if kernel != "table_copy":
+            return None
+        return {"flops": 2.0e9 * len(tokens), "bytes": cfg["bytes_a_token"]
+                * len(tokens)}
+
+
+def test_kernel_roofline_on_the_recorded_trace():
+    """The `copy` ops of the recorded trace against a stated amount of work:
+    the share is the least time the chip could take over their device time,
+    bound here by bytes; events are matched on their name, the HLO text."""
+    t = R.reduce_trace(R.read_device_lines(
+        os.path.join(HERE, "recorded_v5e.xplane.pb")))
+    copy_s = dict(t["device_ops"])["copy"]
+    assert copy_s == pytest.approx(0.019975915, rel=1e-6)     # 402 events
+    assert R.matching_op_seconds(t, re.compile(r"^%copy(\.\d+)? = ")) == \
+        pytest.approx(copy_s, rel=1e-9)
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    ctx = {"trace": t, "family": _Family, "peaks": peaks,
+           "cfg": {"bytes_a_token": 1.0e9},
+           "traced_work": {"tokens": [(5, True), (6, True)]}}
+    args = {"kernel": "table_copy", "match": r"^%copy(\.\d+)? = "}
+    least = max(4.0e9 / 197e12, 2.0e9 / 819e9)          # the bytes bound it
+    assert readers.kernel_roofline(ctx, **args) == pytest.approx(
+        100.0 * least / copy_s)
+    assert 0.0 < readers.kernel_roofline(ctx, **args) < 100.0
+    # nothing to read is None, never 0: no event matches, the family reckons
+    # no such kernel or none at all, the run has no trace or no traced work
+    assert readers.kernel_roofline(ctx, "table_copy", r"no_such_op") is None
+    assert readers.kernel_roofline(ctx, "other", args["match"]) is None
+    assert readers.kernel_roofline(dict(ctx, family=object()), **args) is None
+    assert readers.kernel_roofline(dict(ctx, trace=None), **args) is None
+    assert readers.kernel_roofline(dict(ctx, traced_work=None), **args) is None
+    # a Pallas call's `name` is its instruction's name on the chip (PERF.md)
+    planes = {"/device:TPU:0": {R.OPS_LINE: [
+        ("%fusion.7 = f32[8,30,96,192]{3,2,1,0} fusion(...)", 0.0, 4e6),
+        ("%probe_kernel.1 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} "
+         "%fusion.7), custom_call_target=\"tpu_custom_call\"", 5e6, 6e6)]}}
+    small = R.reduce_trace(planes)
+    assert R.matching_op_seconds(
+        small, re.compile(r"^%probe_kernel(\.\d+)? = ")) == pytest.approx(6e-3)
+    assert R.matching_op_seconds(
+        small, re.compile(r"\[\d+,30,96,192\]")) == pytest.approx(4e-3)
+
+
+def test_an_unknown_reader_is_refused_by_name(tmp_path):
+    (tmp_path / "layer_metrics").mkdir()
+    (tmp_path / "layer_metrics" / "m.json").write_text(
+        '{"reader": "no_such_reader", "args": {}}')
+    (tmp_path / "layer_metrics" / "mine.json").write_text(
+        '{"reader": "twice", "args": {"stat": "ttft_p50_ms"}}')
+    ctx = {"dirs": [str(tmp_path)], "client": {"ttft_p50_ms": 21.0}}
+    with pytest.raises(C.Refused, match=r"'no_such_reader'.*counter_delta"):
+        readers.read_metric("m", ctx)
+    with pytest.raises(C.Refused, match="'absent'"):
+        readers.read_metric("absent", ctx)
+    # a reader of a later PR's own is a file found by name
+    (tmp_path / "layer_readers").mkdir()
+    (tmp_path / "layer_readers" / "twice.py").write_text(
+        "def read(ctx, stat):\n    return 2 * ctx['client'][stat]\n")
+    assert readers.read_metric("mine", ctx) == 42.0

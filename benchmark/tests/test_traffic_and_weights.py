@@ -3,8 +3,11 @@
 import numpy as np
 import pytest
 
+import common as C
 import trafficgen
 import weights
+
+GPT2 = C.plug_in((C.HERE,), "families", "gpt2", "family")
 
 TINY = {"vocab_size": 97, "n_positions": 64, "n_embd": 32, "n_head": 4,
         "n_layer": 3}
@@ -85,13 +88,13 @@ def test_a_mix_that_cannot_fit_a_slot_is_refused():
 def test_both_layouts_hold_the_same_weights_bit_for_bit():
     import jax
     seed = 2 ** 31 + 7
-    prog = weights.program_params(seed, TINY)
-    again = weights.program_tree(weights.stacked(seed, TINY))
+    prog = GPT2.program_params(seed, TINY)
+    again = GPT2.program_tree(GPT2.stacked(seed, TINY))
     a, b = jax.tree.leaves(prog), jax.tree.leaves(again)
     assert jax.tree.structure(prog) == jax.tree.structure(again)
     assert all(np.array_equal(np.asarray(x), np.asarray(y))
                for x, y in zip(a, b))
-    other = weights.program_params(seed + 1, TINY)
+    other = GPT2.program_params(seed + 1, TINY)
     assert not np.array_equal(np.asarray(prog["wte"]),
                               np.asarray(other["wte"]))
     assert not np.array_equal(np.asarray(prog["h0"]["attn"]["wq"]),
@@ -100,9 +103,9 @@ def test_both_layouts_hold_the_same_weights_bit_for_bit():
 
 def test_a_stated_embedding_spread_changes_the_two_tables_and_nothing_else():
     import jax
-    plain = weights.stacked(11, TINY)
+    plain = GPT2.stacked(11, TINY)
     assert weights.init_std(TINY) == (weights.STD, weights.STD)
-    small = weights.stacked(11, dict(TINY, init={"embedding_std": 0.002}))
+    small = GPT2.stacked(11, dict(TINY, init={"embedding_std": 0.002}))
     ratio = np.asarray(small["wte"]) / np.asarray(plain["wte"])
     assert np.allclose(ratio, 0.1, rtol=1e-5)
     assert float(np.std(np.asarray(small["wpe"]))) == pytest.approx(

@@ -30,12 +30,16 @@ POOL = 256
 BLOCK = 32
 
 
-def load_mix(name):
-    with open(os.path.join(HERE, "traffic", name + ".json")) as f:
+def read_mix(path):
+    with open(path) as f:
         mix = json.load(f)
     if mix.get("kind") not in ("closed_loop", "open_loop", "train"):
-        raise ValueError(f"traffic {name!r}: unknown kind {mix.get('kind')!r}")
+        raise ValueError(f"traffic {path}: unknown kind {mix.get('kind')!r}")
     return mix
+
+
+def load_mix(name):
+    return read_mix(os.path.join(HERE, "traffic", name + ".json"))
 
 
 def quantiles(dist, n):

@@ -4,10 +4,10 @@ the clock; its first three steps against the plain reference."""
 import numpy as np
 
 import common as C
-import reduce as R
 import weights
 
 BETA1 = 0.9                 # Adam's, as the configuration's method has it
+FAULTS = ("half_batch",)    # what `--control` can plant besides a mode
 
 
 def trainer_parts(t):
@@ -153,15 +153,17 @@ def run(env, cell):
     checked, warm = int(mix["checked_steps"]), int(mix["warmup_steps"])
     seed, dev = env["seed"], env["device"]
 
-    model, _ = C.build_model(cfg)
+    family = C.family_of(cfg, cell["dirs"])
+    control = C.control_of(family, env.get("control"), FAULTS)
+    model, _ = family.build_model(cfg)
     wdtype = jnp.dtype(cfg["weights_dtype"])
-    params = weights.program_params(seed, cfg, wdtype)
+    params = family.program_params(seed, cfg, wdtype)
     C.layout_matches(model, params)
     host_params = jax.device_get(params)        # the trainer places its own
     del params
     _, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))  # no leaves
     toks = weights.train_tokens(seed, int(mix["dataset_batches"]) * B, T,
-                                cfg["vocab_size"])
+                                family.sizes(cfg)["vocab"])
     x, y = toks[:, :-1], toks[:, 1:]
     losses = Losses()
     trainer, method, criterion = trainer_parts(t)
@@ -185,7 +187,7 @@ def run(env, cell):
     opt.set_end_when(Trigger.max_iteration(checked))
     opt.optimize()
     ours["change_norm"] = reference.named(norms_of_change(
-        opt.params, weights.program_params(seed, cfg, wdtype)))
+        opt.params, family.program_params(seed, cfg, wdtype)))
     _continue_from_here(opt)
 
     # ---- the window
@@ -226,8 +228,8 @@ def run(env, cell):
 
     def follow(mode, batches=batches):
         return reference.train_trajectory(
-            weights.stacked(seed, cfg), cfg, batches, lr=lr, mode=mode,
-            rows=min(rows, B), steps=checked)
+            family, family.stacked(seed, cfg), cfg, batches, lr=lr,
+            mode=mode, rows=min(rows, B), steps=checked)
     t_check = C.now()
     ref = follow("float32")
     got = compare(ours, ref)
@@ -235,13 +237,13 @@ def run(env, cell):
     notes = {"steps": steps, "window_s": window_s, "compared": got,
              "check_s": check_s,
              "loss": ours["loss"], "reference_loss": ref["loss"]}
-    if env.get("control") == "half_batch":
+    if control == "half_batch":
         # the fault, planted in the reference put in the program's place
         half = [(bx[:B // 2], by[:B // 2]) for bx, by in batches]
         got = notes["control"] = compare(follow("float32", half), ref)
-    elif env.get("control"):
+    elif control:
         # the reference in the lower precision, in the program's place
-        got = notes["control"] = compare(follow(env["control"]), ref)
+        got = notes["control"] = compare(follow(control), ref)
 
     checks = C.Checks()
     limits = cfg["limits"]
@@ -254,13 +256,16 @@ def run(env, cell):
     checks.at_most("compiles_in_window", compiled_in_window, 0)
 
     tokens_per_s = steps * B * T / window_s
+    token_flops = family.train_token_flops(cfg, T)
     ctx = {"before": snap["before"], "after": after, "trace": trigger.trace,
-           "peaks": env["peaks"], "window_s": window_s,
-           "needed_flops": steps * B * T * R.train_token_flops(cfg, T)}
+           "peaks": env["peaks"], "window_s": window_s, "cfg": cfg,
+           "family": family, "dirs": cell["dirs"],
+           "needed_flops": steps * B * T * token_flops}
     if trigger.traced:          # the traced steps alone, for the traced run
         ctx["window_s"] = trigger.traced[1]
-        ctx["needed_flops"] = trigger.traced[0] * B * T \
-            * R.train_token_flops(cfg, T)
+        ctx["needed_flops"] = trigger.traced[0] * B * T * token_flops
+        ctx["traced_work"] = {"train_tokens": trigger.traced[0] * B * T,
+                              "sequence": T}
     return {"attempted": steps, "failed": 0,
             "end_to_end": {"setup_s": snap["setup_s"],
                            "train_tokens_per_s": tokens_per_s},
