@@ -3,5 +3,5 @@
 utils/ConvertModel.scala, pyspark/bigdl/contrib/onnx/; SURVEY.md §2.8)."""
 
 from bigdl_tpu.interop import (caffe, caffe_saver, huggingface,
-                               keras_loader, onnx, protowire, tensorflow,
-                               tf_example, torchfile)
+                               keras_loader, olmo_hybrid, onnx, protowire,
+                               tensorflow, tf_example, torchfile)

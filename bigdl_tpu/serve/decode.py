@@ -340,13 +340,16 @@ class DecodeEntry:
                  sampling: Optional[bool] = None,
                  kv_shard: Optional[bool] = None):
         from bigdl_tpu.utils import config
-        missing = [m for m in _DECODE_CONTRACT if not hasattr(model, m)]
-        if missing:
+        has_dense = all(hasattr(model, m) for m in _DECODE_CONTRACT)
+        has_paged = all(hasattr(model, m) for m in _PAGED_CONTRACT)
+        if not has_dense and not has_paged:
             raise TypeError(
                 f"decode=True needs a model implementing the slot-decode "
-                f"contract {_DECODE_CONTRACT}; {type(model).__name__} "
-                f"lacks {missing} (GPT2LM/LlamaLM from "
-                f"interop/huggingface.py provide it)")
+                f"contract {_DECODE_CONTRACT} or the paged one "
+                f"{_PAGED_CONTRACT}; {type(model).__name__} lacks "
+                f"{[m for m in _DECODE_CONTRACT if not hasattr(model, m)]} "
+                f"(GPT2LM/LlamaLM from interop/huggingface.py provide "
+                f"both)")
         self.name = name
         self.model = model
         self.params = params
@@ -382,7 +385,6 @@ class DecodeEntry:
                 f"eos_id= at registration")
         self.vocab_size = int(model.vocab_size)
         # ---------------------------------------------- paged resolution
-        has_paged = all(hasattr(model, m) for m in _PAGED_CONTRACT)
         if paged and not has_paged:
             raise TypeError(
                 f"paged=True needs a model implementing the paged "
@@ -392,6 +394,15 @@ class DecodeEntry:
         want_paged = (bool(config.get("SERVE_KV_PAGED")) if paged is None
                       else bool(paged))
         self.paged = want_paged and has_paged
+        if not self.paged and not has_dense:
+            raise TypeError(
+                f"{type(model).__name__} carries the paged slot-decode "
+                f"contract only (it has no {_DECODE_CONTRACT}): register "
+                f"it with paged=True / BIGDL_TPU_SERVE_KV_PAGED=1")
+        # a model whose cache holds more than keys and values says which
+        # leaves are resident by slot (leading axis num_slots: a recurrent
+        # state) and not by block; only the paged layout carries them
+        self.slot_state = self.paged and hasattr(model, "slot_resident")
         self.kv_block = int(kv_block if kv_block is not None
                             else config.get("SERVE_KV_BLOCK"))
         if self.kv_block < 1:
@@ -411,7 +422,14 @@ class DecodeEntry:
                 f"sampling=True needs a model exposing {logits_fn} "
                 f"(the decode_step stopped before the token choice); "
                 f"{type(model).__name__} lacks it")
-        self.prefix_cache = self.paged and (
+        if self.slot_state and prefix_cache:
+            raise ValueError(
+                f"prefix_cache=True cannot serve {type(model).__name__}: "
+                f"a prefix hit skips the prefill of tokens whose recurrent "
+                f"state no KV block holds (the prefix cache hashes token "
+                f"blocks; the slot-resident state is not snapshotted at "
+                f"block boundaries)")
+        self.prefix_cache = self.paged and not self.slot_state and (
             bool(config.get("SERVE_PREFIX_CACHE"))
             if prefix_cache is None else bool(prefix_cache))
         cap = int(prefix_cache_blocks if prefix_cache_blocks is not None
@@ -443,23 +461,34 @@ class DecodeEntry:
         # to pool_blocks x kv_block tokens, not slots x max_seq_len.
         import jax
         from bigdl_tpu.observe import memz as _memz
+        cache_specs = jax.eval_shape(self._raw_caches, params)
         if self.paged:
-            cache_specs = jax.eval_shape(
-                lambda p: model.make_paged_slot_caches(
-                    p, self.pool_blocks, self.kv_block), params)
             what = (f"decode model {name!r} ({self.pool_blocks} KV "
                     f"blocks x {self.kv_block} tokens paged pool")
         else:
-            cache_specs = jax.eval_shape(
-                lambda p: model.make_slot_caches(p, self.num_slots,
-                                                 self.max_seq_len),
-                params)
             what = (f"decode model {name!r} ({self.num_slots} slots x "
                     f"{self.max_seq_len} tokens KV bucket")
+        # True at each leaf resident by slot, None where every leaf is KV
+        self._slot_mask = (model.slot_resident(cache_specs)
+                           if self.slot_state else None)
         self.kv_cache_bytes = _memz.tree_nbytes(cache_specs)
+        self.state_bytes = _memz.tree_nbytes(
+            self.split_caches(cache_specs)[1])
+        if self.slot_state:
+            what += (f" + {self.state_bytes:,} bytes of state resident by "
+                     f"slot")
+        self.kv_pool_bytes = self.kv_cache_bytes - self.state_bytes
+        self.state_kind = "kv+recurrent" if self.slot_state else "kv"
+        # parameters that already lie on the device are in the backend's
+        # bytes in use, and `_place` makes no second copy of them; under a
+        # mesh every leaf is placed anew
+        to_place = _memz.tree_nbytes(
+            [a for a in jax.tree.leaves(params)
+             if mesh is not None or not isinstance(a, jax.Array)])
         _memz.admission_check(
-            self.kv_cache_bytes + _memz.tree_nbytes(params),
-            f"{what} = {self.kv_cache_bytes:,} bytes + params)")
+            self.kv_cache_bytes + to_place,
+            f"{what} = {self.kv_cache_bytes:,} bytes + {to_place:,} bytes "
+            f"of params yet to be placed)")
         self._jit_decode = None
         self._jit_prefill = None
         self._aot_decode = None
@@ -475,7 +504,7 @@ class DecodeEntry:
         donate = (jax.default_backend() != "cpu")
         kw_d = {"donate_argnums": (1,)} if donate else {}
         kw_p = dict(kw_d)
-        sh_in = None
+        self._rep_sharding = None
         self._pool_sharding = None
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -488,15 +517,13 @@ class DecodeEntry:
             # layout of the dense bucket, applied to its paged
             # replacement; blocks stay whole on a device) — the pool is
             # the one decode resident worth splitting at real-chip scale.
-            sh_in = rep
+            self._rep_sharding = rep
             if self.kv_shard:
                 from bigdl_tpu.nn.attention import PAGED_POOL_BLOCK_AXIS
                 self._pool_sharding = NamedSharding(
                     self.mesh, P(*[None] * PAGED_POOL_BLOCK_AXIS,
                                  self._shard_axis))
-                cache_sh = self._pool_sharding
-            else:
-                cache_sh = rep
+            cache_sh = self._cache_shardings()
             # in_shardings as a per-argument prefix pytree: the cache
             # subtree takes the pool sharding, everything else is
             # replicated. Argument layouts (see the lambdas below):
@@ -511,7 +538,6 @@ class DecodeEntry:
             n_extra_p = 2 if self.paged else 1
             kw_p["in_shardings"] = (rep, cache_sh) + (rep,) * (2 + n_extra_p)
             kw_p["out_shardings"] = cache_sh
-        self._rep_sharding = sh_in
         if self.paged:
             if self.sampling:
                 from bigdl_tpu.nn.sampling import sample_tokens
@@ -558,20 +584,46 @@ class DecodeEntry:
             self._placed = jax.tree.map(self._place, self.params)
         return self._placed
 
+    def _raw_caches(self, params):
+        """The model's zero cache pytree for this registration: the paged
+        block pool (with, for a model that has one, its state resident by
+        slot), or the dense slot bucket."""
+        if not self.paged:
+            return self.model.make_slot_caches(
+                params, self.num_slots, self.max_seq_len)
+        by_slot = {"num_slots": self.num_slots} if self.slot_state else {}
+        return self.model.make_paged_slot_caches(
+            params, self.pool_blocks, self.kv_block, **by_slot)
+
+    def _cache_shardings(self):
+        """The caches' sharding under a mesh, as a prefix of their pytree:
+        the pool's block-dim sharding under kv_shard, replicated otherwise;
+        leaves resident by slot are always replicated."""
+        pool = self._pool_sharding or self._rep_sharding
+        if self._slot_mask is None or pool is None:
+            return pool
+        import jax
+        return jax.tree.map(
+            lambda by_slot: self._rep_sharding if by_slot else pool,
+            self._slot_mask)
+
+    def split_caches(self, caches):
+        """(the leaves pooled by block, the leaves resident by slot)."""
+        import jax
+        leaves = jax.tree.leaves(caches)
+        if self._slot_mask is None:
+            return leaves, []
+        mask = jax.tree.leaves(self._slot_mask)
+        return ([a for a, m in zip(leaves, mask) if not m],
+                [a for a, m in zip(leaves, mask) if m])
+
     def make_caches(self):
-        """The persistent KV pytree (zeros, placed): the paged block
-        pool, or the dense slot bucket."""
-        if self.paged:
-            caches = self.model.make_paged_slot_caches(
-                self.params, self.pool_blocks, self.kv_block)
-        else:
-            caches = self.model.make_slot_caches(
-                self.params, self.num_slots, self.max_seq_len)
-        sh = self._pool_sharding or self._rep_sharding
+        """The persistent cache pytree (zeros, placed)."""
+        caches = self._raw_caches(self.params)
+        sh = self._cache_shardings()
         if sh is not None:
             import jax
-            caches = jax.tree.map(
-                lambda a: jax.device_put(a, sh), caches)
+            caches = jax.device_put(caches, sh)
         return caches
 
     # --------------------------------------------------------------- AOT
@@ -592,16 +644,15 @@ class DecodeEntry:
 
         p_s = jax.tree.map(lambda a: spec(tuple(a.shape), a.dtype),
                            self.params)
-        if self.paged:
-            raw_caches = self.model.make_paged_slot_caches(
-                self.params, self.pool_blocks, self.kv_block)
+        raw = jax.eval_shape(self._raw_caches, self.params)
+        sh = self._cache_shardings()
+        if sh is None or self._slot_mask is None:
+            c_s = jax.tree.map(
+                lambda a: spec(tuple(a.shape), a.dtype, sharding=sh), raw)
         else:
-            raw_caches = self.model.make_slot_caches(
-                self.params, self.num_slots, self.max_seq_len)
-        c_s = jax.tree.map(
-            lambda a: spec(tuple(a.shape), a.dtype,
-                           sharding=self._pool_sharding), raw_caches)
-        del raw_caches
+            c_s = jax.tree.map(
+                lambda a, s: spec(tuple(a.shape), a.dtype, sharding=s),
+                raw, sh)
         S = self.num_slots
         i32 = np.dtype(np.int32)
         f32 = np.dtype(np.float32)
@@ -845,6 +896,7 @@ class DecodeScheduler:
         self._slots: List[Optional[_GenRequest]] = \
             [None] * entry.num_slots
         self._caches = entry.make_caches()
+        self._state_handle = None
         from bigdl_tpu.observe import memz as _memz
         if entry.paged:
             # paged-pool bookkeeping: free-list allocator + per-slot
@@ -860,16 +912,23 @@ class DecodeScheduler:
             # `serve/<model>/kv_pool`, kind="kv_pool" — bytes stay
             # constant across donated steps while the meta carries the
             # LIVE block accounting (headroom = free blocks)
+            pooled, by_slot = entry.split_caches(self._caches)
             self._mem_handle = _memz.ledger().register(
-                f"serve/{self.name}/kv_pool", self._caches, anchor=self,
+                f"serve/{self.name}/kv_pool", pooled, anchor=self,
                 kind="kv_pool",
                 meta={"blocks": entry.pool_blocks,
                       "block": entry.kv_block,
                       "bytes_per_block":
-                          entry.kv_cache_bytes // entry.pool_blocks,
+                          entry.kv_pool_bytes // entry.pool_blocks,
                       "blocks_free": entry.pool_blocks,
                       "slots": entry.num_slots,
                       "max_seq_len": entry.max_seq_len})
+            if entry.slot_state:
+                # what the model keeps by slot and not by block (a
+                # recurrent state): its own owner beside the pool
+                self._state_handle = _memz.ledger().register(
+                    f"serve/{self.name}/slot_state", by_slot, anchor=self,
+                    kind="slot_state", meta={"slots": entry.num_slots})
         else:
             self._pool = None
             self._prefix = None
@@ -931,6 +990,15 @@ class DecodeScheduler:
         self._m_prefix_hit_rate = observe.gauge(
             f"serve/{n}/decode/prefix_hit_rate")
         self._prefix_synced = (0, 0, 0)    # (hits, misses, evictions)
+        # what the cache holds, by kind of leaf, and what prefill wrote
+        observe.gauge(f"serve/{n}/decode/kv_pool_bytes").set(
+            float(entry.kv_pool_bytes))
+        observe.gauge(f"serve/{n}/decode/state_bytes").set(
+            float(entry.state_bytes))
+        self._m_prefill_tokens = observe.counter(
+            f"serve/{n}/decode/prefill_tokens")
+        self._m_state_resets = observe.counter(
+            f"serve/{n}/decode/state_resets")
         self._win_t0 = self._clock()
         self._win_tokens = 0
         if start:
@@ -1051,6 +1119,10 @@ class DecodeScheduler:
                 self._slots[s] = req
                 admitted += 1
             self._m_queued.set(len(self._queue))
+        if admitted and self.entry.slot_state:
+            # a slot handed to a new request starts from a zero state: the
+            # programs see to it themselves, from position 0
+            self._m_state_resets.inc(admitted)
         return admitted
 
     def _admit_blocks(self, req: _GenRequest, s: int) -> bool:
@@ -1184,7 +1256,8 @@ class DecodeScheduler:
             t0 = self._clock()
             with observe.span("serve/decode/prefill", cat="serve",
                               args={"model": self.name, "chunk": C,
-                                    "slots": len(reqs)}):
+                                    "slots": len(reqs),
+                                    "state": self.entry.state_kind}):
                 if paged:
                     # lengths masks the rounded-up bucket's padded tail
                     # (and inactive rows) out of the pool scatter —
@@ -1196,6 +1269,7 @@ class DecodeScheduler:
                         self._caches, tokens, positions, active)
             self._h_prefill.record(
                 max(0.0, (self._clock() - t0) * 1e3))
+            self._m_prefill_tokens.inc(int(lengths.sum()))
             for req in reqs:
                 req.fed += min(req.prefill_target - req.fed, C)
                 done += 1
@@ -1255,7 +1329,8 @@ class DecodeScheduler:
         t0 = self._clock()
         with observe.span("serve/decode/step", cat="serve",
                           args={"model": self.name,
-                                "active": len(ready)}):
+                                "active": len(ready),
+                                "state": self.entry.state_kind}):
             nxt, self._caches = self.entry.run_decode(
                 self._caches, tokens, positions, active, *extra)
             from bigdl_tpu.analysis.sancov import sanctioned_sync
@@ -1458,6 +1533,8 @@ class DecodeScheduler:
         # cache reference; release the ledger accounting with it
         self._caches = None
         self._mem_handle.close()
+        if self._state_handle is not None:
+            self._state_handle.close()
 
     # ------------------------------------------------------------- stats
     def stats(self) -> Dict:
@@ -1501,6 +1578,13 @@ class DecodeScheduler:
             "cancelled": int(self._m_cancelled.value),
         }
         out["paged"] = bool(self.entry.paged)
+        out.update({
+            "state": self.entry.state_kind,
+            "kv_pool_bytes": self.entry.kv_pool_bytes,
+            "state_bytes": self.entry.state_bytes,
+            "state_resets": int(self._m_state_resets.value),
+            "prefill_tokens": int(self._m_prefill_tokens.value),
+        })
         if self.entry.paged and self._pool is not None:
             pool = self._pool
             cached = pool.cached_count()

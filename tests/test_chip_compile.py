@@ -210,3 +210,88 @@ def test_gpt2_xl_paged_prefill_chunk_fits_one_chip(one_chip, xl_entry):
         _sds(one_chip, (S, entry.blocks_per_slot), np.int32),
         _sds(one_chip, (S,), np.int32)).compile()
     _serves_from_the_pool(c, entry, caches)
+
+
+# Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B config.json): one period of its
+# layer pattern at the published widths, as benchmark/configs/
+# olmo-hybrid-7b-serve.json registers it (the cell holds four periods)
+HYBRID = dict(vocab=100352, d=3840, heads=30, ff=11008, positions=1280,
+              linear=dict(num_heads=30, key_dim=96, value_dim=192,
+                          conv_kernel=4, allow_neg_eigval=True),
+              register=dict(num_slots=8, max_seq_len=1280, kv_block=16,
+                            kv_pool_blocks=640, prefill_chunk=64,
+                            paged=True))
+
+
+@pytest.fixture(scope="module")
+def hybrid_entry(one_chip):
+    from bigdl_tpu.interop.olmo_hybrid import FULL, LINEAR, OlmoHybridLM
+    from bigdl_tpu.serve.decode import DecodeEntry
+    model = OlmoHybridLM(
+        HYBRID["vocab"], HYBRID["d"], HYBRID["heads"], HYBRID["ff"],
+        [LINEAR] * 3 + [FULL], HYBRID["linear"], HYBRID["positions"],
+        eos_id=HYBRID["vocab"] - 1, param_dtype=jnp.bfloat16)
+    params, _ = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), params)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        entry = DecodeEntry("hybrid", model, params, **HYBRID["register"])
+    assert entry.paged and entry.slot_state and not entry.prefix_cache
+    caches = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                          jax.eval_shape(entry._raw_caches, params))
+    return entry, params, caches
+
+
+_TYPED_MOVES = re.compile(r"= (\w+)\[([\d,]*)\]\S* (copy|gather|transpose)\(")
+
+
+def _keeps_both_kinds_of_state_in_place(compiled, entry, caches):
+    """The cache pytree (three linear layers' state by slot, one full
+    layer's block pool) is donated and rewritten where it lies: the program
+    moves no array with the state's or the pool's dimensions, and no
+    float32 array larger than one layer's state."""
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= entry.kv_cache_bytes
+    _fits(compiled)
+    state, pool = caches[0]["S"].shape, caches[3].shape
+    assert state == (8, 30, 96, 192) and pool == (30, 640, 16, 256)
+    moves = [(dtype, tuple(int(d) for d in dims.split(",") if d))
+             for dtype, dims, _ in _TYPED_MOVES.findall(compiled.as_text())]
+    assert len(moves) > 5             # the pattern still reads this compiler
+    of_a_cache = [mv for mv in moves
+                  if sorted(mv[1]) in (sorted(state), sorted(pool))]
+    assert of_a_cache == []
+    assert [mv for mv in moves if mv[0] == "f32"
+            and int(np.prod(mv[1])) > int(np.prod(state))] == []
+    return m
+
+
+def test_olmo_hybrid_decode_step_keeps_its_state_in_place(one_chip,
+                                                          hybrid_entry):
+    """The decode program of one period at the published widths: the
+    recurrence as written, on a state of 8 x 30 x 96 x 192 float32 a
+    layer. Its temporaries stay under one layer's state: nothing of the
+    size of a state or a pool is made beside them."""
+    entry, params, caches = hybrid_entry
+    S = entry.num_slots
+    vec = _sds(one_chip, (S,), np.int32)
+    c = entry._jit_decode.lower(
+        params, caches, vec, vec, _sds(one_chip, (S,), np.bool_),
+        _sds(one_chip, (S, entry.blocks_per_slot), np.int32)).compile()
+    m = _keeps_both_kinds_of_state_in_place(c, entry, caches)
+    assert m.temp_size_in_bytes < 4 * int(np.prod(caches[0]["S"].shape)), m
+
+
+def test_olmo_hybrid_prefill_chunk_keeps_its_state_in_place(one_chip,
+                                                            hybrid_entry):
+    """The chunk-64 prefill of the same entry: the chunkwise form (one
+    triangular solve a head) and the full layer's attention over the pool."""
+    entry, params, caches = hybrid_entry
+    S, C = entry.num_slots, entry.buckets[-1]
+    chunk = _sds(one_chip, (S, C), np.int32)
+    c = entry._jit_prefill.lower(
+        params, caches, chunk, chunk,
+        _sds(one_chip, (S, entry.blocks_per_slot), np.int32),
+        _sds(one_chip, (S,), np.int32)).compile()
+    m = _keeps_both_kinds_of_state_in_place(c, entry, caches)
+    assert m.temp_size_in_bytes < entry.kv_cache_bytes, m

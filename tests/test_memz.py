@@ -437,6 +437,29 @@ def test_decode_admission_refused_with_capacity_report(
     eng.shutdown()
 
 
+def test_decode_admission_counts_resident_params_once(clean_mem,
+                                                      monkeypatch):
+    """Parameters that already lie on the device are in the backend's
+    bytes in use: the check asks for the cache beside them, not for a
+    second copy (a model of half the chip's memory could not register).
+    The same parameters on the host have yet to be placed, and count."""
+    import jax
+    from bigdl_tpu.serve.decode import DecodeEntry, decode_demo_model
+    model, params, _ = decode_demo_model(num_layers=2, d_model=64,
+                                         num_heads=4)
+    kw = dict(num_slots=2, max_seq_len=32, kv_block=8, kv_pool_blocks=8,
+              paged=True)
+    cache = DecodeEntry("probe", model, params, **kw).kv_cache_bytes
+    assert memz.tree_nbytes(params) > 4 * cache
+    in_use = memz.backend_in_use()[0]
+    monkeypatch.setenv("BIGDL_TPU_MEM_LIMIT_BYTES",
+                       str(in_use + 2 * cache))
+    DecodeEntry("resident", model, params, **kw)
+    on_host = jax.tree.map(np.asarray, params)
+    with pytest.raises(memz.CapacityError, match="yet to be placed"):
+        DecodeEntry("on_host", model, on_host, **kw)
+
+
 # ------------------------------------------------------------ shims + CLI
 def test_profile_shim_routes_through_memz(clean_mem, tmp_path):
     from bigdl_tpu.utils import profile as uprofile
