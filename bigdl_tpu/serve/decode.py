@@ -25,11 +25,36 @@ this codebase's discipline):
     stalls concurrent decode for at most one chunk and the program
     count stays O(log chunk).
   * **iteration-level scheduler** — clock-injectable (the batcher.py
-    fake-clock testing discipline): every decode step first admits
+    fake-clock testing discipline): every iteration first admits
     queued requests into free slots (prefill), then runs one fused step
     over whatever is active; finished sequences (EOS or
     max_new_tokens) retire IMMEDIATELY and free their slot. O(L) per
     token per sequence instead of O(L²), no head-of-line blocking.
+  * **one decode step in flight** — an iteration enqueues step N+1
+    BEFORE it fetches step N's tokens (the iteration's single host
+    sync): a slot that continues feeds N+1 its own output of N on the
+    device (a tiny merge program picks, row by row, that or the token
+    the host knows), and position, block table and sampling arguments
+    never needed the token. So the fetch, the push to the streams,
+    retirement, the gauges, the cancel sweep, admission and the next
+    prefill chunks run beside the chip's step and not between two of
+    them; device order (one stream, the donated caches threaded
+    through) keeps every write behind the reads it must follow. A
+    sequence that ends by count is known ahead and simply has no row
+    in N+1. One that ends by VALUE (EOS) or is cancelled while its row
+    of N+1 is in flight costs that row: its output is dropped at the
+    fetch (requests are matched by identity, a slot may have changed
+    hands), its stray KV write lies inside its own reservation, in
+    blocks any later owner overwrites by programs enqueued after it,
+    and a recurrent state restarts at position 0. The tokens are those
+    of the serial loop, bit for bit. What a step in flight costs is
+    paid by a request that arrives: its prefill queues behind every
+    step already enqueued. So while a slot is free and nobody is
+    queued, the iteration waits for the taker (until the step in
+    flight is done, never longer) before it enqueues the next step:
+    the request a closed-loop caller sends when its last one retires
+    lands a few ms after the fetch, and its prefill then runs behind
+    one step and not behind two.
 
 The model contract is duck-typed: `make_slot_caches(params, S, L)`,
 `prefill(params, caches, tokens, positions, active)`,
@@ -68,10 +93,15 @@ EOS — concurrent decode with staggered joins/leaves is BIT-IDENTICAL
 to each sequence run alone (tests/test_decode.py parity oracle).
 
 Observability: `serve/<model>/decode/{tokens_per_s, slot_occupancy,
-prefill_ms, step_ms, queue_wait_ms, latency_ms, ttft_ms}` + counters,
-a `decode` section in /statusz, per-peer decode rows in /fleetz, and
-the ServeWatchdog pointed at decode latency p99 with
-queue-vs-prefill-vs-step attribution (observe/doctor.py).
+prefill_ms, step_ms, queue_wait_ms, latency_ms, ttft_ms}` + counters
+(`steps`; `steps_ahead`, the steps enqueued while the one before them
+was unfetched; `rows_dropped`, the rows computed for a sequence that
+had ended by value or been cancelled), a `decode` section in /statusz,
+per-peer decode rows in /fleetz, and the ServeWatchdog pointed at
+decode latency p99 with queue-vs-prefill-vs-step attribution
+(observe/doctor.py). `step_ms` is what one iteration costs a token:
+from a step's dispatch, or from the fetch before it where that came
+later (a step was in flight), to its own fetch's return.
 """
 
 from __future__ import annotations
@@ -80,7 +110,7 @@ import logging
 import queue as _queue
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -90,6 +120,10 @@ from bigdl_tpu.serve.batcher import (BATCH_FILL_BOUNDS, LATENCY_MS_BOUNDS,
 from bigdl_tpu.utils.threads import make_condition, spawn
 
 log = logging.getLogger("bigdl_tpu")
+
+# how often the scheduler, waiting for a request to take a free slot, looks
+# whether the step in flight is done (DecodeScheduler._await_taker)
+_TAKER_POLL_S = 0.0005
 
 _DECODE_CONTRACT = ("make_slot_caches", "prefill", "decode_step")
 _PAGED_CONTRACT = ("make_paged_slot_caches", "paged_prefill",
@@ -491,7 +525,9 @@ class DecodeEntry:
             f"of params yet to be placed)")
         self._jit_decode = None
         self._jit_prefill = None
+        self._jit_merge = None
         self._aot_decode = None
+        self._aot_merge = None
         self._aot_prefill: Dict[int, object] = {}
         self._placed = None          # (params, caches) device-resident
         self._shardings = None
@@ -571,12 +607,25 @@ class DecodeEntry:
             self._jit_prefill = jax.jit(
                 lambda p, c, t, pos, a: model.prefill(p, c, t, pos, a),
                 **kw_p)
+        # the next step's input tokens while this step's are still on the
+        # device: a row that continues takes its own output, every other
+        # row what the host knows (DecodeScheduler._dispatch_step)
+        kw_m = ({} if self._rep_sharding is None else
+                {"in_shardings": (self._rep_sharding,) * 3,
+                 "out_shardings": self._rep_sharding})
+        self._jit_merge = jax.jit(
+            lambda use_prev, prev, host: jax.numpy.where(use_prev, prev,
+                                                         host), **kw_m)
 
     def _place(self, a):
+        """`a` where the programs take it; an array already on the device
+        stays there."""
         import jax
         if self._rep_sharding is None:
             return jax.numpy.asarray(a)
-        return jax.device_put(np.asarray(a), self._rep_sharding)
+        if not isinstance(a, jax.Array):
+            a = np.asarray(a)
+        return jax.device_put(a, self._rep_sharding)
 
     def placed_params(self):
         if self._placed is None:
@@ -628,10 +677,10 @@ class DecodeEntry:
 
     # --------------------------------------------------------------- AOT
     def precompile(self) -> Dict[str, Dict]:
-        """AOT-compile the fused decode step plus every prefill-chunk
-        bucket before traffic (compilecache.precompile_fixed) — with the
-        persistent compile cache warm, a restarted decode server
-        compiles ZERO fresh programs (counter-asserted in
+        """AOT-compile the fused decode step, every prefill-chunk bucket
+        and the token merge before traffic
+        (compilecache.precompile_fixed) — with the persistent compile
+        cache warm, a restarted decode server compiles ZERO fresh programs (counter-asserted in
         tests/test_decode.py). Cost analyses land under
         `compile/serve/<model>/decode/...`."""
         import jax
@@ -683,6 +732,10 @@ class DecodeEntry:
             self._assert_pool_sharding(exe)
             self._aot_prefill[b] = exe
             results[f"prefill{b}"] = cost
+        cost, self._aot_merge = precompile_fixed(
+            self._jit_merge, (act, vec, vec),
+            name=f"serve/{self.name}/decode/merge")
+        results["merge"] = cost
         return results
 
     def _assert_pool_sharding(self, exe) -> None:
@@ -726,11 +779,13 @@ class DecodeEntry:
         return self._jit_prefill(*args)
 
     def run_decode(self, caches, tokens_last: np.ndarray, *rest):
-        """One fused decode step; returns (next_tokens device array,
-        new caches). The caller fetches next_tokens (the iteration's
-        single host sync). `rest` is the layout's trailing host args
-        (positions, active[, block_table][, temps, top_ks, top_ps,
-        seeds])."""
+        """One fused decode step, enqueued and not waited for; returns
+        (next_tokens device array, new caches). `tokens_last` is a host
+        array or, for a step enqueued ahead, `merge_tokens`' device array,
+        which goes in untouched. The scheduler fetches next_tokens (the
+        iteration's single host sync) only after it has enqueued the step
+        that follows. `rest` is the layout's trailing host args (positions,
+        active[, block_table][, temps, top_ks, top_ps, seeds])."""
         args = (self.placed_params(), caches,
                 self._place(tokens_last)) + \
             tuple(self._place(a) for a in rest)
@@ -743,6 +798,13 @@ class DecodeEntry:
                             self.name)
                 self._aot_decode = None
         return self._jit_decode(*args)
+
+    def merge_tokens(self, use_prev: np.ndarray, prev_next, host_tokens):
+        """`where(use_prev, prev_next, host_tokens)` over the slots, on the
+        device: `prev_next` is the unfetched output of the step in flight."""
+        merge = self._aot_merge or self._jit_merge
+        return merge(*(self._place(a)
+                       for a in (use_prev, prev_next, host_tokens)))
 
 
 class GenReply:
@@ -859,6 +921,16 @@ class _GenRequest:
         return self.generated[-1], self.prompt.shape[0] - 1 + n
 
 
+class _Step(NamedTuple):
+    """A decode step that is enqueued and whose tokens are not fetched yet:
+    the device array of next tokens, the requests it computes a row for
+    (matched by identity when the tokens arrive: a slot may have changed
+    hands meanwhile) and when it was dispatched."""
+    nxt: object
+    rows: List[_GenRequest]
+    t_dispatch: float
+
+
 class DecodeScheduler:
     """One decode model's request queue + iteration-level scheduler.
 
@@ -866,11 +938,15 @@ class DecodeScheduler:
     the thread loop composes — batcher.py's testing discipline):
 
       1. **admit**: pop queued requests into free slots (any number, any
-         step — requests join the running batch mid-flight);
+         step — requests join the running batch mid-flight), having
+         waited for one while a slot is free, nobody is queued and the
+         step in flight is still running;
       2. **prefill**: slots still streaming their prompt advance by one
          length-bucketed chunk (grouped by bucket so one program call
          serves every slot on the same chunk size);
-      3. **decode**: one fused step over all prompt-complete slots;
+      3. **decode**: enqueue one fused step over all prompt-complete
+         slots (a slot that continues from the step in flight takes its
+         token on the device), then fetch the step that was in flight:
          EOS/max_new retirements complete their reply and free the slot
          IMMEDIATELY — the next iteration admits into it.
 
@@ -945,6 +1021,10 @@ class DecodeScheduler:
                       "max_seq_len": entry.max_seq_len})
         self._closed = False
         self._draining = False
+        # the decode step that is enqueued and not fetched yet; written by
+        # the thread that runs step_once, cleared by close()
+        self._in_flight: Optional[_Step] = None
+        self._t_fetched = float("-inf")    # when the last fetch returned
         self._thread: Optional[threading.Thread] = None
         self._stop_check: Optional[Callable[[], bool]] = None
         # --------------------------------------------------- telemetry
@@ -953,6 +1033,12 @@ class DecodeScheduler:
         self._m_requests = observe.counter(f"serve/{n}/decode/requests")
         self._m_retired = observe.counter(f"serve/{n}/decode/retired")
         self._m_steps = observe.counter(f"serve/{n}/decode/steps")
+        # steps enqueued while the step before them was still unfetched,
+        # and rows computed for a sequence that had ended meanwhile
+        self._m_steps_ahead = observe.counter(
+            f"serve/{n}/decode/steps_ahead")
+        self._m_rows_dropped = observe.counter(
+            f"serve/{n}/decode/rows_dropped")
         self._m_tps = observe.gauge(f"serve/{n}/decode/tokens_per_s")
         self._m_active = observe.gauge(f"serve/{n}/decode/active_slots")
         self._m_queued = observe.gauge(f"serve/{n}/decode/queued")
@@ -1294,25 +1380,51 @@ class DecodeScheduler:
             req.commit_upto = j
 
     def _decode_pass(self) -> int:
-        """One fused decode step over every prompt-complete slot; retire
-        finished sequences and free their slots."""
-        ready = [r for r in self._slots
-                 if r is not None and r.fed >= r.prefill_target]
-        if not ready:
-            return 0
+        """Enqueue the next fused step over every prompt-complete slot,
+        then fetch and deliver the step that was in flight, which the
+        device has been running meanwhile."""
+        prev = self._in_flight
+        with observe.span("serve/decode/step", cat="serve",
+                          args={"model": self.name,
+                                "state": self.entry.state_kind}):
+            self._in_flight = self._dispatch_step(prev)
+            if prev is not None:
+                self._deliver(prev)
+        step = prev or self._in_flight
+        return len(step.rows) if step is not None else 0
+
+    def _dispatch_step(self, prev: Optional[_Step]) -> Optional[_Step]:
+        """One fused decode step over every slot whose prompt is complete
+        and whose sequence does not end by count in `prev`, the step in
+        flight. A row of `prev` that continues takes its input token from
+        `prev.nxt` on the device; everything else of the step the host
+        knows without that token."""
         S = self.entry.num_slots
         tokens = np.zeros((S,), np.int32)
         positions = np.zeros((S,), np.int32)
         active = np.zeros((S,), bool)
-        for req in ready:
+        use_prev = np.zeros((S,), bool)
+        rows: List[_GenRequest] = []
+        for req in self._slots:
+            if req is None or req.fed < req.prefill_target:
+                continue
             tok, pos = req.next_input()
-            tokens[req.slot] = tok
+            if prev is not None and any(r is req for r in prev.rows):
+                if len(req.generated) + 1 >= req.max_new:
+                    continue        # `prev` holds its last token
+                pos += 1
+                use_prev[req.slot] = True
+            else:
+                tokens[req.slot] = tok
             positions[req.slot] = pos
             active[req.slot] = True
+            rows.append(req)
+        if not rows:
+            return None
         extra = []
         if self.entry.paged:
             with self._cv:
-                for req in ready:
+                for req in rows:
                     self._ensure_blocks(req, int(positions[req.slot]))
                 extra.append(self._tables.copy())
         if self.entry.sampling:
@@ -1320,33 +1432,46 @@ class DecodeScheduler:
             tks = np.zeros((S,), np.int32)
             tps = np.ones((S,), np.float32)
             seeds = np.zeros((S,), np.int32)
-            for req in ready:
+            for req in rows:
                 temps[req.slot] = req.temperature
                 tks[req.slot] = req.top_k
                 tps[req.slot] = req.top_p
                 seeds[req.slot] = req.seed
             extra += [temps, tks, tps, seeds]
         t0 = self._clock()
-        with observe.span("serve/decode/step", cat="serve",
-                          args={"model": self.name,
-                                "active": len(ready),
-                                "state": self.entry.state_kind}):
-            nxt, self._caches = self.entry.run_decode(
-                self._caches, tokens, positions, active, *extra)
-            from bigdl_tpu.analysis.sancov import sanctioned_sync
-            import jax
-            with sanctioned_sync("decode next-token fetch"):
-                nxt = np.asarray(jax.device_get(nxt))
+        if use_prev.any():
+            tokens = self.entry.merge_tokens(use_prev, prev.nxt, tokens)
+        nxt, self._caches = self.entry.run_decode(
+            self._caches, tokens, positions, active, *extra)
+        if prev is not None:
+            self._m_steps_ahead.inc()
+        return _Step(nxt, rows, t0)
+
+    def _deliver(self, step: _Step) -> None:
+        """Fetch a step's tokens (the iteration's single host sync), push
+        them to their streams and retire the sequences that end. A row
+        whose request left its slot while the step was in flight (it ended
+        by value in the step before, or was cancelled) is dropped."""
+        from bigdl_tpu.analysis.sancov import sanctioned_sync
+        import jax
+        with sanctioned_sync("decode next-token fetch"):
+            nxt = np.asarray(jax.device_get(step.nxt))
         now = self._clock()
-        self._h_step.record(max(0.0, (now - t0) * 1e3))
-        self._h_occ.record(len(ready) / S)
+        # what the iteration cost a token: from dispatch when nothing was
+        # in flight before it, else from the fetch before this one
+        self._h_step.record(
+            max(0.0, (now - max(step.t_dispatch, self._t_fetched)) * 1e3))
+        self._t_fetched = now
+        live = [r for r in step.rows if self._slots[r.slot] is r]
+        self._m_rows_dropped.inc(len(step.rows) - len(live))
+        self._h_occ.record(len(step.rows) / self.entry.num_slots)
         self._m_steps.inc()
-        self._m_tokens.inc(len(ready))
-        self._win_tokens += len(ready)
+        self._m_tokens.inc(len(live))
+        self._win_tokens += len(live)
         if now - self._win_t0 >= 0.5:
             self._m_tps.set(self._win_tokens / (now - self._win_t0))
             self._win_t0, self._win_tokens = now, 0
-        for req in ready:
+        for req in live:
             tok = int(nxt[req.slot])
             req.generated.append(tok)
             req.reply._push(tok)
@@ -1357,7 +1482,6 @@ class DecodeScheduler:
             if tok == req.eos_id or len(req.generated) >= req.max_new:
                 self._retire(req, now)
         self._m_active.set(self.active_slots)
-        return len(ready)
 
     def _retire(self, req: _GenRequest, now: float) -> None:
         self._slots[req.slot] = None
@@ -1403,11 +1527,36 @@ class DecodeScheduler:
                             args={"model": self.name, "freed": freed})
         return freed
 
+    def _await_taker(self) -> None:
+        """With a slot free, nobody queued and a step in flight, wait for
+        the request that takes the slot, until that step is done. Admitted
+        in this iteration its prefill runs right behind the step in flight;
+        an iteration later it would run behind the next step too, which
+        this iteration is about to enqueue. The wait is off the interpreter
+        lock (the front's threads get to run) and costs the device nothing
+        while the step runs; if no request came, the next step is enqueued
+        late by the host's dispatch time."""
+        step = self._in_flight
+        if step is None:
+            return
+        with self._cv:
+            while (not self._queue and not self._closed
+                   and not self._draining
+                   and self.active_slots < self.entry.num_slots
+                   and not self._done(step)):
+                self._cv.wait(timeout=_TAKER_POLL_S)
+
+    @staticmethod
+    def _done(step: _Step) -> bool:
+        """Whether the device has finished `step` (never blocks)."""
+        is_ready = getattr(step.nxt, "is_ready", None)
+        return is_ready is None or is_ready()
+
     def step_once(self) -> bool:
         """One scheduler iteration: sweep cancels → admit → prefill →
-        decode. Returns True when any work happened (the thread loop
-        sleeps otherwise); tests drive this synchronously with a fake
-        clock."""
+        decode (enqueue the next step, then fetch the one in flight).
+        Returns True when any work happened (the thread loop sleeps
+        otherwise); tests drive this synchronously with a fake clock."""
         worked = self._sweep_cancelled() > 0
         if self._prefix is not None:
             from bigdl_tpu.observe import memz as _memz
@@ -1419,6 +1568,7 @@ class DecodeScheduler:
                                     cat="serve",
                                     args={"model": self.name,
                                           "blocks": swept})
+        self._await_taker()
         worked = self._admit() > 0 or worked
         worked = self._prefill_pass() > 0 or worked
         worked = self._decode_pass() > 0 or worked
@@ -1437,6 +1587,12 @@ class DecodeScheduler:
         self._thread = spawn(self._loop, name=f"serve-decode-{self.name}")
         return self
 
+    def _idle(self) -> bool:
+        """Nothing queued, no slot taken and no step in flight (lock
+        held): a step in flight still owes its tokens."""
+        return (not self._queue and self.active_slots == 0
+                and self._in_flight is None)
+
     def _loop(self) -> None:
         while True:
             with self._cv:
@@ -1450,8 +1606,7 @@ class DecodeScheduler:
                                     args={"model": self.name,
                                           "decode": True})
                     self._draining = True
-                idle = (not self._queue and self.active_slots == 0)
-                if idle:
+                if self._idle():
                     if self._closed or self._draining:
                         self._closed = True
                         return
@@ -1487,7 +1642,8 @@ class DecodeScheduler:
 
     def drain(self, timeout: Optional[float] = None) -> bool:
         """Stop admission and wait for every queued + active generate
-        to complete. Returns False on timeout."""
+        to complete, the last step's tokens delivered. Returns False on
+        timeout."""
         with self._cv:
             self._draining = True
             self._cv.notify_all()
@@ -1495,7 +1651,7 @@ class DecodeScheduler:
                     else time.monotonic() + timeout)
         while True:
             with self._cv:
-                if not self._queue and self.active_slots == 0:
+                if self._idle():
                     return True
             if deadline is not None and time.monotonic() > deadline:
                 return False
@@ -1514,6 +1670,7 @@ class DecodeScheduler:
             self._queue.clear()
             dropped += [r for r in self._slots if r is not None]
             self._slots = [None] * self.entry.num_slots
+            self._in_flight = None       # its rows' requests fail below
             self._m_queued.set(0)
             self._m_active.set(0)
             self._cv.notify_all()
@@ -1576,6 +1733,9 @@ class DecodeScheduler:
             "p99_ms": round(lat.quantile(0.99), 3),
             "queue_wait_p99_ms": round(qw.quantile(0.99), 3),
             "cancelled": int(self._m_cancelled.value),
+            "steps": int(self._m_steps.value),
+            "steps_ahead": int(self._m_steps_ahead.value),
+            "rows_dropped": int(self._m_rows_dropped.value),
         }
         out["paged"] = bool(self.entry.paged)
         out.update({

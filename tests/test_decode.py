@@ -271,27 +271,184 @@ def test_submit_validation_and_admission(entry):
         sched.submit([2, 3], 2)
 
 
-def test_decode_step_is_one_host_sync(entry, monkeypatch):
-    """One fused iteration over 3 concurrent sequences = exactly ONE
-    jax.device_get (the next-token fetch)."""
-    sched = DecodeScheduler(entry, name="sync", start=False)
-    for _ in range(3):
-        sched.submit([2, 3], 4)
-    sched.step_once()                 # admit + first prefill
-    while any(r is not None and r.fed < r.prefill_target
-              for r in sched._slots):
+def _run_until_done(sched, replies, limit=300):
+    steps = 0
+    while not all(r.done() for r in replies):
         sched.step_once()
-    syncs = {"n": 0}
-    real_get = jax.device_get
+        steps += 1
+        assert steps < limit, "scheduler failed to converge"
+    return [r.result(timeout=1) for r in replies]
+
+
+def _counter(sched, name):
+    return observe.registry().counter(
+        f"serve/{sched.name}/decode/{name}").value
+
+
+def test_decode_step_is_one_host_sync(entry, monkeypatch):
+    """The pipeline's invariant: over K iterations with continuing slots
+    there are exactly K jax.device_get calls (the next-token fetch), and
+    each comes AFTER the dispatch of the step that follows the one it
+    fetches: the host never waits on the device with nothing enqueued
+    behind."""
+    sched = DecodeScheduler(entry, name="sync", start=False)
+    replies = [sched.submit([2, 3], 12, eos_id=-1) for _ in range(3)]
+    sched.step_once()         # admit, prefill, first step: nothing to fetch
+    assert sched._in_flight is not None
+    assert [len(r.generated) for r in sched._in_flight.rows] == [0, 0, 0]
+    calls = []
+    real_get, real_run = jax.device_get, entry.run_decode
 
     def counting_get(v):
-        syncs["n"] += 1
+        calls.append("get")
         return real_get(v)
+
+    def counting_run(*a):
+        calls.append("run")
+        return real_run(*a)
     monkeypatch.setattr(jax, "device_get", counting_get)
-    assert sched._decode_pass() == 3
-    monkeypatch.setattr(jax, "device_get", real_get)
-    assert syncs["n"] == 1
+    monkeypatch.setattr(entry, "run_decode", counting_run)
+    K = 8
+    for _ in range(K):
+        assert sched.step_once()
+    monkeypatch.undo()
+    assert calls == ["run", "get"] * K
+    assert all(len(r.generated) == K for r in sched._in_flight.rows)
+    assert _counter(sched, "steps_ahead") == K
+    for got in _run_until_done(sched, replies):
+        assert got.shape == (12,)
+    assert sched._in_flight is None
     sched.close(drain=False)
+
+
+@pytest.mark.parametrize("arrives", [True, False])
+def test_free_slot_waits_for_its_taker_while_a_step_is_in_flight(
+        entry, monkeypatch, arrives):
+    """With a slot free, nobody queued and a step in flight, the iteration
+    waits for a request until that step is done: one that arrives meanwhile
+    is admitted in the same iteration, its prefill enqueued BEFORE the next
+    decode step; if none arrives, the step's end lets the iteration go on."""
+    import threading
+    sched = DecodeScheduler(entry, name=f"taker-{arrives}", start=False)
+    first = sched.submit([2, 3], 6, eos_id=-1)
+    sched.step_once()
+    assert sched._in_flight is not None and sched.active_slots == 1
+    calls = []
+    real_prefill, real_run = entry.run_prefill, entry.run_decode
+    monkeypatch.setattr(entry, "run_prefill", lambda *a: (
+        calls.append("prefill"), real_prefill(*a))[1])
+    monkeypatch.setattr(entry, "run_decode", lambda *a: (
+        calls.append("decode"), real_run(*a))[1])
+    polls = []
+
+    def done(step):            # a device that stays busy: for ever where a
+        polls.append(1)        # taker comes, else for three looks
+        return not arrives and len(polls) > 3
+    monkeypatch.setattr(DecodeScheduler, "_done", staticmethod(done))
+    reply = []
+
+    def taker():
+        reply.append(sched.submit([2, 3, 4, 5], 3, eos_id=-1))
+    timer = threading.Timer(0.05, taker)
+    if arrives:
+        timer.start()
+    sched.step_once()
+    timer.cancel()
+    monkeypatch.undo()
+    if arrives:
+        assert calls == ["prefill", "decode"] and sched.active_slots == 2
+        assert len(sched._in_flight.rows) == 2
+        assert _run_until_done(sched, reply)[0].shape == (3,)
+    else:
+        assert calls == ["decode"] and len(polls) == 4
+    assert _run_until_done(sched, [first])[0].shape == (6,)
+    sched.close(drain=False)
+
+
+@pytest.mark.parametrize("how", ["eos", "cancel"])
+def test_row_in_flight_of_a_sequence_that_ended_is_dropped(lm, entry, how):
+    """A sequence that ends by value (EOS), or is cancelled, while its row
+    of the next step is in flight: the reply holds no token past its end,
+    `rows_dropped` gains 1, its blocks return to the pool, and the request
+    admitted to that slot next decodes bit-identically to itself alone."""
+    assert entry.paged
+    sched = DecodeScheduler(entry, name=f"drop-{how}", start=False)
+    r = np.random.RandomState(21)
+    prompt = r.randint(2, VOCAB, 6).astype(np.int32)
+    alone, = _staggered_run(entry, [(0, prompt, 10, -1)])
+    if how == "eos":
+        eos = int(alone[3])
+        stop = int(np.argmax(alone == eos)) + 1       # its first occurrence
+        rep = sched.submit(prompt, 10, eos_id=eos)
+        got = _run_until_done(sched, [rep])[0]
+        np.testing.assert_array_equal(got, alone[:stop])
+        assert sched._in_flight is not None      # the stray row, unfetched
+    else:
+        rep = sched.submit(prompt, 10, eos_id=-1)
+        while rep._tokens.qsize() < 3:
+            sched.step_once()
+        assert sched._in_flight is not None
+        rep.cancel()
+        sched.step_once()             # sweeps, then fetches the stray row
+        got = rep.result(timeout=1)
+        np.testing.assert_array_equal(got, alone[:3])
+        assert sched._in_flight is None
+    p = sched._pool                   # its blocks are back, all of them
+    assert p.free == p.total and p.live == 0 and p.reserved == 0
+    follower = r.randint(2, VOCAB, 9).astype(np.int32)
+    rep2 = sched.submit(follower, 8)  # the same slot, the same blocks
+    assert sched._queue[0].slot is None
+    sched.step_once()
+    assert sched._slots[0] is not None and sched._slots[0].reply is rep2
+    check_vs_oracle(lm, follower, _run_until_done(sched, [rep2])[0], 8)
+    assert _counter(sched, "rows_dropped") == 1
+    assert sched.stats()["rows_dropped"] == 1
+    assert p.free == p.total and p.live == 0 and p.reserved == 0
+    sched.close(drain=False)
+
+
+@pytest.mark.parametrize("how", ["drain", "close"])
+def test_drain_and_close_deliver_the_last_steps_tokens(entry, how):
+    """A step in flight is work: the thread neither sleeps nor exits before
+    the last step's tokens are fetched and delivered; and over 3 concurrent
+    sequences of 16 tokens nearly every step is enqueued ahead."""
+    sched = DecodeScheduler(entry, name=f"last-{how}", start=True)
+    r = np.random.RandomState(31)
+    prompts = [r.randint(2, VOCAB, n).astype(np.int32) for n in (3, 5, 4)]
+    want = _staggered_run(entry, [(0, p, 16, -1) for p in prompts])
+    replies = [sched.submit(p, 16, eos_id=-1) for p in prompts]
+    if how == "drain":
+        assert sched.drain(timeout=60)
+        with pytest.raises(Closed):
+            sched.submit([2, 3], 2)
+    sched.close(drain=True, timeout=60)
+    for w, rep in zip(want, replies):
+        assert w.shape == (16,)
+        np.testing.assert_array_equal(rep.result(timeout=1), w)
+    assert sched._in_flight is None
+    st = sched.stats()
+    assert st["rows_dropped"] == 0
+    assert st["steps_ahead"] / st["steps"] >= 0.9
+
+
+def test_failed_fetch_fails_every_reply_with_the_real_error(entry,
+                                                            monkeypatch):
+    """An exception at the fetch of a step in flight reaches every active
+    and queued reply as itself, and the thread ends."""
+    sched = DecodeScheduler(entry, name="boom", start=False)
+    replies = [sched.submit([2, 3, 4], 8, eos_id=-1) for _ in range(5)]
+    sched.step_once()
+    assert sched._in_flight is not None and sched.queued == 1
+
+    def broken(self, step):
+        raise RuntimeError("fetch failed")
+    monkeypatch.setattr(DecodeScheduler, "_deliver", broken)
+    thread = sched.start()._thread
+    for rep in replies:
+        with pytest.raises(RuntimeError, match="fetch failed"):
+            rep.result(timeout=30)
+    thread.join(timeout=30)
+    assert not thread.is_alive() and sched._in_flight is None
 
 
 # ------------------------------------ paged KV pool & prefix cache (r21)

@@ -316,6 +316,33 @@ def test_served_tokens_lie_on_the_references_best_logit(family, lm,
         assert g["finite"] and float(g["gap"].max()) < ATOL
 
 
+def test_slot_handed_on_after_a_dropped_row_starts_from_a_zero_state(
+        staggered):
+    """A sequence that ends by value while its row of the next step is in
+    flight leaves one stray update in its slot's state. The request that
+    takes the slot next (a prompt of one token: no prefill, its first
+    decode step at position 0) decodes what it decodes alone."""
+    entry, submits, outs, _ = staggered
+    (_, first, new), (_, follower, new_f) = submits[0], submits[3]
+    assert len(follower) == 1
+    eos = int(outs[0][2])
+    stop = int(np.argmax(outs[0] == eos)) + 1
+    sched = DecodeScheduler(entry, name="handon", start=False)
+    rep = sched.submit(first, new, eos_id=eos)
+    while not rep.done():
+        sched.step_once()
+    np.testing.assert_array_equal(rep.result(timeout=1), outs[0][:stop])
+    assert sched._in_flight is not None          # the stray row, unfetched
+    rep2 = sched.submit(follower, new_f)
+    while not rep2.done():
+        sched.step_once()
+    assert rep2.result(timeout=1).shape == outs[3].shape
+    np.testing.assert_array_equal(rep2.result(timeout=1), outs[3])
+    stats = sched.stats()
+    assert stats["rows_dropped"] == 1 and stats["state_resets"] == 2
+    sched.close(drain=False)
+
+
 def test_state_counters_and_gauges(staggered):
     entry, submits, outs, stats = staggered
     assert stats["state"] == "kv+recurrent"
