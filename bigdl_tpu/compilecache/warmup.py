@@ -9,8 +9,8 @@ of paying trace + XLA compile. With the persistent cache enabled
 
 The compiled object's XLA cost analysis (flops, bytes accessed, peak
 memory) is routed into the observe metrics registry under
-`compile/<program>/...` — the same numbers bench.py uses for MFU, now
-available for every trainer program at warmup time.
+`compile/<program>/...`, available for every trainer program at warmup
+time.
 """
 
 from __future__ import annotations

@@ -10,8 +10,8 @@ trainer.
                 (dataset/service.py InputService) with placement
                 replaced by a no-op, and report the feed rate plus the
                 pipeline-stage phase table. If the rec/s here is below
-                what `bench.py input`'s device demands, the feed — not
-                the chip — is the wall.
+                what the device demands, the feed — not the chip — is
+                the wall.
 """
 
 from __future__ import annotations

@@ -96,14 +96,6 @@ def _beam_generate(lm, params, state, prompt, max_new_tokens, beam_size,
     return full, scores
 
 
-def _restore_inactive(new, old, active):
-    """Keep only ACTIVE rows' cache updates: inactive slots' cache rows
-    come back bit-identical, so stale content can neither change nor
-    leak (serve/decode.py's slot-bucket contract)."""
-    keep = active.reshape((-1, 1, 1, 1))
-    return tuple(jnp.where(keep, n, o) for n, o in zip(new, old))
-
-
 class GPT2LM(Module):
     """GPT-2 rebuilt on this framework's primitives. apply(params, state,
     tokens (B, T) int32) → (B, T, vocab) logits (head tied to the token
@@ -204,80 +196,17 @@ class GPT2LM(Module):
             dtype=params["wte"].dtype, n_positions=self.n_positions)
 
     # ------------------------------------------- iteration-level decoding
-    # The decode-serving contract (serve/decode.py DecodeEntry):
-    # make_slot_caches / prefill / decode_step over a SLOT batch where
-    # each row is an independent sequence at its own absolute positions.
-    # Per-row numerics are bit-identical to _cached_forward with the
-    # matching scalar start (asserted by tests/test_decode.py).
-    def make_slot_caches(self, params, num_slots: int, max_seq_len: int):
-        """Zero per-layer KV caches of (num_slots, max_seq_len, H, hd) —
-        the persistent slot-bucket pytree the decode engine owns."""
-        H = self.children()["h0"].attn.num_heads
-        hd = self.d_model // H
-        dtype = params["wte"].dtype
-        zeros = lambda: jnp.zeros(                         # noqa: E731
-            (num_slots, max_seq_len, H, hd), dtype)
-        return (tuple(zeros() for _ in range(self.num_layers)),
-                tuple(zeros() for _ in range(self.num_layers)))
-
-    def _slot_hidden(self, params, caches, tokens, positions, active):
-        cks, cvs = caches
-        pos = jnp.clip(positions, 0, self.n_positions - 1)
-        x = params["wte"][tokens] + params["wpe"][pos]
-        new_ck, new_cv = [], []
-        for i in range(self.num_layers):
-            x, ck_i, cv_i = self.children()[f"h{i}"].slot_cached_step(
-                params[f"h{i}"], x, cks[i], cvs[i], pos)
-            new_ck.append(ck_i)
-            new_cv.append(cv_i)
-        return x, (_restore_inactive(tuple(new_ck), cks, active),
-                   _restore_inactive(tuple(new_cv), cvs, active))
-
-    def prefill(self, params, caches, tokens, positions, active):
-        """Write one prompt chunk per slot into the KV caches: tokens/
-        positions (S, C) int32 (absolute positions, row-independent),
-        active (S,) bool — inactive rows' caches are untouched. No
-        logits (the LM head is skipped; decode_step produces tokens).
-        Returns the new caches."""
-        return self._slot_hidden(params, caches, tokens, positions,
-                                 active)[1]
-
-    def _finish_logits(self, params, x):
-        x, _ = self.children()["ln_f"].apply(params["ln_f"], {}, x)
-        return x[:, -1] @ self._head(params).T
-
-    def decode_logits(self, params, caches, tokens_last, positions,
-                      active):
-        """decode_step stopped before the token choice: returns
-        (last-position logits (S, V), new caches) so the serving layer
-        can compose its own sampler (nn/sampling.py) into the fused
-        step."""
-        x, caches = self._slot_hidden(
-            params, caches, tokens_last[:, None], positions[:, None],
-            active)
-        return self._finish_logits(params, x), caches
-
-    def decode_step(self, params, caches, tokens_last, positions,
-                    active):
-        """One iteration-level greedy decode step over the slot batch:
-        tokens_last/positions (S,) int32, active (S,) bool →
-        (next_tokens (S,) int32, new caches). Inactive rows' caches are
-        bit-preserved and their next_tokens are meaningless (the
-        scheduler masks them)."""
-        logits, caches = self.decode_logits(
-            params, caches, tokens_last, positions, active)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
-
-    # -------------------------------------------------- paged KV decoding
-    # The PAGED decode-serving contract (serve/decode.py BlockPool):
-    # same slot-batch semantics, but K/V live in a shared pool of
+    # What the decode engine composes its programs from (serve/decode.py
+    # DecodeEntry._build): the cache pytree, the hidden states of a SLOT
+    # batch where each row is an independent sequence at its own absolute
+    # positions, and the final norm and head. K/V live in a shared pool of
     # fixed-size blocks addressed through a per-slot block table and
     # attention reads the pool where it lies
     # (nn/attention.paged_slot_cached_attend). Per row the same lanes are
-    # attended as by the dense slot path, summed in pool order (the
-    # paged-vs-dense oracle in tests/test_decode.py). Inactive rows and
-    # padded prefill tails are left out of the write instead of
-    # _restore_inactive - they never touch the pool.
+    # attended as by _cached_forward with the matching scalar start,
+    # summed in pool order (the oracle of tests/test_decode.py). Inactive
+    # rows and padded prefill tails are left out of the write - they never
+    # touch the pool.
     def make_paged_slot_caches(self, params, num_blocks: int, block: int):
         """One zero KV pool per layer (nn/attention.make_paged_kv_pool) -
         the shared block pool the decode engine's BlockPool allocates
@@ -289,8 +218,15 @@ class GPT2LM(Module):
                                         params["wte"].dtype)
                      for _ in range(self.num_layers))
 
-    def _paged_slot_hidden(self, params, caches, tokens, positions,
-                           block_table, lengths):
+    def paged_hidden(self, params, caches, tokens, positions,
+                     block_table, lengths, decode=False):
+        """Hidden states of one chunk a slot, its K/V written into the
+        pool: tokens/positions (S, C) int32, block_table (S, M) int32
+        (-1 = unacquired), lengths (S,) int32 = VALID leading tokens per
+        row (0 = inactive; padded tail tokens of a rounded-up bucket are
+        dropped, not written). `decode` (the one-token step, not a
+        prompt chunk) changes nothing here. Returns (x (S, C, d), the
+        new pool caches)."""
         pos = jnp.clip(positions, 0, self.n_positions - 1)
         x = params["wte"][tokens] + params["wpe"][pos]
         pools = []
@@ -300,31 +236,11 @@ class GPT2LM(Module):
             pools.append(pool)
         return x, tuple(pools)
 
-    def paged_prefill(self, params, caches, tokens, positions,
-                      block_table, lengths):
-        """`prefill` against the paged pool: tokens/positions (S, C)
-        int32, block_table (S, M) int32 (-1 = unacquired), lengths (S,)
-        int32 = VALID leading tokens per row (0 = inactive; padded tail
-        tokens of a rounded-up bucket are dropped, not written).
-        Returns the new pool caches."""
-        return self._paged_slot_hidden(params, caches, tokens, positions,
-                                       block_table, lengths)[1]
-
-    def paged_decode_logits(self, params, caches, tokens_last, positions,
-                            active, block_table):
-        """`decode_logits` against the paged pool."""
-        x, caches = self._paged_slot_hidden(
-            params, caches, tokens_last[:, None], positions[:, None],
-            block_table, active.astype(jnp.int32))
-        return self._finish_logits(params, x), caches
-
-    def paged_decode_step(self, params, caches, tokens_last, positions,
-                          active, block_table):
-        """`decode_step` against the paged pool: one fused greedy step,
-        writes at each row's position through its block table."""
-        logits, caches = self.paged_decode_logits(
-            params, caches, tokens_last, positions, active, block_table)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
+    def head_logits(self, params, x):
+        """The final norm and the head on a step's hidden state:
+        x (S, 1, d) -> logits (S, V)."""
+        x, _ = self.children()["ln_f"].apply(params["ln_f"], {}, x)
+        return x[:, -1] @ self._head(params).T
 
 
 def _gelu_exact(x):
@@ -622,49 +538,16 @@ class LlamaBlock(Module):
         dn, _ = c["down"].apply(params["down"], {}, jax.nn.silu(g) * u)
         return x + dn, ck, cv
 
-    def slot_cached_step(self, params, x, ck, cv, positions):
-        """`cached_step` over a slot batch with PER-ROW positions
-        (N, T) int32 — RoPE angles and the causal-over-cache mask are
-        computed per row, so each slot decodes at its own offset
-        (nn/attention.slot_cached_attend). Bit-identical per row to
-        cached_step with the matching scalar start."""
-        from bigdl_tpu.nn.attention import (rotary_embedding,
-                                            slot_cached_attend)
-        c = self.children()
-        attn = c["attn"]
-        if callable(attn.attn_impl):
-            raise ValueError(
-                "slot_cached_step decodes through the dense attention "
-                "core; this block was built with a custom attn_impl "
-                "whose numerics it cannot reproduce")
-        N, T, d = x.shape
-        H, hd = attn.num_heads, attn.head_dim
-        KV = attn.num_kv_heads or H
-        at = params["attn"]
-        h, _ = c["ln1"].apply(params["ln1"], {}, x)
-        q = (h @ at["wq"]).reshape(N, T, H, hd)
-        k = (h @ at["wk"]).reshape(N, T, KV, hd)
-        v = (h @ at["wv"]).reshape(N, T, KV, hd)
-        q = rotary_embedding(q.transpose(0, 2, 1, 3), attn.rope_theta,
-                             positions)
-        k = rotary_embedding(k.transpose(0, 2, 1, 3), attn.rope_theta,
-                             positions).transpose(0, 2, 1, 3)
-        a, ck, cv = slot_cached_attend(q, k, v, ck, cv, positions)
-        x = x + a @ at["wo"]
-        h, _ = c["ln2"].apply(params["ln2"], {}, x)
-        g, _ = c["gate"].apply(params["gate"], {}, h)
-        u, _ = c["up"].apply(params["up"], {}, h)
-        dn, _ = c["down"].apply(params["down"], {}, jax.nn.silu(g) * u)
-        return x + dn, ck, cv
-
     def paged_slot_cached_step(self, params, x, kv_pool, positions,
                                block_table, lengths):
-        """`slot_cached_step` against a PAGED grouped-KV pool
-        (nn/attention.paged_slot_cached_attend) - per-row RoPE as in the
-        dense slot path, K/V written into pool blocks through the slot's
-        block table, the grouped query heads attending to the pool where
-        it lies. Per row the same lanes as slot_cached_step with a dense
-        cache row."""
+        """`cached_step` over a slot batch with PER-ROW positions (N, T)
+        int32 against a PAGED grouped-KV pool
+        (nn/attention.paged_slot_cached_attend): RoPE angles and the
+        causal-over-cache mask are computed per row, so each slot decodes
+        at its own offset; K/V are written into pool blocks through the
+        slot's block table, the grouped query heads attending to the pool
+        where it lies. Per row the same lanes as cached_step with the
+        matching scalar start."""
         from bigdl_tpu.nn.attention import (rotary_embedding,
                                             paged_slot_cached_attend)
         c = self.children()
@@ -792,59 +675,6 @@ class LlamaLM(Module):
 
     # ------------------------------------------- iteration-level decoding
     # Same decode-serving contract as GPT2LM (serve/decode.py): grouped
-    # KV caches, per-row RoPE offsets, bit-preserved inactive rows.
-    def make_slot_caches(self, params, num_slots: int, max_seq_len: int):
-        """Zero per-layer grouped-KV caches (num_slots, max_seq_len, KV,
-        hd) — the persistent slot-bucket pytree."""
-        attn0 = self.children()["l0"].children()["attn"]
-        KV = attn0.num_kv_heads or attn0.num_heads
-        dtype = params["embed"].dtype
-        zeros = lambda: jnp.zeros(                         # noqa: E731
-            (num_slots, max_seq_len, KV, attn0.head_dim), dtype)
-        return (tuple(zeros() for _ in range(self.num_layers)),
-                tuple(zeros() for _ in range(self.num_layers)))
-
-    def _slot_hidden(self, params, caches, tokens, positions, active):
-        cks, cvs = caches
-        x = params["embed"][tokens]
-        new_ck, new_cv = [], []
-        for i in range(self.num_layers):
-            x, ck_i, cv_i = self.children()[f"l{i}"].slot_cached_step(
-                params[f"l{i}"], x, cks[i], cvs[i], positions)
-            new_ck.append(ck_i)
-            new_cv.append(cv_i)
-        return x, (_restore_inactive(tuple(new_ck), cks, active),
-                   _restore_inactive(tuple(new_cv), cvs, active))
-
-    def prefill(self, params, caches, tokens, positions, active):
-        """Write one prompt chunk per slot into the grouped-KV caches
-        (see GPT2LM.prefill — same contract). Returns the new caches."""
-        return self._slot_hidden(params, caches, tokens, positions,
-                                 active)[1]
-
-    def _finish_logits(self, params, x):
-        x, _ = self.children()["norm"].apply(params["norm"], {}, x)
-        return x[:, -1] @ self._head(params).T
-
-    def decode_logits(self, params, caches, tokens_last, positions,
-                      active):
-        """(last-position logits (S, V), new caches) — see
-        GPT2LM.decode_logits; the serving layer's sampler hook."""
-        x, caches = self._slot_hidden(
-            params, caches, tokens_last[:, None], positions[:, None],
-            active)
-        return self._finish_logits(params, x), caches
-
-    def decode_step(self, params, caches, tokens_last, positions,
-                    active):
-        """One iteration-level greedy decode step over the slot batch
-        (see GPT2LM.decode_step — same contract)."""
-        logits, caches = self.decode_logits(
-            params, caches, tokens_last, positions, active)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
-
-    # -------------------------------------------------- paged KV decoding
-    # Same paged contract as GPT2LM (serve/decode.py BlockPool): grouped
     # KV pools, per-row RoPE offsets, inactive rows and padded tails left
     # out of the write.
     def make_paged_slot_caches(self, params, num_blocks: int, block: int):
@@ -858,8 +688,10 @@ class LlamaLM(Module):
                                         params["embed"].dtype)
                      for _ in range(self.num_layers))
 
-    def _paged_slot_hidden(self, params, caches, tokens, positions,
-                           block_table, lengths):
+    def paged_hidden(self, params, caches, tokens, positions,
+                     block_table, lengths, decode=False):
+        """Hidden states of one chunk a slot against the paged grouped-KV
+        pool (see GPT2LM.paged_hidden — same contract)."""
         x = params["embed"][tokens]
         pools = []
         for i in range(self.num_layers):
@@ -869,28 +701,10 @@ class LlamaLM(Module):
             pools.append(pool)
         return x, tuple(pools)
 
-    def paged_prefill(self, params, caches, tokens, positions,
-                      block_table, lengths):
-        """Paged prompt-chunk prefill (see GPT2LM.paged_prefill — same
-        contract). Returns the new pool caches."""
-        return self._paged_slot_hidden(params, caches, tokens, positions,
-                                       block_table, lengths)[1]
-
-    def paged_decode_logits(self, params, caches, tokens_last, positions,
-                            active, block_table):
-        """`decode_logits` against the paged grouped-KV pool."""
-        x, caches = self._paged_slot_hidden(
-            params, caches, tokens_last[:, None], positions[:, None],
-            block_table, active.astype(jnp.int32))
-        return self._finish_logits(params, x), caches
-
-    def paged_decode_step(self, params, caches, tokens_last, positions,
-                          active, block_table):
-        """One fused greedy decode step against the paged pool (see
-        GPT2LM.paged_decode_step — same contract)."""
-        logits, caches = self.paged_decode_logits(
-            params, caches, tokens_last, positions, active, block_table)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
+    def head_logits(self, params, x):
+        """x (S, 1, d) -> logits (S, V): the final norm and the head."""
+        x, _ = self.children()["norm"].apply(params["norm"], {}, x)
+        return x[:, -1] @ self._head(params).T
 
 
 def from_llama(hf_model, attn_impl="dense", block_size=512,

@@ -15,8 +15,7 @@ both: a full block's keys and values, in the block pool that
 nn/attention.make_paged_kv_pool lays out and `BlockPool` allots; a linear
 block's `{"S", "conv"}`, resident by slot (leading axis `num_slots`), which
 no block holds. `slot_resident` tells the decode engine which leaf is
-which. Only the paged contract is here: the dense slot bucket of
-`GPT2LM`/`LlamaLM` would be a third layout of the same keys and values.
+which.
 """
 
 from __future__ import annotations
@@ -169,7 +168,7 @@ class OlmoHybridLM(Module):
         return self._logits(params, x), state
 
     # -------------------------------------------------- paged decoding
-    # The paged decode contract of serve/decode.py, with two kinds of
+    # What serve/decode.py composes its programs from, with two kinds of
     # cache leaf: a full block's pool of KV blocks, a linear block's state
     # by slot.
     def make_paged_slot_caches(self, params, num_blocks: int, block: int,
@@ -195,8 +194,14 @@ class OlmoHybridLM(Module):
         return tuple(jax.tree.map(lambda _: blk.kind == LINEAR, c)
                      for (_, blk), c in zip(self._blocks(), caches))
 
-    def _paged_hidden(self, params, caches, tokens, positions, block_table,
-                      lengths, decode):
+    def paged_hidden(self, params, caches, tokens, positions, block_table,
+                     lengths, decode=False):
+        """Hidden states of one chunk a slot: tokens/positions (S, C)
+        int32, block_table (S, M) int32, lengths (S,) int32 = valid
+        leading tokens a row (0 = inactive). `decode` says the chunk is a
+        step's one token: the linear layers then take their recurrence and
+        not the chunk form, which a one-token prompt chunk keeps. Returns
+        (x (S, C, d), the new caches)."""
         x = params["embed"][tokens]
         new = []
         for (name, blk), cache in zip(self._blocks(), caches):
@@ -214,25 +219,6 @@ class OlmoHybridLM(Module):
             new.append(cache)
         return x, tuple(new)
 
-    def paged_prefill(self, params, caches, tokens, positions, block_table,
-                      lengths):
-        """One prompt chunk a slot into the caches: tokens/positions (S,
-        C) int32, block_table (S, M) int32, lengths (S,) int32 = valid
-        leading tokens a row (0 = inactive). Returns the new caches."""
-        return self._paged_hidden(params, caches, tokens, positions,
-                                  block_table, lengths, decode=False)[1]
-
-    def paged_decode_logits(self, params, caches, tokens_last, positions,
-                            active, block_table):
-        """One token a slot: (last-position logits (S, V), new caches)."""
-        x, caches = self._paged_hidden(
-            params, caches, tokens_last[:, None], positions[:, None],
-            block_table, active.astype(jnp.int32), decode=True)
-        return self._logits(params, x[:, -1]), caches
-
-    def paged_decode_step(self, params, caches, tokens_last, positions,
-                          active, block_table):
-        """One fused greedy decode step over the slot batch."""
-        logits, caches = self.paged_decode_logits(
-            params, caches, tokens_last, positions, active, block_table)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
+    def head_logits(self, params, x):
+        """x (S, 1, d) -> logits (S, V): the final norm and the head."""
+        return self._logits(params, x[:, -1])

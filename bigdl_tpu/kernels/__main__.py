@@ -6,7 +6,7 @@
 
 `tune` sweeps every (kernel, shape) of a named shape set (see
 `autotune.SHAPE_SETS`; default "smoke" — CPU-interpreter-sized; "bench"
-mirrors the bench.py kernel shapes) and publishes the winners; `stats`
+holds shapes of real-hardware size) and publishes the winners; `stats`
 prints the committed table grouped by kernel plus staging dirs; `clear`
 removes everything under the root. DIR defaults to
 BIGDL_TPU_AUTOTUNE_CACHE (falling back to `<compile cache dir>/autotune`

@@ -602,8 +602,8 @@ _DEFAULTS: Dict[str, Dict] = {
 }
 
 # named shape sets for the offline CLI sweep (python -m bigdl_tpu.kernels
-# tune SET): "smoke" is CPU-interpreter-sized, "bench" mirrors the shapes
-# bench.py kernels times on real hardware
+# tune SET): "smoke" is CPU-interpreter-sized, "bench" holds shapes of
+# real-hardware size
 SHAPE_SETS: Dict[str, Sequence[Tuple[str, Dict]]] = {
     "smoke": (
         ("flash_attention", {"b": 2, "h": 2, "tq": 64, "tk": 64, "d": 32,

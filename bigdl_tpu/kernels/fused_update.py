@@ -19,7 +19,7 @@ the entire update is a single kernel:
     the same math as one fused elementwise expression — on the flat
     layout a whole model's update is ~15 ops instead of ~10 x n_leaves.
 
-Layouts (and what a CPU-mesh run of `bench.py kernels` showed, PR 11):
+Layouts:
   * ``flat``  — concatenate all float leaves (cast to fp32), update the
     one flat vector through the Pallas kernel, split back (per-leaf
     dtype cast fused into the epilogue). This is the TPU layout: the
@@ -29,9 +29,8 @@ Layouts (and what a CPU-mesh run of `bench.py kernels` showed, PR 11):
     dominates — i.e. on the real chip with many leaves.
   * ``leaf``  — identical fused math applied leaf-wise in the leaf's
     native dtype, no assembly copies. On CPU (where XLA's loop fusion
-    already folds the tree-map update into one pass per leaf — measured
-    on the 8-virtual-device mesh, the flat assembly copies make it a
-    net LOSS there) and on ZeRO-1/TP-sharded trees (a concat would
+    already folds the tree-map update into one pass per leaf, so the
+    flat assembly copies buy nothing) and on ZeRO-1/TP-sharded trees (a concat would
     re-gather exactly the state the sharding distributed) this is the
     right engine, and it is bitwise identical to the oracle.
   * ``auto``  — flat+Pallas on a TPU backend, leaf elsewhere. The

@@ -6,7 +6,7 @@ train-step throughput on synthetic data).
     python -m bigdl_tpu.models.perf --model inception-v2 --dtype bf16
 
 Timing uses the chained-dispatch + wait-for-completion protocol of
-`utils/sync.py` (see bench.py)."""
+`utils/sync.py`."""
 
 from __future__ import annotations
 

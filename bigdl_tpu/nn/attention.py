@@ -184,8 +184,10 @@ def cached_attend(q_heads, k_chunk, v_chunk, ck, cv, start):
 
 def slot_cached_attend(q_heads, k_chunk, v_chunk, ck, cv, positions):
     """`cached_attend` batched over a SLOT dimension with per-row start
-    offsets — the decode-serving core (serve/decode.py): row n of the
-    batch is an independent sequence sitting at its own absolute
+    offsets over one dense cache row a slot — the reference that
+    `paged_slot_cached_attend`, which serve/decode.py serves from, is
+    tested against (tests/test_attention.py, tests/test_decode.py): row n
+    of the batch is an independent sequence sitting at its own absolute
     positions `positions[n]` (N, T) int32, so its chunk is written at
     `[positions[n, 0], positions[n, 0] + T)` of ITS cache row and
     attends causally over its own prefix only.
@@ -197,7 +199,7 @@ def slot_cached_attend(q_heads, k_chunk, v_chunk, ck, cv, positions):
     the softmax, so stale/poisoned cache content beyond the frontier
     contributes exactly zero (the PR 5/8 valid-mask discipline applied
     along the sequence axis). Masking INACTIVE rows entirely is the
-    caller's job (their cache rows are restored post-hoc).
+    caller's job.
 
     q_heads (N, H, T, hd); k_chunk/v_chunk (N, T, Hc, hd) with Hc == H
     or a grouped divisor (GQA). Returns ((N, T, H*hd), new_ck, new_cv).
@@ -267,10 +269,8 @@ def paged_slot_cached_attend(q_heads, k_chunk, v_chunk, kv_pool, positions,
     from `positions[:, 0]`; `lengths` (N,) int32 is the count of VALID
     leading tokens in this chunk per row (0 for inactive rows): padded
     tail tokens of a rounded-up prefill bucket, inactive rows and
-    unacquired blocks are left out of the write - the paged analogue of
-    the dense path's tolerated-garbage + `_restore_inactive` discipline,
-    required here because a padded write could land past the slot's
-    reserved blocks.
+    unacquired blocks are left out of the write, because a padded write
+    could land past the slot's reserved blocks.
 
     q_heads (N, H, T, hd); k_chunk/v_chunk (N, T, Hc, hd), Hc == H or a
     grouped divisor (GQA: the H/Hc query heads of a group attend to its
@@ -526,51 +526,16 @@ class TransformerLayer(Module):
                               self.ln2.apply(params["ln2"], {}, x)[0])
         return x + f, ck, cv
 
-    def slot_cached_step(self, params, x, ck, cv, positions):
-        """`cached_step` over a slot batch with PER-ROW positions
-        (N, T) int32 — each row is an independent sequence at its own
-        offset (slot_cached_attend). Per-row numerics are bit-identical
-        to `cached_step` with the matching scalar start. Self-attention
-        blocks only; same custom-attn_impl refusal as cached_step."""
-        if self.cross:
-            raise ValueError("slot_cached_step supports self-attention "
-                             "decoder blocks only")
-        if callable(self.attn.attn_impl):
-            raise ValueError(
-                "slot_cached_step decodes through the dense attention "
-                "core; this layer was built with a custom attn_impl "
-                "whose numerics it cannot reproduce")
-        N, T, d = x.shape
-        H = self.attn.num_heads
-        hd = d // H
-        at = params["attn"]
-        h, _ = self.ln1.apply(params["ln1"], {}, x)
-        q = h @ at["wq"]
-        k = h @ at["wk"]
-        v = h @ at["wv"]
-        if self.attn.bias:
-            q, k, v = q + at["bq"], k + at["bk"], v + at["bv"]
-        q = q.reshape(N, T, H, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(N, T, H, hd)
-        v = v.reshape(N, T, H, hd)
-        a, ck, cv = slot_cached_attend(q, k, v, ck, cv, positions)
-        a = a @ at["wo"]
-        if self.attn.bias:
-            a = a + at["bo"]
-        x = x + a
-        f, _ = self.ffn.apply(params["ffn"], {},
-                              self.ln2.apply(params["ln2"], {}, x)[0])
-        return x + f, ck, cv
-
     def paged_slot_cached_step(self, params, x, kv_pool, positions,
                                block_table, lengths):
-        """`slot_cached_step` against a PAGED KV pool: same hand-rolled
-        projection chain, but the chunk's K/V are written into the pool's
-        blocks through the slot's block table and attention reads the
-        pool where it lies (paged_slot_cached_attend). Per row the same
-        lanes are attended as by `slot_cached_step` with a dense cache
-        row, summed in pool order. Self-attention blocks only; same
-        custom-attn_impl refusal as cached_step."""
+        """`cached_step` over a slot batch with PER-ROW positions (N, T)
+        int32 against a PAGED KV pool: each row is an independent
+        sequence at its own offset, the chunk's K/V are written into the
+        pool's blocks through the slot's block table and attention reads
+        the pool where it lies (paged_slot_cached_attend). Per row the
+        same lanes are attended as by `cached_step` with the matching
+        scalar start, summed in pool order. Self-attention blocks only;
+        same custom-attn_impl refusal as cached_step."""
         if self.cross:
             raise ValueError("paged_slot_cached_step supports self-"
                              "attention decoder blocks only")

@@ -32,9 +32,7 @@ rebuilt for the HTTP plane:
     through the shared ``export.render_prometheus``).
 
 Cadence contract unchanged: aggregation reads HTTP + host-side state
-only — polling the fleet adds zero device syncs to any train loop
-(bench.py overhead re-measured with the full fleet plane armed,
-BENCH_r16).
+only — polling the fleet adds zero device syncs to any train loop.
 """
 
 from __future__ import annotations
@@ -311,20 +309,18 @@ class FleetAggregator:
                         dec["_occ_sum"] += float(occ)
                         dec["_occ_n"] += 1
                     dec["peers"] += 1
-                    if d.get("paged"):
-                        # paged-KV pool economics: block counts are
-                        # additive across peers; prefix hit rate is
-                        # re-derived from the summed hit/miss counters
-                        for k in ("kv_blocks_total", "kv_blocks_free",
-                                  "kv_blocks_cached", "prefix_hits",
-                                  "prefix_misses"):
-                            dec[k] = (dec.get(k, 0)
-                                      + int(d.get(k, 0) or 0))
-                        seen = (dec.get("prefix_hits", 0)
-                                + dec.get("prefix_misses", 0))
-                        dec["prefix_hit_rate"] = (
-                            round(dec["prefix_hits"] / seen, 4)
-                            if seen else 0.0)
+                    # KV pool economics: block counts are additive
+                    # across peers; prefix hit rate is re-derived from
+                    # the summed hit/miss counters
+                    for k in ("kv_blocks_total", "kv_blocks_free",
+                              "kv_blocks_cached", "prefix_hits",
+                              "prefix_misses"):
+                        dec[k] = dec.get(k, 0) + int(d.get(k, 0) or 0)
+                    seen = (dec.get("prefix_hits", 0)
+                            + dec.get("prefix_misses", 0))
+                    dec["prefix_hit_rate"] = (
+                        round(dec["prefix_hits"] / seen, 4)
+                        if seen else 0.0)
             fo = p.payload.get("failover") or {}
             for k in ("slice_losses", "grow_backs", "lost_slices"):
                 if k in fo:

@@ -2,7 +2,7 @@
 
 HBM is the scarce resource on a TPU, and the platform now fills it from
 four unmetered directions at once: trainer param/slot trees (ZeRO-1),
-the decode path's persistent (num_slots, max_seq_len) KV buckets, the
+the decode path's persistent paged KV pools, the
 data service's double-buffered H2D staging, and per-program XLA
 workspace. The reference treats memory as a first-class managed
 resource (MKL-DNN `MemoryData` + native allocation accounting, SURVEY
@@ -466,29 +466,18 @@ class BufferLedger:
 
     def headroom(self) -> dict:
         """Capacity planning from the ledger: free bytes against the
-        limit (None when no limit is known), plus closed-form "one more"
-        estimates — additional decode slots per kv_cache owner (its
-        bytes / num_slots) and whether one more copy of the largest
-        serve model's params fits."""
+        limit (None when no limit is known), each decode KV pool's live
+        free-block count, and whether one more copy of the largest serve
+        model's params fits."""
         util = self.utilization()
         limit = util["bytes_limit"]
         free = (limit - util["bytes_in_use"]) if limit else None
-        decode_slots: Dict[str, dict] = {}
         kv_pools: Dict[str, dict] = {}
         largest_model = None
         with self._lock:
             for name, o in self._owners.items():
-                slots = o.meta.get("slots")
-                if o.kind == "kv_cache" and slots:
-                    per_slot = o.bytes // max(1, int(slots))
-                    decode_slots[name] = {
-                        "bytes_per_slot": per_slot,
-                        "additional_slots": (free // per_slot
-                                             if free is not None and per_slot
-                                             else None),
-                    }
                 if o.kind == "kv_pool":
-                    # paged decode pools: headroom is the pool's own LIVE
+                    # decode pools: headroom is the pool's own LIVE
                     # free-block count (serve/decode.py keeps the meta
                     # current), not a closed-form byte estimate
                     kv_pools[name] = {
@@ -500,8 +489,7 @@ class BufferLedger:
                 if o.kind == "params" and name.startswith("serve/"):
                     if largest_model is None or o.bytes > largest_model[1]:
                         largest_model = (name, o.bytes)
-        out = {"free_bytes": free, "decode_slots": decode_slots or None,
-               "kv_pools": kv_pools or None}
+        out = {"free_bytes": free, "kv_pools": kv_pools or None}
         if largest_model is not None:
             out["one_more_model"] = {
                 "model": largest_model[0], "bytes": largest_model[1],
@@ -849,10 +837,10 @@ def render_table(payload: dict) -> str:
     head = payload.get("headroom") or {}
     if head.get("free_bytes") is not None:
         lines.append(f"\nheadroom: {_fmt_bytes(head['free_bytes'])} free")
-        for name, d in (head.get("decode_slots") or {}).items():
+        for name, d in (head.get("kv_pools") or {}).items():
             lines.append(
-                f"  {name}: {_fmt_bytes(d['bytes_per_slot'])}/slot -> "
-                f"{d['additional_slots']} more slot(s) fit")
+                f"  {name}: {d['blocks_free']} of {d['blocks']} KV blocks "
+                f"free")
         om = head.get("one_more_model")
         if om:
             lines.append(f"  one more {om['model']} "

@@ -415,8 +415,8 @@ def data_wait_fraction(snapshot: dict) -> Optional[dict]:
     span `_observed_batches` wraps around each batch fetch) over the
     loop's total accounted time (data_wait + dispatch + flush +
     checkpoint, the disjoint sibling phases of the step loop). This is
-    the number `bench.py input` gates on and the input service exists
-    to drive to ~0; None when the snapshot has no step-loop phases."""
+    the number the input service exists to drive to ~0; None when the
+    snapshot has no step-loop phases."""
     hists = snapshot.get("histograms", {})
 
     def total(name):

@@ -36,7 +36,7 @@ Endpoints (all GET, all JSON unless noted):
 Cadence contract: every handler reads host-side registry/ring state
 only — a scrape NEVER touches a device value, so polling /statusz under
 load adds zero host syncs to the train loop (asserted by
-tests/test_statusz.py, measured by bench.py overhead / BENCH_r14).
+tests/test_statusz.py).
 
 Enable with BIGDL_TPU_STATUSZ_PORT (0 = off; process 0 only — the
 other hosts of a multihost job export files with `.p<i>` suffixes and

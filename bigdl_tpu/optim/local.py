@@ -1094,7 +1094,7 @@ class Optimizer:
         # bounded: long runs used to grow this list forever; the full
         # distribution lives in the phase/train/checkpoint log-bucket
         # histogram (observe/metrics.py), this deque keeps only the
-        # newest samples for bench.py checkpoint mode
+        # newest samples
         self._ckpt_stalls: "deque[float]" = deque(maxlen=256)
         if self.ckpt_path is not None:
             from bigdl_tpu.utils import config as _cfg
@@ -1663,9 +1663,9 @@ class Optimizer:
             else:
                 self._checkpointer().save(path, trees, meta,
                                           root=self.ckpt_path)
-        # per-save blocking stall: newest samples ride the bounded deque
-        # (bench.py checkpoint mode), the full run's distribution lives
-        # in the phase/train/checkpoint log-bucket histogram
+        # per-save blocking stall: newest samples ride the bounded deque,
+        # the full run's distribution lives in the phase/train/checkpoint
+        # log-bucket histogram
         self._ckpt_stalls.append(time.perf_counter() - t0)
         log.info("checkpoint -> %s (%.1f ms stall)", path,
                  self._ckpt_stalls[-1] * 1e3)

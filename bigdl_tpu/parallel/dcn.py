@@ -102,7 +102,7 @@ def wire_bytes_per_exchange(params_like, compress: str,
     all-reduce payload for every floating gradient leaf: fp32 raw, bf16
     halves it, int8 sends one byte per element (padded to the block
     size) plus one fp32 scale per block. Feeds the exchange/wire_bytes
-    counter and the simulated-DCN throttle in `bench.py dcn`."""
+    counter."""
     import jax
     total = 0
     for leaf in jax.tree.leaves(params_like):

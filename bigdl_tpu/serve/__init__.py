@@ -23,8 +23,8 @@ handles LIVE traffic:
                    batch-fill SLO metrics through the observe registry,
                    SIGTERM drain riding the resilience handler;
   * **decode**   — iteration-level continuous batching for
-                   autoregressive LMs: persistent (slots, max_seq_len)
-                   KV-slot buckets, chunked prompt prefill through
+                   autoregressive LMs: a persistent paged KV pool
+                   a model, chunked prompt prefill through
                    length-bucketed AOT programs, one fused greedy step
                    per iteration over the ragged active set, requests
                    joining free slots and retiring (EOS/max_new) EVERY

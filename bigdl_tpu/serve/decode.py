@@ -7,18 +7,29 @@ blocking). This module is the decode-native path (Orca-style
 iteration-level scheduling + vLLM-style slot KV management, scaled to
 this codebase's discipline):
 
-  * **KV-slot bucket** — per-layer `(S, L, H, hd)` cache arrays
-    (`model.make_slot_caches`), allocated ONCE per model and donated
-    across steps (TPU: the step writes in place; CPU: donation is a
-    no-op). Each of the S slots is an independent sequence at its own
-    absolute offset.
+  * **paged KV pool** — the KV cache is one shared pool of fixed-size
+    blocks a layer (`BIGDL_TPU_SERVE_KV_BLOCK` tokens each, in the
+    layout of nn/attention.make_paged_kv_pool), allocated ONCE per model
+    and donated across steps (TPU: the step writes in place; CPU:
+    donation is a no-op), plus per-slot int32 block tables (vLLM's
+    PagedAttention discipline). Each of the S slots is an independent
+    sequence at its own absolute offset; the programs write a chunk's
+    K/V into the slot's blocks in place and attend over the pool where
+    it lies, under a mask of which slot owns which block
+    (nn/attention.paged_slot_cached_attend), so no per-slot copy of the
+    cache is ever made. HBM cost follows LIVE sequences, not the
+    (num_slots x max_seq_len) worst case: slots acquire blocks lazily
+    as their frontier crosses a block boundary and retire returns them
+    to the free list; admission refuses with a block-level
+    `CapacityError` capacity report when a request can never fit the
+    pool.
   * **fused decode step** — ONE AOT-precompiled program
-    `(params, caches, tokens_last, positions, active) ->
-    (next_tokens, caches)` over the ragged active set: the valid-mask
-    trick along both the slot axis (inactive rows' caches are restored
-    bit-identically — pad-poison can never leak, PR 5/8) and the
-    sequence axis (entries past a row's frontier are masked to NEG_INF
-    pre-softmax, so stale cache content contributes exactly zero).
+    `(params, caches, tokens_last, positions, active, block_table) ->
+    (next_tokens, caches)` over the ragged active set: inactive rows
+    and a rounded-up bucket's padded tail are left out of the pool
+    write (pad-poison can never leak, PR 5/8), and entries past a row's
+    frontier are masked to NEG_INF pre-softmax, so stale pool content
+    contributes exactly zero.
   * **chunked prefill** — prompts stream into their slot's cache
     through power-of-two length-bucketed AOT prefill programs
     (`BIGDL_TPU_SERVE_PREFILL_CHUNK` caps the chunk), so a long prompt
@@ -56,32 +67,22 @@ this codebase's discipline):
     lands a few ms after the fetch, and its prefill then runs behind
     one step and not behind two.
 
-The model contract is duck-typed: `make_slot_caches(params, S, L)`,
-`prefill(params, caches, tokens, positions, active)`,
-`decode_step(params, caches, tokens_last, positions, active)`,
-plus `vocab_size` and (default) `eos_id` — provided by the HF bridge's
-GPT2LM and LlamaLM (interop/huggingface.py).
+The model contract is duck-typed and names what a model owns
+(`_PAGED_CONTRACT`; docs/serving.md "What a served model provides"):
+its cache pytree (`make_paged_slot_caches`), its hidden-state function
+over a slot batch (`paged_hidden`) and its final norm and head
+(`head_logits`), plus `vocab_size`, (default) `eos_id` and, where the
+cache holds more than keys and values, `slot_resident`. The prefill
+program, the step and the choice of token (argmax or nn/sampling.py)
+are composed from them in `DecodeEntry._build`. GPT2LM and LlamaLM
+(interop/huggingface.py) and OlmoHybridLM (interop/olmo_hybrid.py)
+provide it.
 
-**Paged KV (default)**: models carrying the paged contract
-(`make_paged_slot_caches` / `paged_prefill` / `paged_decode_step`)
-allocate the KV cache as a shared pool of fixed-size blocks
-(`BIGDL_TPU_SERVE_KV_BLOCK` tokens each, one array a layer in the layout
-of nn/attention.make_paged_kv_pool) plus per-slot int32 block tables
-(vLLM's PagedAttention discipline): the programs write a chunk's K/V
-into the donated pool's blocks in place and attend over the pool where
-it lies, under a mask of which slot owns which block
-(nn/attention.paged_slot_cached_attend), so no per-slot copy of the
-cache is ever made. HBM cost follows LIVE
-sequences, not the (num_slots x max_seq_len) worst case; slots acquire
-blocks lazily as their frontier crosses a block boundary and retire
-returns them to the free list; admission refuses with a block-level
-`CapacityError` capacity report when a request can never fit the pool.
 On top of the block table sits the **prefix cache**: whole prompt
 blocks finished by prefill are published under a chained token-hash
 key (stage-at-admit / commit-as-the-frontier-passes), so N requests
-sharing a system
-prompt pay its prefill once; entries are refcounted, copy-on-write
-never triggers (matching is block-granular, the divergence block is
+sharing a system prompt pay its prefill once; entries are refcounted,
+copy-on-write never triggers (matching is block-granular, the divergence block is
 always private), and unreferenced entries are retained up to a cap,
 evicted LRU on demand and swept wholesale under memory-watchdog
 pressure.
@@ -125,9 +126,10 @@ log = logging.getLogger("bigdl_tpu")
 # whether the step in flight is done (DecodeScheduler._await_taker)
 _TAKER_POLL_S = 0.0005
 
-_DECODE_CONTRACT = ("make_slot_caches", "prefill", "decode_step")
-_PAGED_CONTRACT = ("make_paged_slot_caches", "paged_prefill",
-                   "paged_decode_step")
+# what a served model provides: its cache pytree, its hidden-state function
+# over a slot batch, its final norm and head (DecodeEntry._build composes the
+# programs from them)
+_PAGED_CONTRACT = ("make_paged_slot_caches", "paged_hidden", "head_logits")
 
 
 class BlockPool:
@@ -354,9 +356,10 @@ def prefill_buckets(chunk: int) -> Tuple[int, ...]:
 
 
 class DecodeEntry:
-    """One decode-served model: the (num_slots, max_seq_len) KV-slot
-    bucket, AOT prefill + decode executables (mesh shardings pinned),
-    and the placed params the programs close over.
+    """One decode-served model: its paged KV pool (with, for a model
+    that has one, its state resident by slot), AOT prefill + decode
+    executables (mesh shardings pinned), and the placed params the
+    programs close over.
 
     Built by `ModelEntry` under `decode=True` registration
     (serve/registry.py); the scheduler (`DecodeScheduler`) drives it."""
@@ -374,16 +377,22 @@ class DecodeEntry:
                  sampling: Optional[bool] = None,
                  kv_shard: Optional[bool] = None):
         from bigdl_tpu.utils import config
-        has_dense = all(hasattr(model, m) for m in _DECODE_CONTRACT)
-        has_paged = all(hasattr(model, m) for m in _PAGED_CONTRACT)
-        if not has_dense and not has_paged:
+        # `paged` (here, on ModelEntry and on ServeEngine.register) is kept
+        # only because benchmark/configs/*-serve.json hold `"paged": true`
+        # and the harness passes its whole `register` object as keywords
+        if paged is not None and not paged:
+            raise ValueError(
+                f"paged=False: the dense slot bucket was removed; "
+                f"{type(model).__name__} is served from the paged KV pool "
+                f"(leave `paged` out)")
+        lacks = [m for m in _PAGED_CONTRACT if not hasattr(model, m)]
+        if lacks:
             raise TypeError(
-                f"decode=True needs a model implementing the slot-decode "
-                f"contract {_DECODE_CONTRACT} or the paged one "
-                f"{_PAGED_CONTRACT}; {type(model).__name__} lacks "
-                f"{[m for m in _DECODE_CONTRACT if not hasattr(model, m)]} "
-                f"(GPT2LM/LlamaLM from interop/huggingface.py provide "
-                f"both)")
+                f"decode=True needs a model implementing the paged "
+                f"slot-decode contract {_PAGED_CONTRACT}; "
+                f"{type(model).__name__} lacks {lacks} (GPT2LM/LlamaLM "
+                f"from interop/huggingface.py and OlmoHybridLM from "
+                f"interop/olmo_hybrid.py provide it)")
         self.name = name
         self.model = model
         self.params = params
@@ -418,44 +427,23 @@ class DecodeEntry:
                 f"decode model {name!r} carries no eos_id — pass "
                 f"eos_id= at registration")
         self.vocab_size = int(model.vocab_size)
-        # ---------------------------------------------- paged resolution
-        if paged and not has_paged:
-            raise TypeError(
-                f"paged=True needs a model implementing the paged "
-                f"slot-decode contract {_PAGED_CONTRACT}; "
-                f"{type(model).__name__} lacks "
-                f"{[m for m in _PAGED_CONTRACT if not hasattr(model, m)]}")
-        want_paged = (bool(config.get("SERVE_KV_PAGED")) if paged is None
-                      else bool(paged))
-        self.paged = want_paged and has_paged
-        if not self.paged and not has_dense:
-            raise TypeError(
-                f"{type(model).__name__} carries the paged slot-decode "
-                f"contract only (it has no {_DECODE_CONTRACT}): register "
-                f"it with paged=True / BIGDL_TPU_SERVE_KV_PAGED=1")
         # a model whose cache holds more than keys and values says which
         # leaves are resident by slot (leading axis num_slots: a recurrent
-        # state) and not by block; only the paged layout carries them
-        self.slot_state = self.paged and hasattr(model, "slot_resident")
+        # state) and not by block
+        self.slot_state = hasattr(model, "slot_resident")
         self.kv_block = int(kv_block if kv_block is not None
                             else config.get("SERVE_KV_BLOCK"))
         if self.kv_block < 1:
             raise ValueError(f"kv_block must be >= 1, got "
                              f"{self.kv_block}")
         self.blocks_per_slot = -(-self.max_seq_len // self.kv_block)
-        dense_equiv = self.num_slots * self.blocks_per_slot
         pool = int(kv_pool_blocks if kv_pool_blocks is not None
                    else config.get("SERVE_KV_POOL_BLOCKS"))
-        self.pool_blocks = pool if pool > 0 else dense_equiv
+        # 0: every slot at full length
+        self.pool_blocks = (pool if pool > 0
+                            else self.num_slots * self.blocks_per_slot)
         self.sampling = (bool(config.get("SERVE_SAMPLING"))
                          if sampling is None else bool(sampling))
-        logits_fn = ("paged_decode_logits" if self.paged
-                     else "decode_logits")
-        if self.sampling and not hasattr(model, logits_fn):
-            raise TypeError(
-                f"sampling=True needs a model exposing {logits_fn} "
-                f"(the decode_step stopped before the token choice); "
-                f"{type(model).__name__} lacks it")
         if self.slot_state and prefix_cache:
             raise ValueError(
                 f"prefix_cache=True cannot serve {type(model).__name__}: "
@@ -463,7 +451,7 @@ class DecodeEntry:
                 f"state no KV block holds (the prefix cache hashes token "
                 f"blocks; the slot-resident state is not snapshotted at "
                 f"block boundaries)")
-        self.prefix_cache = self.paged and not self.slot_state and (
+        self.prefix_cache = not self.slot_state and (
             bool(config.get("SERVE_PREFIX_CACHE"))
             if prefix_cache is None else bool(prefix_cache))
         cap = int(prefix_cache_blocks if prefix_cache_blocks is not None
@@ -473,9 +461,6 @@ class DecodeEntry:
                          if kv_shard is None else bool(kv_shard))
         self._shard_axis = None
         if self.kv_shard:
-            if not self.paged:
-                raise ValueError("kv_shard=True needs the paged KV pool "
-                                 "(paged=True)")
             if mesh is None:
                 raise ValueError("kv_shard=True needs a mesh at "
                                  "registration (parallel.create_mesh)")
@@ -491,17 +476,13 @@ class DecodeEntry:
         # path's dominant resident — size it in CLOSED FORM from
         # eval_shape (zero allocation) and refuse the registration up
         # front when params + pool exceed the remaining headroom,
-        # instead of OOMing on the first decode step. Paged pools size
-        # to pool_blocks x kv_block tokens, not slots x max_seq_len.
+        # instead of OOMing on the first decode step. The pool sizes to
+        # pool_blocks x kv_block tokens, not slots x max_seq_len.
         import jax
         from bigdl_tpu.observe import memz as _memz
         cache_specs = jax.eval_shape(self._raw_caches, params)
-        if self.paged:
-            what = (f"decode model {name!r} ({self.pool_blocks} KV "
-                    f"blocks x {self.kv_block} tokens paged pool")
-        else:
-            what = (f"decode model {name!r} ({self.num_slots} slots x "
-                    f"{self.max_seq_len} tokens KV bucket")
+        what = (f"decode model {name!r} ({self.pool_blocks} KV "
+                f"blocks x {self.kv_block} tokens paged pool")
         # True at each leaf resident by slot, None where every leaf is KV
         self._slot_mask = (model.slot_resident(cache_specs)
                            if self.slot_state else None)
@@ -548,11 +529,10 @@ class DecodeEntry:
             # the non-cache shardings are pinned REPLICATED: decode
             # steps are tiny and latency-bound, so the mesh buys program
             # portability (one registration path for meshed servers),
-            # not FLOPs. kv_shard=True additionally shards the paged
-            # pool's BLOCK dimension over the data axis (the slot-dim
-            # layout of the dense bucket, applied to its paged
-            # replacement; blocks stay whole on a device) — the pool is
-            # the one decode resident worth splitting at real-chip scale.
+            # not FLOPs. kv_shard=True additionally shards the pool's
+            # BLOCK dimension over the data axis (blocks stay whole on a
+            # device) — the pool is the one decode resident worth
+            # splitting at real-chip scale.
             self._rep_sharding = rep
             if self.kv_shard:
                 from bigdl_tpu.nn.attention import PAGED_POOL_BLOCK_AXIS
@@ -562,51 +542,41 @@ class DecodeEntry:
             cache_sh = self._cache_shardings()
             # in_shardings as a per-argument prefix pytree: the cache
             # subtree takes the pool sharding, everything else is
-            # replicated. Argument layouts (see the lambdas below):
+            # replicated. Argument layouts (see the programs below):
             #   decode:  (params, caches, tokens, positions, active,
-            #             [table,] [temps, top_ks, top_ps, seeds])
-            #   prefill: (params, caches, tokens, positions,
-            #             table, lengths | active)
-            n_extra_d = (1 if self.paged else 0) + \
-                (4 if self.sampling else 0)
-            kw_d["in_shardings"] = (rep, cache_sh) + (rep,) * (3 + n_extra_d)
+            #             table[, temps, top_ks, top_ps, seeds])
+            #   prefill: (params, caches, tokens, positions, table,
+            #             lengths)
+            n_samp = 4 if self.sampling else 0
+            kw_d["in_shardings"] = (rep, cache_sh) + (rep,) * (4 + n_samp)
             kw_d["out_shardings"] = (rep, cache_sh)
-            n_extra_p = 2 if self.paged else 1
-            kw_p["in_shardings"] = (rep, cache_sh) + (rep,) * (2 + n_extra_p)
+            kw_p["in_shardings"] = (rep, cache_sh) + (rep,) * 4
             kw_p["out_shardings"] = cache_sh
-        if self.paged:
-            if self.sampling:
-                from bigdl_tpu.nn.sampling import sample_tokens
+        # the programs, composed from what the model owns: the token choice
+        # is the server's, greedy or sampled as the registration says
+        jnp = jax.numpy
+        if self.sampling:
+            from bigdl_tpu.nn.sampling import sample_tokens
 
-                def _step(p, c, t, pos, a, bt, temps, tks, tps, seeds):
-                    logits, c = model.paged_decode_logits(
-                        p, c, t, pos, a, bt)
-                    return sample_tokens(logits, temps, tks, tps,
-                                         seeds, pos), c
-                self._jit_decode = jax.jit(_step, **kw_d)
-            else:
-                self._jit_decode = jax.jit(
-                    lambda p, c, t, pos, a, bt:
-                    model.paged_decode_step(p, c, t, pos, a, bt), **kw_d)
-            self._jit_prefill = jax.jit(
-                lambda p, c, t, pos, bt, ln:
-                model.paged_prefill(p, c, t, pos, bt, ln), **kw_p)
+            def choose(logits, pos, temps, tks, tps, seeds):
+                return sample_tokens(logits, temps, tks, tps, seeds, pos)
         else:
-            if self.sampling:
-                from bigdl_tpu.nn.sampling import sample_tokens
+            def choose(logits, pos):
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-                def _step(p, c, t, pos, a, temps, tks, tps, seeds):
-                    logits, c = model.decode_logits(p, c, t, pos, a)
-                    return sample_tokens(logits, temps, tks, tps,
-                                         seeds, pos), c
-                self._jit_decode = jax.jit(_step, **kw_d)
-            else:
-                self._jit_decode = jax.jit(
-                    lambda p, c, t, pos, a:
-                    model.decode_step(p, c, t, pos, a), **kw_d)
-            self._jit_prefill = jax.jit(
-                lambda p, c, t, pos, a: model.prefill(p, c, t, pos, a),
-                **kw_p)
+        def _step(p, c, t, pos, a, bt, *samp):
+            x, c = model.paged_hidden(
+                p, c, t[:, None], pos[:, None], bt, a.astype(jnp.int32),
+                decode=True)
+            return choose(model.head_logits(p, x), pos, *samp), c
+
+        def _prefill(p, c, t, pos, bt, ln):
+            # lengths masks the rounded-up bucket's padded tail (and
+            # inactive rows) out of the pool write; no logits
+            return model.paged_hidden(p, c, t, pos, bt, ln, decode=False)[1]
+
+        self._jit_decode = jax.jit(_step, **kw_d)
+        self._jit_prefill = jax.jit(_prefill, **kw_p)
         # the next step's input tokens while this step's are still on the
         # device: a row that continues takes its own output, every other
         # row what the host knows (DecodeScheduler._dispatch_step)
@@ -635,11 +605,8 @@ class DecodeEntry:
 
     def _raw_caches(self, params):
         """The model's zero cache pytree for this registration: the paged
-        block pool (with, for a model that has one, its state resident by
-        slot), or the dense slot bucket."""
-        if not self.paged:
-            return self.model.make_slot_caches(
-                params, self.num_slots, self.max_seq_len)
+        block pool with, for a model that has one, its state resident by
+        slot."""
         by_slot = {"num_slots": self.num_slots} if self.slot_state else {}
         return self.model.make_paged_slot_caches(
             params, self.pool_blocks, self.kv_block, **by_slot)
@@ -710,10 +677,7 @@ class DecodeEntry:
         table = spec((S, self.blocks_per_slot), i32)
         samp = ((spec((S,), f32), vec, spec((S,), f32), vec)
                 if self.sampling else ())
-        if self.paged:
-            d_args = (p_s, c_s, vec, vec, act, table) + samp
-        else:
-            d_args = (p_s, c_s, vec, vec, act) + samp
+        d_args = (p_s, c_s, vec, vec, act, table) + samp
         results: Dict[str, Dict] = {}
         cost, self._aot_decode = precompile_fixed(
             self._jit_decode, d_args,
@@ -722,12 +686,8 @@ class DecodeEntry:
         results["decode_step"] = cost
         for b in self.buckets:
             chunk = spec((S, b), i32)
-            if self.paged:
-                pf_args = (p_s, c_s, chunk, chunk, table, vec)
-            else:
-                pf_args = (p_s, c_s, chunk, chunk, act)
             cost, exe = precompile_fixed(
-                self._jit_prefill, pf_args,
+                self._jit_prefill, (p_s, c_s, chunk, chunk, table, vec),
                 name=f"serve/{self.name}/decode/prefill{b}")
             self._assert_pool_sharding(exe)
             self._aot_prefill[b] = exe
@@ -758,9 +718,8 @@ class DecodeEntry:
     # ------------------------------------------------------------ device
     def run_prefill(self, caches, tokens: np.ndarray, *rest):
         """One chunk-prefill program call; returns the new caches (the
-        input cache buffers are donated on TPU). `rest` is the layout's
-        trailing host args (positions, then active — or block_table +
-        lengths when paged)."""
+        input cache buffers are donated on TPU). `rest` is the trailing
+        host args (positions, block_table, lengths)."""
         C = tokens.shape[1]
         args = (self.placed_params(), caches, self._place(tokens)) + \
             tuple(self._place(a) for a in rest)
@@ -784,8 +743,8 @@ class DecodeEntry:
         array or, for a step enqueued ahead, `merge_tokens`' device array,
         which goes in untouched. The scheduler fetches next_tokens (the
         iteration's single host sync) only after it has enqueued the step
-        that follows. `rest` is the layout's trailing host args (positions,
-        active[, block_table][, temps, top_ks, top_ps, seeds])."""
+        that follows. `rest` is the trailing host args (positions, active,
+        block_table[, temps, top_ks, top_ps, seeds])."""
         args = (self.placed_params(), caches,
                 self._place(tokens_last)) + \
             tuple(self._place(a) for a in rest)
@@ -974,51 +933,35 @@ class DecodeScheduler:
         self._caches = entry.make_caches()
         self._state_handle = None
         from bigdl_tpu.observe import memz as _memz
-        if entry.paged:
-            # paged-pool bookkeeping: free-list allocator + per-slot
-            # block tables (+ the prefix cache when enabled). All
-            # mutation happens under self._cv.
-            self._pool = BlockPool(entry.pool_blocks)
-            self._prefix = (PrefixCache(self._pool,
-                                        entry.prefix_cache_cap)
-                            if entry.prefix_cache else None)
-            self._tables = np.full(
-                (entry.num_slots, entry.blocks_per_slot), -1, np.int32)
-            # buffer ledger (observe/memz.py): the pool under
-            # `serve/<model>/kv_pool`, kind="kv_pool" — bytes stay
-            # constant across donated steps while the meta carries the
-            # LIVE block accounting (headroom = free blocks)
-            pooled, by_slot = entry.split_caches(self._caches)
-            self._mem_handle = _memz.ledger().register(
-                f"serve/{self.name}/kv_pool", pooled, anchor=self,
-                kind="kv_pool",
-                meta={"blocks": entry.pool_blocks,
-                      "block": entry.kv_block,
-                      "bytes_per_block":
-                          entry.kv_pool_bytes // entry.pool_blocks,
-                      "blocks_free": entry.pool_blocks,
-                      "slots": entry.num_slots,
-                      "max_seq_len": entry.max_seq_len})
-            if entry.slot_state:
-                # what the model keeps by slot and not by block (a
-                # recurrent state): its own owner beside the pool
-                self._state_handle = _memz.ledger().register(
-                    f"serve/{self.name}/slot_state", by_slot, anchor=self,
-                    kind="slot_state", meta={"slots": entry.num_slots})
-        else:
-            self._pool = None
-            self._prefix = None
-            self._tables = None
-            # buffer ledger: the persistent KV-slot bucket under
-            # `serve/<model>/kv_cache` — the bytes stay constant across
-            # donated steps, and close()/GC releases the accounting; the
-            # slots meta feeds the /memz "one more slot" headroom
-            # estimate
-            self._mem_handle = _memz.ledger().register(
-                f"serve/{self.name}/kv_cache", self._caches, anchor=self,
-                kind="kv_cache",
-                meta={"slots": entry.num_slots,
-                      "max_seq_len": entry.max_seq_len})
+        # pool bookkeeping: free-list allocator + per-slot block tables
+        # (+ the prefix cache when enabled). All mutation happens under
+        # self._cv.
+        self._pool = BlockPool(entry.pool_blocks)
+        self._prefix = (PrefixCache(self._pool, entry.prefix_cache_cap)
+                        if entry.prefix_cache else None)
+        self._tables = np.full(
+            (entry.num_slots, entry.blocks_per_slot), -1, np.int32)
+        # buffer ledger (observe/memz.py): the pool under
+        # `serve/<model>/kv_pool`, kind="kv_pool" — bytes stay constant
+        # across donated steps while the meta carries the LIVE block
+        # accounting (headroom = free blocks)
+        pooled, by_slot = entry.split_caches(self._caches)
+        self._mem_handle = _memz.ledger().register(
+            f"serve/{self.name}/kv_pool", pooled, anchor=self,
+            kind="kv_pool",
+            meta={"blocks": entry.pool_blocks,
+                  "block": entry.kv_block,
+                  "bytes_per_block":
+                      entry.kv_pool_bytes // entry.pool_blocks,
+                  "blocks_free": entry.pool_blocks,
+                  "slots": entry.num_slots,
+                  "max_seq_len": entry.max_seq_len})
+        if entry.slot_state:
+            # what the model keeps by slot and not by block (a recurrent
+            # state): its own owner beside the pool
+            self._state_handle = _memz.ledger().register(
+                f"serve/{self.name}/slot_state", by_slot, anchor=self,
+                kind="slot_state", meta={"slots": entry.num_slots})
         self._closed = False
         self._draining = False
         # the decode step that is enqueued and not fetched yet; written by
@@ -1098,7 +1041,7 @@ class DecodeScheduler:
         """Queue one generate request; returns its `GenReply`. Raises
         ValueError (bad prompt / budget over the slot cache length /
         sampling params on a greedy registration), `CapacityError`
-        (paged: the request needs more KV blocks than the whole pool —
+        (the request needs more KV blocks than the whole pool —
         it can NEVER be scheduled; the error carries the live
         block-level capacity report and leaves no partial state),
         `Overloaded` (queue at bound), or `Closed` (shut down).
@@ -1127,30 +1070,29 @@ class DecodeScheduler:
         req = _GenRequest(prompt, max_new_tokens, eos, self._clock(),
                           temperature=temperature, top_k=top_k,
                           top_p=top_p, seed=seed)
-        if self.entry.paged:
-            req.need_blocks = -(-total // self.entry.kv_block)
-            if req.need_blocks > self._pool.total:
-                # refuse, don't queue: no retirement can ever free
-                # enough blocks. Live block-level capacity report; the
-                # submit leaves NO partial state, so a resized retry
-                # (or a bigger pool) goes through cleanly.
-                from bigdl_tpu.observe.memz import CapacityError
-                with self._cv:
-                    p = self._pool
-                    cached = p.cached_count()
-                    report = (f"{p.total} blocks total = {p.live} live "
-                              f"+ {cached} cached + {p.free} free "
-                              f"({p.reserved} reserved)")
-                observe.instant("serve/decode/refuse", cat="serve",
-                                args={"model": self.name,
-                                      "need_blocks": req.need_blocks})
-                raise CapacityError(
-                    f"decode request needs {req.need_blocks} KV blocks "
-                    f"({total} tokens @ {self.entry.kv_block}/block) "
-                    f"but the {self.name!r} pool holds {report} — "
-                    f"shrink the request or grow "
-                    f"BIGDL_TPU_SERVE_KV_POOL_BLOCKS / "
-                    f"register(kv_pool_blocks=...)")
+        req.need_blocks = -(-total // self.entry.kv_block)
+        if req.need_blocks > self._pool.total:
+            # refuse, don't queue: no retirement can ever free
+            # enough blocks. Live block-level capacity report; the
+            # submit leaves NO partial state, so a resized retry
+            # (or a bigger pool) goes through cleanly.
+            from bigdl_tpu.observe.memz import CapacityError
+            with self._cv:
+                p = self._pool
+                cached = p.cached_count()
+                report = (f"{p.total} blocks total = {p.live} live "
+                          f"+ {cached} cached + {p.free} free "
+                          f"({p.reserved} reserved)")
+            observe.instant("serve/decode/refuse", cat="serve",
+                            args={"model": self.name,
+                                  "need_blocks": req.need_blocks})
+            raise CapacityError(
+                f"decode request needs {req.need_blocks} KV blocks "
+                f"({total} tokens @ {self.entry.kv_block}/block) "
+                f"but the {self.name!r} pool holds {report} — "
+                f"shrink the request or grow "
+                f"BIGDL_TPU_SERVE_KV_POOL_BLOCKS / "
+                f"register(kv_pool_blocks=...)")
         with self._cv:
             if self._closed or self._draining:
                 raise Closed(f"decode scheduler {self.name!r} is shut "
@@ -1181,9 +1123,8 @@ class DecodeScheduler:
     # --------------------------------------------------- iteration core
     def _admit(self) -> int:
         """Move queued requests into free slots (holding the lock).
-        Paged: admission additionally reserves the request's KV blocks
-        against the LIVE pool (matching any committed shared prefix
-        first — matched blocks are refcounted into the slot's table and
+        Admission reserves the request's KV blocks against the LIVE
+        pool (matching any committed shared prefix first — matched blocks are refcounted into the slot's table and
         their prefill is skipped); when the head request's blocks don't
         fit, admission stops — FIFO, no overtaking — and retries next
         iteration after retirements return blocks."""
@@ -1194,7 +1135,7 @@ class DecodeScheduler:
             while free_slots and self._queue:
                 req = self._queue[0]
                 s = free_slots[0]
-                if self.entry.paged and not self._admit_blocks(req, s):
+                if not self._admit_blocks(req, s):
                     break
                 self._queue.pop(0)
                 free_slots.pop(0)
@@ -1251,7 +1192,7 @@ class DecodeScheduler:
         committed entries decref in the prefix cache (refs==0 entries
         stay CACHED for future hits), private blocks go back to the
         free list, unacquired reservations are dropped."""
-        if not self.entry.paged or req.slot is None:
+        if req.slot is None:
             return
         with self._cv:
             row = self._tables[req.slot]
@@ -1275,8 +1216,6 @@ class DecodeScheduler:
         counters, and the ledger owner's meta (headroom = free
         blocks)."""
         pool = self._pool
-        if pool is None:
-            return
         cached = pool.cached_count()
         self._m_blocks_free.set(float(pool.free))
         self._m_blocks_live.set(float(pool.live))
@@ -1320,39 +1259,30 @@ class DecodeScheduler:
         for req in pending:
             by_bucket.setdefault(self._chunk_for(req), []).append(req)
         S = self.entry.num_slots
-        paged = self.entry.paged
         done = 0
         for C, reqs in sorted(by_bucket.items()):
             tokens = np.zeros((S, C), np.int32)
             positions = np.zeros((S, C), np.int32)
-            active = np.zeros((S,), bool)
             lengths = np.zeros((S,), np.int32)
             for req in reqs:
                 n = min(req.prefill_target - req.fed, C)
                 tokens[req.slot, :n] = req.prompt[req.fed:req.fed + n]
                 positions[req.slot] = req.fed + np.arange(C)
-                active[req.slot] = True
                 lengths[req.slot] = n
-            if paged:
-                with self._cv:
-                    for req in reqs:
-                        n = int(lengths[req.slot])
-                        self._ensure_blocks(req, req.fed + n - 1)
-                    table = self._tables.copy()
+            with self._cv:
+                for req in reqs:
+                    n = int(lengths[req.slot])
+                    self._ensure_blocks(req, req.fed + n - 1)
+                table = self._tables.copy()
             t0 = self._clock()
             with observe.span("serve/decode/prefill", cat="serve",
                               args={"model": self.name, "chunk": C,
                                     "slots": len(reqs),
                                     "state": self.entry.state_kind}):
-                if paged:
-                    # lengths masks the rounded-up bucket's padded tail
-                    # (and inactive rows) out of the pool scatter —
-                    # active is implied by lengths > 0
-                    self._caches = self.entry.run_prefill(
-                        self._caches, tokens, positions, table, lengths)
-                else:
-                    self._caches = self.entry.run_prefill(
-                        self._caches, tokens, positions, active)
+                # lengths masks the rounded-up bucket's padded tail (and
+                # inactive rows) out of the pool scatter
+                self._caches = self.entry.run_prefill(
+                    self._caches, tokens, positions, table, lengths)
             self._h_prefill.record(
                 max(0.0, (self._clock() - t0) * 1e3))
             self._m_prefill_tokens.inc(int(lengths.sum()))
@@ -1421,12 +1351,10 @@ class DecodeScheduler:
             rows.append(req)
         if not rows:
             return None
-        extra = []
-        if self.entry.paged:
-            with self._cv:
-                for req in rows:
-                    self._ensure_blocks(req, int(positions[req.slot]))
-                extra.append(self._tables.copy())
+        with self._cv:
+            for req in rows:
+                self._ensure_blocks(req, int(positions[req.slot]))
+            extra = [self._tables.copy()]
         if self.entry.sampling:
             temps = np.zeros((S,), np.float32)
             tks = np.zeros((S,), np.int32)
@@ -1485,8 +1413,7 @@ class DecodeScheduler:
 
     def _retire(self, req: _GenRequest, now: float) -> None:
         self._slots[req.slot] = None
-        if self.entry.paged:
-            self._release_blocks(req)
+        self._release_blocks(req)
         self._m_retired.inc()
         self._h_lat.record(max(0.0, (now - req.t_submit) * 1e3))
         observe.instant("serve/decode/retire", cat="serve",
@@ -1516,8 +1443,7 @@ class DecodeScheduler:
         for s, req in enumerate(self._slots):
             if req is not None and req.reply.cancelled():
                 self._slots[s] = None
-                if self.entry.paged:
-                    self._release_blocks(req)
+                self._release_blocks(req)
                 self._m_cancelled.inc()
                 req.reply._finish(req.generated)
                 freed += 1
@@ -1572,8 +1498,7 @@ class DecodeScheduler:
         worked = self._admit() > 0 or worked
         worked = self._prefill_pass() > 0 or worked
         worked = self._decode_pass() > 0 or worked
-        if self.entry.paged:
-            self._refresh_pool_stats()
+        self._refresh_pool_stats()
         return worked
 
     # ----------------------------------------------------------- lifecycle
@@ -1674,10 +1599,8 @@ class DecodeScheduler:
             self._m_queued.set(0)
             self._m_active.set(0)
             self._cv.notify_all()
-        if self.entry.paged:
-            for req in dropped:
-                self._release_blocks(req)
         for req in dropped:
+            self._release_blocks(req)
             if not req.reply.done():
                 req.reply._fail(Closed(
                     f"decode scheduler {self.name!r} closed before "
@@ -1686,7 +1609,7 @@ class DecodeScheduler:
         if t is not None and t is not threading.current_thread():
             t.join(timeout=5.0)
         self._thread = None
-        # the KV bucket itself is freed when the scheduler drops its
+        # the KV pool itself is freed when the scheduler drops its
         # cache reference; release the ledger accounting with it
         self._caches = None
         self._mem_handle.close()
@@ -1715,6 +1638,8 @@ class DecodeScheduler:
             # report the live partial-window estimate instead of 0
             rate = self._win_tokens / max(self._clock() - self._win_t0,
                                           1e-9)
+        pool = self._pool
+        cached = pool.cached_count()
         out = {
             "slots": self.entry.num_slots,
             "max_seq_len": self.entry.max_seq_len,
@@ -1736,38 +1661,30 @@ class DecodeScheduler:
             "steps": int(self._m_steps.value),
             "steps_ahead": int(self._m_steps_ahead.value),
             "rows_dropped": int(self._m_rows_dropped.value),
-        }
-        out["paged"] = bool(self.entry.paged)
-        out.update({
             "state": self.entry.state_kind,
             "kv_pool_bytes": self.entry.kv_pool_bytes,
             "state_bytes": self.entry.state_bytes,
             "state_resets": int(self._m_state_resets.value),
             "prefill_tokens": int(self._m_prefill_tokens.value),
-        })
-        if self.entry.paged and self._pool is not None:
-            pool = self._pool
-            cached = pool.cached_count()
+            "kv_block": self.entry.kv_block,
+            "kv_blocks_total": pool.total,
+            "kv_blocks_free": pool.free,
+            "kv_blocks_live": pool.live,
+            "kv_blocks_cached": cached,
+            "kv_blocks_reserved": pool.reserved,
+            "kv_pool_util": round(pool.live / pool.total, 4),
+        }
+        if self._prefix is not None:
+            pf = self._prefix
+            seen = pf.hits + pf.misses
             out.update({
-                "kv_block": self.entry.kv_block,
-                "kv_blocks_total": pool.total,
-                "kv_blocks_free": pool.free,
-                "kv_blocks_live": pool.live,
-                "kv_blocks_cached": cached,
-                "kv_blocks_reserved": pool.reserved,
-                "kv_pool_util": round(pool.live / pool.total, 4),
+                "prefix_hits": pf.hits,
+                "prefix_misses": pf.misses,
+                "prefix_evictions": pf.evictions,
+                "prefix_cached_blocks": cached,
+                "prefix_hit_rate": round(pf.hits / seen, 4)
+                if seen else 0.0,
             })
-            if self._prefix is not None:
-                pf = self._prefix
-                seen = pf.hits + pf.misses
-                out.update({
-                    "prefix_hits": pf.hits,
-                    "prefix_misses": pf.misses,
-                    "prefix_evictions": pf.evictions,
-                    "prefix_cached_blocks": cached,
-                    "prefix_hit_rate": round(pf.hits / seen, 4)
-                    if seen else 0.0,
-                })
         return out
 
 

@@ -162,23 +162,25 @@ class ServeEngine:
 
         `decode=True` registers the iteration-level autoregressive path
         instead (serve/decode.py): the model must carry the slot-decode
-        contract (GPT2LM/LlamaLM), requests enter through
+        contract (GPT2LM/LlamaLM/OlmoHybridLM), requests enter through
         `submit_generate`, and `precompile_decode` (default on)
         AOT-compiles the fused step + every prefill bucket so warm
         serving compiles zero fresh programs. num_slots / max_seq_len /
         prefill_chunk default to the BIGDL_TPU_SERVE_DECODE_* knobs;
-        paged / kv_block / kv_pool_blocks / prefix_cache / sampling /
-        kv_shard override the BIGDL_TPU_SERVE_KV_* and
+        kv_block / kv_pool_blocks / prefix_cache / sampling / kv_shard
+        override the BIGDL_TPU_SERVE_KV_* and
         BIGDL_TPU_SERVE_{PREFIX_CACHE,SAMPLING} knobs (paged KV block
-        pool + shared-prefix reuse — docs/serving.md).
+        pool + shared-prefix reuse — docs/serving.md). `paged` takes
+        None or True (DecodeEntry says why it is still here); False is
+        refused: the dense slot bucket was removed.
 
         Admission is memory-checked (observe/memz.py): params+state —
-        and for decode the closed-form KV bucket, BEFORE allocation —
+        and for decode the closed-form KV pool, BEFORE allocation —
         must fit the remaining device headroom, else a `CapacityError`
         with the per-owner capacity report is raised and nothing is
         registered (no model entry, no scheduler thread). Registered
         trees are accounted in the buffer ledger (`serve/<name>/params`,
-        `serve/<name>/kv_cache` — the /memz plane)."""
+        `serve/<name>/kv_pool` — the /memz plane)."""
         if self._closed:
             raise Closed("engine is shut down")
         d = self._defaults

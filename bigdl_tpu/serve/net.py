@@ -198,7 +198,7 @@ class LocalBackend:
             from bigdl_tpu.observe import memz as _memz
             head = _memz.ledger().headroom()
             payload["headroom_bytes"] = head.get("free_bytes")
-            payload["decode_slots"] = head.get("decode_slots")
+            payload["kv_pools"] = head.get("kv_pools")
         except Exception:                # noqa: BLE001 — telemetry
             payload["headroom_bytes"] = None
         return payload

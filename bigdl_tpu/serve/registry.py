@@ -132,7 +132,7 @@ class ModelEntry:
         from bigdl_tpu.observe import memz as _memz
         need = _memz.tree_nbytes(params) + _memz.tree_nbytes(state)
         if not decode:
-            # the decode path admission-checks params + the KV bucket
+            # the decode path admission-checks params + the KV pool
             # together (DecodeEntry, closed form, before any allocation)
             _memz.admission_check(need, f"serve model {name!r}")
         self._mem_handle = _memz.ledger().register(
@@ -143,8 +143,8 @@ class ModelEntry:
         self._jitted = _serve_forward(model, mesh)
         self._aot: Dict[int, object] = {}
         self._placed_params = None     # mesh: replicate params/state once
-        # decode=True: the iteration-level autoregressive path — KV-slot
-        # bucket + AOT prefill/decode programs (serve/decode.py); the
+        # decode=True: the iteration-level autoregressive path — paged
+        # KV pool + AOT prefill/decode programs (serve/decode.py); the
         # engine drives it through a DecodeScheduler instead of a
         # ContinuousBatcher
         self.decode = None

@@ -469,8 +469,8 @@ def launch_replicas(n: int, cli_args: Sequence[str], *,
     (ephemeral ports) and wait up to `ready_timeout_s` — the caller's
     budget for start-up AND compilation, all replicas together — for
     each one's READY line. Returns `(procs, urls)`; pair with
-    :func:`stop_replicas`. Used by the CLI `--replicas` mode, bench.py
-    serve_net, and the failover tests.
+    :func:`stop_replicas`. Used by the CLI `--replicas` mode and the
+    failover tests.
 
     A replica runs on the platform the parent was given (its
     environment plus `env`; nothing here picks a backend) and writes

@@ -253,21 +253,11 @@ _register("SERVE_PREFILL_CHUNK", 64, int,
           "cannot stall concurrent decode for more than one chunk "
           "(serve/decode.py)")
 _register("SERVE_MAX_SEQ_LEN", 1024, int,
-          "Autoregressive decode serving: KV-slot cache length — the "
-          "hard cap on prompt + generated tokens per sequence. The "
-          "per-layer (slots, max_seq_len, heads, head_dim) cache "
-          "arrays are allocated once per model and donated across "
-          "steps (serve/decode.py). Per-model override: "
-          "ServeEngine.register(max_seq_len=...)")
-_register("SERVE_KV_PAGED", True, _bool,
-          "Autoregressive decode serving: allocate the KV cache as a "
-          "PAGED block pool (fixed-size blocks + per-slot block "
-          "tables, serve/decode.py BlockPool) instead of one dense "
-          "(slots, max_seq_len) bucket — HBM cost follows live "
-          "sequences, admission is live block accounting, and shared "
-          "prompt prefixes are reusable. Models lacking the paged "
-          "slot-decode contract fall back to the dense bucket. "
-          "Per-model override: ServeEngine.register(paged=...)")
+          "Autoregressive decode serving: the hard cap on prompt + "
+          "generated tokens per sequence, and with it the length of a "
+          "slot's block table; the paged KV pool is allocated once "
+          "per model and donated across steps (serve/decode.py). "
+          "Per-model override: ServeEngine.register(max_seq_len=...)")
 _register("SERVE_KV_BLOCK", 16, int,
           "Paged KV cache: tokens per block. Smaller blocks waste "
           "less tail capacity per sequence but grow the block table; "
@@ -275,8 +265,8 @@ _register("SERVE_KV_BLOCK", 16, int,
           "ServeEngine.register(kv_block=...)")
 _register("SERVE_KV_POOL_BLOCKS", 0, int,
           "Paged KV cache: total blocks in the per-model pool. "
-          "0 (default) = dense-equivalent sizing "
-          "(slots x ceil(max_seq_len/block) — identical capacity, "
+          "0 (default) = every slot at full length "
+          "(slots x ceil(max_seq_len/block) blocks — the "
           "zero-risk default); size it BELOW that to spend less HBM "
           "than the worst case and let live block accounting admit "
           "against real usage (docs/serving.md sizing runbook). "
@@ -285,8 +275,8 @@ _register("SERVE_PREFIX_CACHE", True, _bool,
           "Paged KV cache: retain finished sequences' full prompt-"
           "prefix blocks as refcounted read-only cache entries keyed "
           "by token-prefix hash, so requests sharing a prompt prefix "
-          "(system prompts) skip its prefill entirely. Paged "
-          "registrations only. Per-model override: "
+          "(system prompts) skip its prefill entirely. "
+          "Per-model override: "
           "ServeEngine.register(prefix_cache=...)")
 _register("SERVE_PREFIX_CACHE_BLOCKS", 0, int,
           "Prefix cache retention cap: max UNREFERENCED cached blocks "
@@ -471,7 +461,7 @@ _register("FORENSICS_PROFILE_S", 1.0, float,
 _register("MEM_LEDGER", True, _bool,
           "Device-memory buffer ledger (observe/memz.py): subsystems "
           "that pin long-lived device memory (trainer param/slot trees, "
-          "serve model params, decode KV-slot buckets, data-service "
+          "serve model params, decode KV pools, data-service "
           "staging) register their trees under named owners — "
           "mem/<owner>/bytes gauges, the /memz endpoint, headroom "
           "estimates, and OOM forensics attribution all read from it. "

@@ -12,8 +12,8 @@ the round trip of the fetch itself (a tiny op plus its fetch: 1.7 ms). The
 two agree, so the helpers below wait with `block_until_ready`: it needs no
 extra device program and no transfer.
 
-These helpers are shared by bench.py, models/perf.py and utils/profile.py
-so the timing protocol lives in exactly one place."""
+These helpers are shared by models/perf.py and utils/profile.py so the
+timing protocol lives in exactly one place."""
 
 from __future__ import annotations
 
@@ -40,7 +40,7 @@ def time_steps(step, carry, warmup: int, iters: int):
     """Time `carry, observed = step(carry)` chains: steps are
     data-dependent through `carry`, and the timed region ends when
     `observed` is complete. The single home for the timing loop used by
-    bench.py and models/perf.py.
+    models/perf.py.
 
     Returns (seconds_per_step, final_carry). warmup=0 measures cold
     (compile included) — that is the caller's explicit choice."""

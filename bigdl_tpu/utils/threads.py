@@ -17,7 +17,7 @@ TPU-LINT101 (raw ``threading.Thread`` outside this file is an error):
     and sanitizer-instrumented wrappers when ``BIGDL_TPU_SANITIZE`` is
     set (analysis/sancov.py: lock-order graph, hold times, lockset race
     checks). The default path constructs the stock primitive directly —
-    zero added cost when the knob is off (bench.py overhead).
+    zero added cost when the knob is off.
 
 The inventory holds weak references only — it never keeps a thread or
 lock alive — and is itself guarded by a raw ``threading.Lock`` (the

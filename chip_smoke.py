@@ -17,7 +17,7 @@ the entry points a user calls, at the published width of a model:
     `ServeFront(LocalBackend(engine))` on an ephemeral port, asked over
     real sockets (`/v1/generate`, some streamed over SSE, overlapping);
   * --chips 4 — `parallel.DistriOptimizer` on a 2x2 (data x model) mesh,
-    ZeRO-1, bf16, on the `bench.py llama` decoder, against the same model,
+    ZeRO-1, bf16, on a Llama decoder (`LLAMA`), against the same model,
     seed and global batch through the one-device `Optimizer` on chip 0.
 
 Any phase that raises, or any check that fails, ends the run with a

@@ -147,7 +147,6 @@ def xl_entry(one_chip):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
         entry = DecodeEntry("xl", model, params, **chip_smoke.SERVE_KV)
-    assert entry.paged
     caches = jax.tree.map(
         lambda a: _sds(one_chip, a.shape, a.dtype),
         jax.eval_shape(lambda p: model.make_paged_slot_caches(
@@ -236,7 +235,7 @@ def hybrid_entry(one_chip):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
         entry = DecodeEntry("hybrid", model, params, **HYBRID["register"])
-    assert entry.paged and entry.slot_state and not entry.prefix_cache
+    assert entry.slot_state and not entry.prefix_cache
     caches = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
                           jax.eval_shape(entry._raw_caches, params))
     return entry, params, caches

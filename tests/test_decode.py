@@ -58,10 +58,12 @@ def oracle(lm, prompt, max_new, eos_id=EOS):
     return np.asarray(seqs)[0, 0, prompt.shape[0]:]
 
 
-def check_vs_oracle(lm, prompt, got, max_new, eos_id=EOS):
-    """Engine output == oracle tokens; the oracle pads with eos after a
-    stop, the engine stops emitting — both checked."""
-    want = oracle(lm, prompt, max_new, eos_id)
+def check_vs_oracle(lm, prompt, got, max_new, eos_id=EOS, want=None):
+    """Engine output == oracle tokens (`want`, where the caller holds
+    them already); the oracle pads with eos after a stop, the engine
+    stops emitting — both checked."""
+    if want is None:
+        want = oracle(lm, prompt, max_new, eos_id)
     n = got.shape[0]
     np.testing.assert_array_equal(got, want[:n])
     if n < max_new:
@@ -160,19 +162,15 @@ def _staggered_run(entry, submits, poison=False):
                 replies[i] = sched.submit(prompt, max_new, eos_id=eos)
         worked = sched.step_once()
         if poison:
-            # poison every FREE cache region (paged: unallocated pool
-            # blocks; dense: free slots' rows): stale content from
-            # retired sequences can never leak into live ones
-            if entry.paged:
-                free = list(sched._pool._free)
-            else:
-                free = [s for s, r in enumerate(sched._slots)
-                        if r is None]
+            # poison every FREE cache region (the unallocated pool
+            # blocks): stale content from retired sequences can never
+            # leak into live ones
+            free = list(sched._pool._free)
             if free:
-                idx = jnp.asarray(free)
-                if entry.paged:       # blocks lie along the pool's block axis
-                    from bigdl_tpu.nn.attention import PAGED_POOL_BLOCK_AXIS
-                    idx = (slice(None),) * PAGED_POOL_BLOCK_AXIS + (idx,)
+                # blocks lie along the pool's block axis
+                from bigdl_tpu.nn.attention import PAGED_POOL_BLOCK_AXIS
+                idx = ((slice(None),) * PAGED_POOL_BLOCK_AXIS
+                       + (jnp.asarray(free),))
                 sched._caches = jax.tree.map(
                     lambda a: a.at[idx].set(1e30), sched._caches)
         step += 1
@@ -210,16 +208,31 @@ def staggered_outs(entry, staggered_submits):
     return _staggered_run(entry, staggered_submits)
 
 
+@pytest.fixture(scope="module")
+def staggered_oracle(lm, staggered_submits):
+    """`want(i)`: request i of the schedule decoded alone, run once and
+    kept (each isolated oracle compiles at its own prompt length, about
+    3 s apiece)."""
+    kept = {}
+
+    def want(i):
+        if i not in kept:
+            _, prompt, max_new, eos = staggered_submits[i]
+            kept[i] = oracle(lm, prompt, max_new, eos_id=eos)
+        return kept[i]
+    return want
+
+
 @pytest.mark.parametrize("i", range(len(STAGGERED)))
 def test_staggered_joins_eos_retirement_bit_identical(
-        lm, staggered_submits, staggered_outs, i):
+        lm, staggered_submits, staggered_outs, staggered_oracle, i):
     """ISSUE 14 acceptance: concurrent iteration-level decode with
     staggered joins/leaves and EOS retirement mid-batch is BIT-IDENTICAL
     to each sequence decoded alone via generate(kv_cache=True). One case
-    a request: each isolated oracle compiles at its own prompt length,
-    about 3 s apiece."""
+    a request: each pays its own oracle run."""
     _, prompt, max_new, eos = staggered_submits[i]
-    check_vs_oracle(lm, prompt, staggered_outs[i], max_new, eos_id=eos)
+    check_vs_oracle(lm, prompt, staggered_outs[i], max_new, eos_id=eos,
+                    want=staggered_oracle(i))
 
 
 def test_staggered_schedule_retires_on_eos_mid_batch(staggered_submits,
@@ -234,10 +247,10 @@ def test_staggered_schedule_retires_on_eos_mid_batch(staggered_submits,
 
 def test_cache_pad_poison_bit_identity(entry, staggered_submits,
                                        staggered_outs):
-    """Poisoning every free slot's cache rows (1e30) between iterations
-    changes NOTHING: inactive rows are bit-restored by the fused step
-    and masked entries contribute exactly zero — stale KV can never
-    leak across slot reuse."""
+    """Poisoning every free pool block (1e30) between iterations
+    changes NOTHING: inactive rows never touch the pool and masked
+    entries contribute exactly zero — stale KV can never leak across
+    slot reuse."""
     poisoned = _staggered_run(entry, staggered_submits, poison=True)
     for a, b in zip(staggered_outs, poisoned):
         np.testing.assert_array_equal(a, b)
@@ -371,7 +384,6 @@ def test_row_in_flight_of_a_sequence_that_ended_is_dropped(lm, entry, how):
     of the next step is in flight: the reply holds no token past its end,
     `rows_dropped` gains 1, its blocks return to the pool, and the request
     admitted to that slot next decodes bit-identically to itself alone."""
-    assert entry.paged
     sched = DecodeScheduler(entry, name=f"drop-{how}", start=False)
     r = np.random.RandomState(21)
     prompt = r.randint(2, VOCAB, 6).astype(np.int32)
@@ -460,29 +472,20 @@ def _paged_entry(lm, name="pg", **kw):
     return DecodeEntry(name, model, params, paged=True, **kw)
 
 
-@pytest.fixture(scope="module")
-def dense_outs(lm, staggered_submits):
-    """The staggered schedule decoded through a DENSE (per-slot bucket)
-    entry — the reference stream every paged variant must bit-match."""
-    model, params, _ = lm
-    e = DecodeEntry("dn", model, params, num_slots=4, max_seq_len=32,
-                    prefill_chunk=8, paged=False)
-    assert not e.paged
-    return _staggered_run(e, staggered_submits)
-
-
 @pytest.mark.parametrize("block", [1, 7, 16])
-def test_paged_vs_dense_bit_parity(lm, staggered_submits, dense_outs,
-                                   block):
+def test_paged_block_sizes_bit_identical_to_isolated_generate(
+        lm, staggered_submits, staggered_oracle, block):
     """ISSUE 20 acceptance: the paged block pool — staggered joins,
-    mid-batch EOS retirement, slot reuse — is BIT-IDENTICAL to the
-    dense per-slot bucket at block sizes 1, odd, and the default 16
+    mid-batch EOS retirement, slot reuse — is BIT-IDENTICAL to each
+    sequence decoded alone over its own dense cache
+    (generate(kv_cache=True)) at block sizes 1, odd, and the default 16
     (frontier-masked stale pages contribute exactly zero)."""
     paged = _paged_entry(lm, name=f"pg{block}", kv_block=block)
-    assert paged.paged
     outs = _staggered_run(paged, staggered_submits)
-    for a, b in zip(dense_outs, outs):
-        np.testing.assert_array_equal(a, b)
+    for i, ((_, prompt, max_new, eos), got) in enumerate(
+            zip(staggered_submits, outs)):
+        check_vs_oracle(lm, prompt, got, max_new, eos_id=eos,
+                        want=staggered_oracle(i))
 
 
 def test_prefix_cache_hit_cow_and_refcounts(lm):
@@ -654,7 +657,7 @@ def test_paged_stats_and_ledger_surface(lm):
     while not rep.done():
         sched.step_once()
     st = sched.stats()
-    assert st["paged"] and st["kv_block"] == 4
+    assert st["kv_block"] == 4
     assert st["kv_blocks_total"] == 16
     assert (st["kv_blocks_free"] + st["kv_blocks_live"]
             + st["kv_blocks_cached"] == 16)
@@ -743,6 +746,55 @@ def test_decode_rejects_non_contract_model():
         eng.shutdown()
 
 
+@pytest.mark.parametrize("missing", ["make_paged_slot_caches",
+                                     "paged_hidden", "head_logits"])
+def test_model_lacking_a_contract_method_is_refused_with_all_three(
+        lm, missing):
+    """What a served model provides is three methods; one that lacks any
+    of them is refused with the whole list and the one it lacks."""
+    model, params, _ = lm
+
+    class Partial:
+        vocab_size, n_positions, eos_id = VOCAB, 64, EOS
+
+    for name in ("make_paged_slot_caches", "paged_hidden", "head_logits"):
+        if name != missing:
+            setattr(Partial, name, staticmethod(getattr(model, name)))
+    with pytest.raises(TypeError) as err:
+        DecodeEntry("part", Partial(), params, num_slots=2, max_seq_len=16)
+    msg = str(err.value)
+    assert ("('make_paged_slot_caches', 'paged_hidden', 'head_logits')"
+            in msg)
+    assert f"Partial lacks ['{missing}']" in msg
+
+
+@pytest.mark.parametrize("family", ["GPT2LM", "LlamaLM"])
+def test_register_refuses_the_dense_bucket_by_name(lm, family):
+    """`paged` survives on register() for the benchmark's configurations:
+    None and True are the one layout, False names what was removed."""
+    if family == "GPT2LM":
+        model, params, state = lm
+    else:
+        from bigdl_tpu.interop.huggingface import LlamaLM
+        model = LlamaLM(VOCAB, 16, 4, 2, 32, 2, eos_id=EOS)
+        params, state = model.init(jax.random.PRNGKey(1))
+    eng = ServeEngine()
+    try:
+        with pytest.raises(ValueError, match="dense slot bucket was "
+                                             f"removed; {family}"):
+            eng.register("dn", model, params, state, decode=True,
+                         num_slots=2, max_seq_len=16, paged=False)
+        assert "dn" not in eng.stats()
+        kw = dict(decode=True, num_slots=2, max_seq_len=16,
+                  prefill_chunk=4, precompile_decode=False)
+        a = eng.register("a", model, params, state, paged=None, **kw)
+        b = eng.register("b", model, params, state, paged=True, **kw)
+        assert (a.decode.pool_blocks, a.decode.prefix_cache) == \
+            (b.decode.pool_blocks, b.decode.prefix_cache)
+    finally:
+        eng.shutdown()
+
+
 # ----------------------------------------------------- llama / GQA path
 def test_llama_engine_parity():
     """The grouped-KV (GQA + RoPE) decode path through the real engine
@@ -820,15 +872,13 @@ def test_decode_knobs_registered():
     from bigdl_tpu.utils import config
     knobs = config.knobs()
     for name in ("SERVE_DECODE_SLOTS", "SERVE_PREFILL_CHUNK",
-                 "SERVE_MAX_SEQ_LEN", "SERVE_KV_PAGED",
-                 "SERVE_KV_BLOCK", "SERVE_KV_POOL_BLOCKS",
+                 "SERVE_MAX_SEQ_LEN", "SERVE_KV_BLOCK", "SERVE_KV_POOL_BLOCKS",
                  "SERVE_PREFIX_CACHE", "SERVE_PREFIX_CACHE_BLOCKS",
                  "SERVE_SAMPLING", "SERVE_KV_SHARD"):
         assert name in knobs and knobs[name].doc
     assert config.get("SERVE_DECODE_SLOTS") >= 1
     assert config.get("SERVE_MAX_SEQ_LEN") >= 1
     assert config.get("SERVE_KV_BLOCK") >= 1
-    assert config.get("SERVE_KV_PAGED") in (True, False)
 
 
 # ----------------------------------------------------------------- CLI

@@ -48,7 +48,7 @@ def trained_resnet20():
         return p2, ns, sl2, l
 
     # 6 epochs reaches the 0.95 fp32 floor with margin on the fixture
-    # set; 8 made this the #3 tier-1 offender (ROUND6_NOTES.md)
+    # set; 8 made this the #3 tier-1 offender
     r = np.random.RandomState(0)
     for _ in range(6):
         order = r.permutation(len(xtr))

@@ -215,8 +215,10 @@ def test_program_params_and_stacked_are_equal_leaf_by_leaf(family, lm):
 
 
 def test_paged_contract_equals_the_reference_on_logits(family, lm):
-    """Prefill in uneven chunks through the paged contract, then the
-    decode step's logits at every position, against the reference's full
+    """Prefill in uneven chunks through the paged contract (the hidden
+    states in chunk form, then one token a step and the head, composed
+    as DecodeEntry._build composes them), the step's logits at every
+    position, against the reference's full
     forward pass: two rows at different offsets, two idle slots."""
     model, params, w = lm
     rng = np.random.default_rng(4)
@@ -228,8 +230,14 @@ def test_paged_contract_equals_the_reference_on_logits(family, lm):
     table = np.full((SLOTS, 12), -1, np.int32)
     table[1, :8], table[2, :8] = np.arange(8), np.arange(20, 28)
     rows, fed = (1, 2), [0, 0]
-    prefill = jax.jit(model.paged_prefill)
-    decode = jax.jit(model.paged_decode_logits)
+    prefill = jax.jit(lambda p, c, t, pos, bt, ln: model.paged_hidden(
+        p, c, t, pos, bt, ln, decode=False)[1])
+
+    @jax.jit
+    def decode(p, c, t, pos, a, bt):
+        x, c = model.paged_hidden(p, c, t[:, None], pos[:, None], bt,
+                                  a.astype(jnp.int32), decode=True)
+        return model.head_logits(p, x), c
     for chunk in ((16, 16), (16, 7), (8, 8), (4, 0)):
         C = max(chunk)
         toks = np.zeros((SLOTS, C), np.int32)
@@ -388,7 +396,8 @@ def test_entry_refuses_the_prefix_cache_by_name(lm):
 
 
 def test_entry_refuses_the_dense_bucket_by_name(lm):
-    with pytest.raises(TypeError, match="paged slot-decode contract only"):
+    with pytest.raises(ValueError, match="dense slot bucket was removed; "
+                                         "OlmoHybridLM"):
         _entry(lm, "dense", paged=False)
 
 

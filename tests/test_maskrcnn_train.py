@@ -107,8 +107,7 @@ def test_maskrcnn_trains_to_map_floor(tmp_path):
         max_detections=8, mask_resolution=7, score_thresh=0.5,
         anchor_scales=(2.0, 4.0))
     # 24 epochs clears both mAP floors at seed 3; 35 made this the top
-    # tier-1 offender at 112 s on the 1-core image (ROUND6_NOTES.md
-    # durations table)
+    # tier-1 offender at 112 s on the 1-core image
     params, state, (first, last) = maskrcnn.finetune(
         model, ds, epochs=24, lr=2e-3, rng=jax.random.PRNGKey(3))
     assert last < 0.2 * first, (first, last)

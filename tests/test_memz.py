@@ -1,6 +1,6 @@
 """Device-memory observability tests (observe/memz.py — ISSUE 15):
 buffer-ledger lifecycle (register → bytes appear, unregister/GC → back
-to baseline), the decode KV bucket accounted EXACTLY against the closed
+to baseline), the decode KV pool accounted EXACTLY against the closed
 form, unattributed drift ~0 on the clean path, the /memz live plane
 scraped during a real optimize(), the memory watchdog opening exactly
 ONE incident attributed to the fastest-growing owner, serve admission
@@ -130,30 +130,34 @@ def test_prefetch_staging_bytes_return_to_zero(clean_mem):
     assert led.owners()["data/staging"]["peak_bytes"] > 0
 
 
-# ------------------------------------------------- decode bucket account
+# --------------------------------------------------- decode pool account
 def test_decode_kv_bucket_accounted_exactly_closed_form(clean_mem):
     from bigdl_tpu.serve.decode import decode_demo_model
     from bigdl_tpu.serve.engine import ServeEngine
-    layers, heads, d_model, slots, seq = 2, 4, 32, 4, 32
+    layers, heads, d_model, slots, seq, block = 2, 4, 32, 4, 32, 8
     model, params, state = decode_demo_model(
         num_layers=layers, d_model=d_model, num_heads=heads)
     eng = ServeEngine()
     entry = eng.register("lm", model, params, state, decode=True,
-                         num_slots=slots, max_seq_len=seq, paged=False,
-                         precompile_decode=False)
-    # dense mode: num_slots x max_seq_len x layers x heads x hd x dtype,
-    # K and V (the paged pool's ledger surface lives in test_decode.py)
+                         num_slots=slots, max_seq_len=seq, kv_block=block,
+                         kv_pool_blocks=10, precompile_decode=False)
+    # the pool: pool_blocks x kv_block x layers x heads x 2*hd (K's lanes
+    # then V's) x itemsize, whatever slots x max_seq_len would come to
+    # (the pool's live block accounting is in test_decode.py)
     hd = d_model // heads
-    want = slots * seq * layers * heads * hd * 4 * 2
+    assert (entry.decode.pool_blocks, entry.decode.kv_block) == (10, block)
+    want = 10 * block * layers * heads * 2 * hd * 4
     owners = memz.ledger().owners()
-    assert owners["serve/lm/kv_cache"]["bytes"] == want
+    assert owners["serve/lm/kv_pool"]["bytes"] == want
     assert entry.decode.kv_cache_bytes == want
-    assert owners["serve/lm/kv_cache"]["meta"]["slots"] == slots
+    assert owners["serve/lm/kv_pool"]["meta"]["slots"] == slots
+    assert owners["serve/lm/kv_pool"]["meta"]["bytes_per_block"] == \
+        want // 10
     assert owners["serve/lm/params"]["bytes"] == \
         memz.tree_nbytes(params) + memz.tree_nbytes(state)
-    # engine/entry teardown returns the bucket bytes to baseline
+    # engine/entry teardown returns the pool's bytes to baseline
     eng.shutdown()
-    assert "serve/lm/kv_cache" not in memz.ledger().owners()
+    assert "serve/lm/kv_pool" not in memz.ledger().owners()
     eng.registry.unregister("lm")
     assert "serve/lm/params" not in memz.ledger().owners()
 
@@ -176,18 +180,18 @@ def test_unattributed_drift_near_zero_on_clean_path(clean_mem):
 def test_headroom_estimates_from_limit(clean_mem, monkeypatch):
     led = memz.ledger()
     led.set_baseline()
-    kv = tuple(np.zeros((4, 16, 2, 8), np.float32) for _ in range(2))
-    led.register("serve/lm/kv_cache", kv, kind="kv_cache",
-                 meta={"slots": 4, "max_seq_len": 16})
+    kv = tuple(np.zeros((2, 8, 8, 16), np.float32) for _ in range(2))
+    led.register("serve/lm/kv_pool", kv, kind="kv_pool",
+                 meta={"blocks": 8, "block": 8, "blocks_free": 5,
+                       "bytes_per_block": 2 * 2 * 8 * 16 * 4})
     led.register("serve/lm/params", nbytes=10_000, kind="params")
     in_use = memz.backend_in_use()[0]
     monkeypatch.setenv("BIGDL_TPU_MEM_LIMIT_BYTES", str(in_use + 50_000))
     head = led.headroom()
     assert head["free_bytes"] == pytest.approx(50_000, abs=2048)
-    per_slot = (2 * 4 * 16 * 2 * 8 * 4) // 4
-    dec = head["decode_slots"]["serve/lm/kv_cache"]
-    assert dec["bytes_per_slot"] == per_slot
-    assert dec["additional_slots"] == head["free_bytes"] // per_slot
+    assert head["kv_pools"]["serve/lm/kv_pool"] == {
+        "blocks": 8, "blocks_free": 5, "block_tokens": 8,
+        "bytes_per_block": 2 * 2 * 8 * 16 * 4}
     assert head["one_more_model"]["fits"] is True
     monkeypatch.setenv("BIGDL_TPU_MEM_LIMIT_BYTES", str(in_use + 5_000))
     assert led.headroom()["one_more_model"]["fits"] is False
@@ -412,7 +416,7 @@ def test_decode_admission_refused_with_capacity_report(
     model, params, state = decode_demo_model(num_layers=2, d_model=32,
                                              num_heads=4)
     in_use = memz.backend_in_use()[0]
-    # leave less headroom than params + the KV bucket need
+    # leave less headroom than params + the KV pool need
     monkeypatch.setenv("BIGDL_TPU_MEM_LIMIT_BYTES", str(in_use + 10_000))
     eng = ServeEngine()
     with pytest.raises(memz.CapacityError) as ei:
@@ -420,14 +424,12 @@ def test_decode_admission_refused_with_capacity_report(
                      num_slots=8, max_seq_len=256,
                      precompile_decode=False)
     msg = str(ei.value)
-    # paged (default) sizes a block pool; dense mode keeps "KV bucket"
-    assert ("paged pool" in msg or "KV bucket" in msg)
+    assert "paged pool" in msg
     assert "bytes" in msg and "/memz" in msg
     assert observe.counter("mem/admission_refused").value == 1
     # nothing was registered (no half-registered model, no scheduler)
     assert eng.models() == []
     owners = memz.ledger().owners()
-    assert "serve/lm/kv_cache" not in owners
     assert "serve/lm/kv_pool" not in owners
     # with the limit lifted the same registration succeeds
     monkeypatch.delenv("BIGDL_TPU_MEM_LIMIT_BYTES")

@@ -61,7 +61,7 @@ def _launch(worker_name, n_procs, tmp_path, port):
     reason="this image's CPU jax backend cannot run multi-process "
            "collectives ('Multiprocess computations aren't implemented "
            "on the CPU backend') — pre-existing environment capability, "
-           "reproduced on the pre-PR tree (ROUND6_NOTES.md); passes "
+           "reproduced on the pre-PR tree; passes "
            "where the distributed CPU/TPU backend exists")
 def test_four_process_composed_and_elastic_resume(tmp_path):
     """4 processes × 2 devices: dp×pp, dp×ep, dp×sp composed meshes all
@@ -93,7 +93,7 @@ def test_four_process_composed_and_elastic_resume(tmp_path):
     reason="this image's CPU jax backend cannot run multi-process "
            "collectives ('Multiprocess computations aren't implemented "
            "on the CPU backend') — pre-existing environment capability, "
-           "reproduced on the pre-PR tree (ROUND6_NOTES.md); passes "
+           "reproduced on the pre-PR tree; passes "
            "where the distributed CPU/TPU backend exists")
 def test_two_process_training(tmp_path):
     port = _free_port()
