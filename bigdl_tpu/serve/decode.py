@@ -34,7 +34,12 @@ this codebase's discipline):
     through power-of-two length-bucketed AOT prefill programs
     (`BIGDL_TPU_SERVE_PREFILL_CHUNK` caps the chunk), so a long prompt
     stalls concurrent decode for at most one chunk and the program
-    count stays O(log chunk).
+    count stays O(log chunk). A call computes the rows that stream a
+    prompt: every bucket's program is over ONE row (the slot's index,
+    its block-table row, its row of a state resident by slot, put back
+    in place), and the full chunk keeps a `num_slots`-row program for
+    the burst in which half the slots or more stream a full chunk in one
+    iteration (`DecodeEntry.prefill_rows`).
   * **iteration-level scheduler** — clock-injectable (the batcher.py
     fake-clock testing discipline): every iteration first admits
     queued requests into free slots (prefill), then runs one fused step
@@ -97,7 +102,9 @@ Observability: `serve/<model>/decode/{tokens_per_s, slot_occupancy,
 prefill_ms, step_ms, queue_wait_ms, latency_ms, ttft_ms}` + counters
 (`steps`; `steps_ahead`, the steps enqueued while the one before them
 was unfetched; `rows_dropped`, the rows computed for a sequence that
-had ended by value or been cancelled), a `decode` section in /statusz,
+had ended by value or been cancelled; `prefill_tokens`, `prefill_calls`
+and `prefill_rows`, the rows those calls computed, streaming or not),
+a `decode` section in /statusz,
 per-peer decode rows in /fleetz, and the ServeWatchdog pointed at
 decode latency p99 with queue-vs-prefill-vs-step attribution
 (observe/doctor.py). `step_ms` is what one iteration costs a token:
@@ -506,10 +513,14 @@ class DecodeEntry:
             f"of params yet to be placed)")
         self._jit_decode = None
         self._jit_prefill = None
+        self._jit_prefill_rows = None
         self._jit_merge = None
         self._aot_decode = None
         self._aot_merge = None
+        # bucket -> the one-row program; the num_slots-row program of the
+        # full chunk
         self._aot_prefill: Dict[int, object] = {}
+        self._aot_prefill_all = None
         self._placed = None          # (params, caches) device-resident
         self._shardings = None
         self._build()
@@ -546,12 +557,15 @@ class DecodeEntry:
             #   decode:  (params, caches, tokens, positions, active,
             #             table[, temps, top_ks, top_ps, seeds])
             #   prefill: (params, caches, tokens, positions, table,
-            #             lengths)
+            #             lengths[, slots])
             n_samp = 4 if self.sampling else 0
             kw_d["in_shardings"] = (rep, cache_sh) + (rep,) * (4 + n_samp)
             kw_d["out_shardings"] = (rep, cache_sh)
             kw_p["in_shardings"] = (rep, cache_sh) + (rep,) * 4
             kw_p["out_shardings"] = cache_sh
+        kw_r = dict(kw_p)
+        if "in_shardings" in kw_r:
+            kw_r["in_shardings"] += (self._rep_sharding,)
         # the programs, composed from what the model owns: the token choice
         # is the server's, greedy or sampled as the registration says
         jnp = jax.numpy
@@ -575,8 +589,27 @@ class DecodeEntry:
             # inactive rows) out of the pool write; no logits
             return model.paged_hidden(p, c, t, pos, bt, ln, decode=False)[1]
 
+        mask = self._slot_mask
+
+        def _prefill_rows(p, c, t, pos, bt, ln, slots):
+            # the same chunk over the rows of the slots `slots` (R,) and no
+            # others: a pool is found through a row's block table whatever
+            # the row count; a leaf resident by slot gives the call its rows
+            # and takes them back where they lay, so a slot that does not
+            # stream is never read
+            if mask is None:
+                return _prefill(p, c, t, pos, bt, ln)
+            own = jax.tree.map(
+                lambda by_slot, a: a.at[slots].get(
+                    mode="promise_in_bounds") if by_slot else a, mask, c)
+            return jax.tree.map(
+                lambda by_slot, a, new: a.at[slots].set(
+                    new, mode="promise_in_bounds", unique_indices=True)
+                if by_slot else new, mask, c, _prefill(p, own, t, pos, bt, ln))
+
         self._jit_decode = jax.jit(_step, **kw_d)
         self._jit_prefill = jax.jit(_prefill, **kw_p)
+        self._jit_prefill_rows = jax.jit(_prefill_rows, **kw_r)
         # the next step's input tokens while this step's are still on the
         # device: a row that continues takes its own output, every other
         # row what the host knows (DecodeScheduler._dispatch_step)
@@ -644,12 +677,15 @@ class DecodeEntry:
 
     # --------------------------------------------------------------- AOT
     def precompile(self) -> Dict[str, Dict]:
-        """AOT-compile the fused decode step, every prefill-chunk bucket
-        and the token merge before traffic
-        (compilecache.precompile_fixed) — with the persistent compile
-        cache warm, a restarted decode server compiles ZERO fresh programs (counter-asserted in
-        tests/test_decode.py). Cost analyses land under
-        `compile/serve/<model>/decode/...`."""
+        """AOT-compile the fused decode step, the prefill programs and the
+        token merge before traffic (compilecache.precompile_fixed) — with
+        the persistent compile cache warm, a restarted decode server
+        compiles ZERO fresh programs (counter-asserted in
+        tests/test_decode.py). The prefill programs: one over ONE row (the
+        slot that streams a prompt, named by its index) for every bucket,
+        and the `num_slots`-row program of the full chunk, for the burst in
+        which many slots stream a full chunk at once (`prefill_rows`). Cost
+        analyses land under `compile/serve/<model>/decode/...`."""
         import jax
         from bigdl_tpu.compilecache import precompile_fixed
 
@@ -669,12 +705,12 @@ class DecodeEntry:
             c_s = jax.tree.map(
                 lambda a, s: spec(tuple(a.shape), a.dtype, sharding=s),
                 raw, sh)
-        S = self.num_slots
+        S, M = self.num_slots, self.blocks_per_slot
         i32 = np.dtype(np.int32)
         f32 = np.dtype(np.float32)
         vec = spec((S,), i32)
         act = spec((S,), np.dtype(np.bool_))
-        table = spec((S, self.blocks_per_slot), i32)
+        table = spec((S, M), i32)
         samp = ((spec((S,), f32), vec, spec((S,), f32), vec)
                 if self.sampling else ())
         d_args = (p_s, c_s, vec, vec, act, table) + samp
@@ -684,19 +720,42 @@ class DecodeEntry:
             name=f"serve/{self.name}/decode/step")
         self._assert_pool_sharding(self._aot_decode)
         results["decode_step"] = cost
+        row = spec((1,), i32)
         for b in self.buckets:
-            chunk = spec((S, b), i32)
+            chunk = spec((1, b), i32)
             cost, exe = precompile_fixed(
-                self._jit_prefill, (p_s, c_s, chunk, chunk, table, vec),
+                self._jit_prefill_rows,
+                (p_s, c_s, chunk, chunk, spec((1, M), i32), row, row),
                 name=f"serve/{self.name}/decode/prefill{b}")
             self._assert_pool_sharding(exe)
             self._aot_prefill[b] = exe
             results[f"prefill{b}"] = cost
+        C = self.prefill_chunk
+        chunk = spec((S, C), i32)
+        cost, self._aot_prefill_all = precompile_fixed(
+            self._jit_prefill, (p_s, c_s, chunk, chunk, table, vec),
+            name=f"serve/{self.name}/decode/prefill{C}x{S}")
+        self._assert_pool_sharding(self._aot_prefill_all)
+        results[f"prefill{C}x{S}"] = cost
         cost, self._aot_merge = precompile_fixed(
             self._jit_merge, (act, vec, vec),
             name=f"serve/{self.name}/decode/merge")
         results["merge"] = cost
         return results
+
+    def prefill_rows(self, C: int, k: int) -> int:
+        """Rows of the program that advances `k` slots by a chunk of bucket
+        `C`: 1 (a one-row call a slot) or `num_slots` (one call for all,
+        which exists for the full chunk alone). The one call from half the
+        slots on: on a v5e a one-row chunk of 64 takes 11.47 ms (GPT-2 XL)
+        and 12.10 ms (Olmo-Hybrid), the 8-row one 34.09 and 45.52 ms, so
+        three calls tie with it or beat it and four lose (PERF.md section
+        5). The compile-time cost analyses put the switch a slot too early
+        for the second: they count bytes, and see neither what its 8-row
+        program computes nor its triangular solves."""
+        if C == self.prefill_chunk and k >= 2 and 2 * k >= self.num_slots:
+            return self.num_slots
+        return 1
 
     def _assert_pool_sharding(self, exe) -> None:
         """kv_shard=True: assert the compiled executable actually
@@ -716,14 +775,22 @@ class DecodeEntry:
                 f"GSPMD dropped the block-dim partition")
 
     # ------------------------------------------------------------ device
-    def run_prefill(self, caches, tokens: np.ndarray, *rest):
+    def run_prefill(self, caches, tokens: np.ndarray, positions, table,
+                    lengths, slots: Optional[np.ndarray] = None):
         """One chunk-prefill program call; returns the new caches (the
-        input cache buffers are donated on TPU). `rest` is the trailing
-        host args (positions, block_table, lengths)."""
+        input cache buffers are donated on TPU). `slots` (R,) int32 names
+        the slots whose rows `tokens`, `positions` (R, C), `table` (R, M)
+        and `lengths` (R,) hold; None: row i is slot i, all `num_slots`."""
         C = tokens.shape[1]
-        args = (self.placed_params(), caches, self._place(tokens)) + \
-            tuple(self._place(a) for a in rest)
-        exe = self._aot_prefill.get(C)
+        by_row = slots is not None
+        host = (tokens, positions, table, lengths) + ((slots,) if by_row
+                                                      else ())
+        args = (self.placed_params(), caches) + \
+            tuple(self._place(a) for a in host)
+        if by_row:
+            exe = self._aot_prefill.get(C) if len(slots) == 1 else None
+        else:
+            exe = self._aot_prefill_all if C == self.prefill_chunk else None
         if exe is not None:
             try:
                 return exe(*args)
@@ -734,8 +801,12 @@ class DecodeEntry:
                 log.warning("serve[%s]: decode prefill%d AOT executable "
                             "rejected live inputs; falling back to jit",
                             self.name, C)
-                self._aot_prefill.pop(C, None)
-        return self._jit_prefill(*args)
+                if by_row:
+                    self._aot_prefill.pop(C, None)
+                else:
+                    self._aot_prefill_all = None
+        return (self._jit_prefill_rows if by_row
+                else self._jit_prefill)(*args)
 
     def run_decode(self, caches, tokens_last: np.ndarray, *rest):
         """One fused decode step, enqueued and not waited for; returns
@@ -1026,6 +1097,11 @@ class DecodeScheduler:
             float(entry.state_bytes))
         self._m_prefill_tokens = observe.counter(
             f"serve/{n}/decode/prefill_tokens")
+        # prefill program calls, and the rows they computed, active or not
+        self._m_prefill_calls = observe.counter(
+            f"serve/{n}/decode/prefill_calls")
+        self._m_prefill_rows = observe.counter(
+            f"serve/{n}/decode/prefill_rows")
         self._m_state_resets = observe.counter(
             f"serve/{n}/decode/state_resets")
         self._win_t0 = self._clock()
@@ -1249,8 +1325,10 @@ class DecodeScheduler:
         return c
 
     def _prefill_pass(self) -> int:
-        """Advance every prompt-streaming slot by one chunk, grouped by
-        bucket size (one program call per distinct bucket)."""
+        """Advance every prompt-streaming slot by one chunk, bucket by
+        bucket: a one-row program call for each slot, or, where half the
+        slots or more stream a full chunk at once, the one `num_slots`-row
+        call (`DecodeEntry.prefill_rows`)."""
         pending = [r for r in self._slots
                    if r is not None and r.fed < r.prefill_target]
         if not pending:
@@ -1258,40 +1336,52 @@ class DecodeScheduler:
         by_bucket: Dict[int, List[_GenRequest]] = {}
         for req in pending:
             by_bucket.setdefault(self._chunk_for(req), []).append(req)
-        S = self.entry.num_slots
-        done = 0
         for C, reqs in sorted(by_bucket.items()):
-            tokens = np.zeros((S, C), np.int32)
-            positions = np.zeros((S, C), np.int32)
-            lengths = np.zeros((S,), np.int32)
-            for req in reqs:
-                n = min(req.prefill_target - req.fed, C)
-                tokens[req.slot, :n] = req.prompt[req.fed:req.fed + n]
-                positions[req.slot] = req.fed + np.arange(C)
-                lengths[req.slot] = n
-            with self._cv:
+            if self.entry.prefill_rows(C, len(reqs)) == 1:
                 for req in reqs:
-                    n = int(lengths[req.slot])
-                    self._ensure_blocks(req, req.fed + n - 1)
-                table = self._tables.copy()
-            t0 = self._clock()
-            with observe.span("serve/decode/prefill", cat="serve",
-                              args={"model": self.name, "chunk": C,
-                                    "slots": len(reqs),
-                                    "state": self.entry.state_kind}):
-                # lengths masks the rounded-up bucket's padded tail (and
-                # inactive rows) out of the pool scatter
-                self._caches = self.entry.run_prefill(
-                    self._caches, tokens, positions, table, lengths)
-            self._h_prefill.record(
-                max(0.0, (self._clock() - t0) * 1e3))
-            self._m_prefill_tokens.inc(int(lengths.sum()))
-            for req in reqs:
-                req.fed += min(req.prefill_target - req.fed, C)
-                done += 1
-                if self._prefix is not None:
-                    self._commit_prefix(req)
-        return done
+                    self._prefill_call(C, [req], by_row=True)
+            else:
+                self._prefill_call(C, reqs, by_row=False)
+        return len(pending)
+
+    def _prefill_call(self, C: int, reqs: List[_GenRequest],
+                      by_row: bool) -> None:
+        """One prefill program call that advances `reqs` by a chunk of
+        bucket `C`: over their slots' rows and no others (`by_row`), or
+        over all `num_slots` rows, those of the other slots at length 0."""
+        slots = np.asarray([req.slot for req in reqs], np.int32)
+        R = len(reqs) if by_row else self.entry.num_slots
+        rows = range(R) if by_row else slots
+        tokens = np.zeros((R, C), np.int32)
+        positions = np.zeros((R, C), np.int32)
+        lengths = np.zeros((R,), np.int32)
+        for i, req in zip(rows, reqs):
+            n = min(req.prefill_target - req.fed, C)
+            tokens[i, :n] = req.prompt[req.fed:req.fed + n]
+            positions[i] = req.fed + np.arange(C)
+            lengths[i] = n
+        with self._cv:
+            for i, req in zip(rows, reqs):
+                self._ensure_blocks(req, req.fed + int(lengths[i]) - 1)
+            table = self._tables[slots] if by_row else self._tables.copy()
+        t0 = self._clock()
+        with observe.span("serve/decode/prefill", cat="serve",
+                          args={"model": self.name, "chunk": C,
+                                "slots": len(reqs), "rows": R,
+                                "state": self.entry.state_kind}):
+            # lengths masks the rounded-up bucket's padded tail (and
+            # inactive rows) out of the pool scatter
+            self._caches = self.entry.run_prefill(
+                self._caches, tokens, positions, table, lengths,
+                slots if by_row else None)
+        self._h_prefill.record(max(0.0, (self._clock() - t0) * 1e3))
+        self._m_prefill_calls.inc()
+        self._m_prefill_rows.inc(R)
+        self._m_prefill_tokens.inc(int(lengths.sum()))
+        for req in reqs:
+            req.fed += min(req.prefill_target - req.fed, C)
+            if self._prefix is not None:
+                self._commit_prefix(req)
 
     def _commit_prefix(self, req: _GenRequest) -> None:
         """Publish the whole prompt blocks `req`'s prefill frontier has
@@ -1666,6 +1756,8 @@ class DecodeScheduler:
             "state_bytes": self.entry.state_bytes,
             "state_resets": int(self._m_state_resets.value),
             "prefill_tokens": int(self._m_prefill_tokens.value),
+            "prefill_calls": int(self._m_prefill_calls.value),
+            "prefill_rows": int(self._m_prefill_rows.value),
             "kv_block": self.entry.kv_block,
             "kv_blocks_total": pool.total,
             "kv_blocks_free": pool.free,

@@ -211,6 +211,30 @@ def test_gpt2_xl_paged_prefill_chunk_fits_one_chip(one_chip, xl_entry):
     _serves_from_the_pool(c, entry, caches)
 
 
+def _one_row_args(one_chip, entry, C):
+    """The trailing arguments of the prefill program over one streaming
+    slot's row: tokens, positions, its block-table row, its length, its
+    slot index."""
+    chunk = _sds(one_chip, (1, C), np.int32)
+    row = _sds(one_chip, (1,), np.int32)
+    return (chunk, chunk,
+            _sds(one_chip, (1, entry.blocks_per_slot), np.int32), row, row)
+
+
+def test_gpt2_xl_one_row_prefill_chunk_serves_from_the_pool(one_chip,
+                                                            xl_entry):
+    """The chunk-64 program over ONE row, the call a streaming slot costs:
+    the pool as in the `num_slots`-row program (donated, no pool-sized
+    copy), and temporaries below that program's 416,298,496 bytes (its
+    scores are an eighth; the tied table's copy is most of what is left)."""
+    entry, params, caches = xl_entry
+    c = entry._jit_prefill_rows.lower(
+        params, caches,
+        *_one_row_args(one_chip, entry, entry.buckets[-1])).compile()
+    _serves_from_the_pool(c, entry, caches)
+    assert c.memory_analysis().temp_size_in_bytes < 416_298_496
+
+
 # Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B config.json): one period of its
 # layer pattern at the published widths, as benchmark/configs/
 # olmo-hybrid-7b-serve.json registers it (the cell holds four periods)
@@ -294,3 +318,25 @@ def test_olmo_hybrid_prefill_chunk_keeps_its_state_in_place(one_chip,
         _sds(one_chip, (S,), np.int32)).compile()
     m = _keeps_both_kinds_of_state_in_place(c, entry, caches)
     assert m.temp_size_in_bytes < entry.kv_cache_bytes, m
+
+
+def test_olmo_hybrid_one_row_prefill_puts_its_row_back_in_place(
+        one_chip, hybrid_entry):
+    """The chunk-64 program over ONE row: the streaming slot's state is
+    taken out of the eight, and the new row written back into the donated
+    buffer (a dynamic-update-slice of each linear layer's `S` and `conv`,
+    no copy of either, no scatter); the chunk form works on one slot's
+    state, so its temporaries (17.8 MB) are a sixth of the 8-row program's."""
+    entry, params, caches = hybrid_entry
+    c = entry._jit_prefill_rows.lower(
+        params, caches,
+        *_one_row_args(one_chip, entry, entry.buckets[-1])).compile()
+    m = _keeps_both_kinds_of_state_in_place(c, entry, caches)
+    text = c.as_text()
+    put_back = re.findall(
+        r"= (f32\[8,30,96,192\]|bf16\[8,3,11520\])\S* "
+        r"(dynamic-update-slice|scatter)\(", text)
+    assert sorted(op for _, op in put_back) == ["dynamic-update-slice"] * 6
+    # the 8-row program of this period takes 113,926,144 bytes
+    assert m.temp_size_in_bytes < 113_926_144 // 4, m
+

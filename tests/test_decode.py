@@ -265,6 +265,105 @@ def test_chunked_prefill_buckets_and_long_prompt(lm, entry):
     check_vs_oracle(lm, prompt, outs[0], 8)
 
 
+# ----------------------------------- a prefill call over the streaming rows
+def _tiny_hybrid():
+    """A two-layer OlmoHybridLM (one linear layer with state by slot, one
+    full layer over the pool) and its parameters."""
+    from bigdl_tpu.interop.olmo_hybrid import FULL, LINEAR, OlmoHybridLM
+    model = OlmoHybridLM(
+        VOCAB, 16, 4, 24, [LINEAR, FULL],
+        dict(num_heads=4, key_dim=8, value_dim=12, conv_kernel=4,
+             allow_neg_eigval=True), 32, eos_id=EOS)
+    return model, model.init(jax.random.PRNGKey(5))[0]
+
+
+@pytest.mark.parametrize("family", ["GPT2LM", "OlmoHybridLM"])
+def test_one_row_prefill_equals_the_all_rows_prefill_of_that_row(lm, family):
+    """The program over the rows of the slots that stream, given one slot,
+    leaves in that slot's blocks and state what the `num_slots`-row program
+    leaves there with only that row active (a padded tail, a chunk that
+    continues a prompt), and every other slot's state and every other block
+    bit for bit as they were: it never read them."""
+    model, params = lm[:2] if family == "GPT2LM" else _tiny_hybrid()
+    e = DecodeEntry(f"row{family}", model, params, num_slots=4,
+                    max_seq_len=32, prefill_chunk=8, kv_block=8)
+    r = np.random.RandomState(2)
+    dirty = jax.tree.map(
+        lambda a: jnp.asarray(r.randn(*a.shape), a.dtype), e.make_caches())
+    s, C, fed, n = 2, 8, 8, 5
+    table = np.full((4, e.blocks_per_slot), -1, np.int32)
+    table[:, :2] = np.arange(8).reshape(4, 2)
+    tokens = np.zeros((4, C), np.int32)
+    positions = np.zeros((4, C), np.int32)
+    lengths = np.zeros((4,), np.int32)
+    tokens[s, :n] = r.randint(2, VOCAB, n)
+    positions[s] = fed + np.arange(C)
+    lengths[s] = n
+    every = e.run_prefill(dirty, tokens, positions, table, lengths)
+    one = e.run_prefill(dirty, tokens[[s]], positions[[s]], table[[s]],
+                        lengths[[s]], np.asarray([s], np.int32))
+    mask = jax.tree.leaves(e._slot_mask) if e.slot_state else None
+    by_slot = 0
+    for i, (was, a, b) in enumerate(zip(*map(jax.tree.leaves,
+                                             (dirty, every, one)))):
+        was, a, b = (np.asarray(x) for x in (was, a, b))
+        np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-6)
+        assert not np.array_equal(b, was)            # the chunk was written
+        if mask is not None and mask[i]:
+            by_slot += 1
+            others = [j for j in range(4) if j != s]
+            np.testing.assert_array_equal(b[others], was[others])
+        else:
+            # the pool: only lanes 0..n-1 of the slot's second block moved
+            blk = int(table[s, 1])
+            rest = np.delete(b, blk, axis=1), np.delete(was, blk, axis=1)
+            np.testing.assert_array_equal(*rest)
+            np.testing.assert_array_equal(b[:, blk, n:], was[:, blk, n:])
+    assert by_slot == (2 if family == "OlmoHybridLM" else 0)
+
+
+def test_the_all_rows_prefill_call_comes_from_half_the_slots_on(lm):
+    model, params, _ = lm
+    of8 = DecodeEntry("rule8", model, params, num_slots=8, max_seq_len=32,
+                      prefill_chunk=8)
+    assert [of8.prefill_rows(8, k) for k in range(1, 9)] == [1] * 3 + [8] * 5
+    assert of8.prefill_rows(4, 8) == 1        # a short bucket: always by row
+    alone = DecodeEntry("rule1", model, params, num_slots=1, max_seq_len=32,
+                        prefill_chunk=8)
+    assert alone.prefill_rows(8, 1) == 1
+
+
+@pytest.mark.parametrize("picked,want", [
+    ([1], (1, 1)), ([3, 5], (2, 2)), ([1, 2], (1, 4)),
+    ([1, 2, 4, 6], (1, 4))],
+    ids=["one", "two-in-a-short-bucket", "half-the-slots", "every-slot"])
+def test_prefill_calls_follow_the_slots_that_stream(
+        lm, entry, staggered_submits, staggered_oracle, picked, want):
+    """The slots that stream a chunk of one bucket in one iteration get a
+    one-row call each; from half the slots on, in the full chunk's bucket,
+    the one `num_slots`-row call. Calls made and rows computed are
+    counted, and the tokens are the isolated oracle's either way."""
+    sched = DecodeScheduler(entry, name="rows" + "".join(map(str, picked)),
+                            start=False)
+    replies = [sched.submit(staggered_submits[i][1], staggered_submits[i][2],
+                            eos_id=staggered_submits[i][3]) for i in picked]
+    sched.step_once()
+    calls, rows = _counter(sched, "prefill_calls"), _counter(
+        sched, "prefill_rows")
+    assert (calls, rows) == want
+    outs = _run_until_done(sched, replies)
+    stats = sched.stats()
+    assert stats["prefill_calls"] == _counter(sched, "prefill_calls") >= calls
+    assert stats["prefill_rows"] == _counter(sched, "prefill_rows")
+    assert stats["prefill_tokens"] == sum(
+        len(staggered_submits[i][1]) - 1 for i in picked)
+    for i, got in zip(picked, outs):
+        _, prompt, max_new, eos = staggered_submits[i]
+        check_vs_oracle(lm, prompt, got, max_new, eos_id=eos,
+                        want=staggered_oracle(i))
+    sched.close(drain=False)
+
+
 def test_submit_validation_and_admission(entry):
     sched = DecodeScheduler(entry, name="adm", max_queue=2, start=False)
     with pytest.raises(ValueError):
@@ -693,15 +792,28 @@ def test_engine_concurrent_generate_parity(lm, engine):
 
 def test_zero_fresh_compiles_after_precompile(engine):
     """ISSUE 14 acceptance: the warm serving path compiles NOTHING —
-    decode step + every prefill bucket are AOT executable hits."""
+    decode step, the one-row prefill program of every bucket and the
+    `num_slots`-row program of the full chunk are AOT executable hits."""
+    sched = engine._decoders["lm"]
+    dec = sched.entry
+    assert sorted(dec._aot_prefill) == list(dec.buckets) == [1, 2, 4, 8]
+    assert dec._aot_prefill_all is not None and dec._aot_decode is not None
     compiles = observe.registry().counter("jit/compiles")
     c0 = compiles.value
+    calls0, rows0 = (_counter(sched, n)
+                     for n in ("prefill_calls", "prefill_rows"))
     r = np.random.RandomState(4)
-    reps = [engine.submit_generate("lm", r.randint(2, VOCAB, p), 6)
-            for p in (2, 5, 9, 13, 7, 3, 11, 6)]
+    with sched._cv:     # all eight queued before the first is admitted
+        reps = [engine.submit_generate("lm", r.randint(2, VOCAB, p), 6)
+                for p in (2, 5, 9, 13, 7, 3, 11, 6)]
     for rep in reps:
         rep.result(timeout=60)
     assert compiles.value == c0
+    # both kinds of prefill call ran: 9 and 13, half the slots, met in the
+    # full chunk's bucket
+    calls = _counter(sched, "prefill_calls") - calls0
+    assert calls < _counter(sched, "prefill_rows") - rows0 < 4 * calls
+    assert dec._aot_prefill_all is not None and len(dec._aot_prefill) == 4
 
 
 def test_streaming_reply_yields_before_completion(engine):
