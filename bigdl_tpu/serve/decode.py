@@ -397,9 +397,8 @@ class DecodeEntry:
             raise TypeError(
                 f"decode=True needs a model implementing the paged "
                 f"slot-decode contract {_PAGED_CONTRACT}; "
-                f"{type(model).__name__} lacks {lacks} (GPT2LM/LlamaLM "
-                f"from interop/huggingface.py and OlmoHybridLM from "
-                f"interop/olmo_hybrid.py provide it)")
+                f"{type(model).__name__} lacks {lacks} (docs/serving.md, "
+                f"\"What a served model provides\")")
         self.name = name
         self.model = model
         self.params = params
@@ -434,6 +433,11 @@ class DecodeEntry:
                 f"decode model {name!r} carries no eos_id — pass "
                 f"eos_id= at registration")
         self.vocab_size = int(model.vocab_size)
+        # what the model counts on the device (`step_counters(caches)`,
+        # running int32 totals): a step sends them back behind its tokens
+        self.counter_names = tuple(getattr(model, "counter_names", ()))
+        # a model whose attention admits at most so many tokens a query
+        self.index_topk = getattr(model, "index_topk", None)
         # a model whose cache holds more than keys and values says which
         # leaves are resident by slot (leading axis num_slots: a recurrent
         # state) and not by block
@@ -487,7 +491,8 @@ class DecodeEntry:
         # pool_blocks x kv_block tokens, not slots x max_seq_len.
         import jax
         from bigdl_tpu.observe import memz as _memz
-        cache_specs = jax.eval_shape(self._raw_caches, params)
+        cache_specs = self._cache_specs = jax.eval_shape(self._raw_caches,
+                                                         params)
         what = (f"decode model {name!r} ({self.pool_blocks} KV "
                 f"blocks x {self.kv_block} tokens paged pool")
         # True at each leaf resident by slot, None where every leaf is KV
@@ -534,6 +539,7 @@ class DecodeEntry:
         kw_p = dict(kw_d)
         self._rep_sharding = None
         self._pool_sharding = None
+        self._cache_sh = None       # the caches' shardings, leaf by leaf
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             rep = NamedSharding(self.mesh, P())
@@ -545,12 +551,25 @@ class DecodeEntry:
             # device) — the pool is the one decode resident worth
             # splitting at real-chip scale.
             self._rep_sharding = rep
+            self._cache_sh = jax.tree.map(lambda _: rep, self._cache_specs)
             if self.kv_shard:
-                from bigdl_tpu.nn.attention import PAGED_POOL_BLOCK_AXIS
-                self._pool_sharding = NamedSharding(
-                    self.mesh, P(*[None] * PAGED_POOL_BLOCK_AXIS,
-                                 self._shard_axis))
-            cache_sh = self._cache_shardings()
+                # a leaf's block dimension is the one that grows with the
+                # pool, wherever the model's layout puts it; a leaf that
+                # has none (resident by slot, a count) is replicated
+                grown = jax.eval_shape(
+                    lambda p: self._raw_caches(p, self.pool_blocks + 1),
+                    self.params)
+                self._cache_sh = jax.tree.map(
+                    lambda a, b: next(
+                        (NamedSharding(self.mesh, P(*[None] * i,
+                                                    self._shard_axis))
+                         for i in range(len(a.shape))
+                         if a.shape[i] != b.shape[i]), rep),
+                    self._cache_specs, grown)
+                self._pool_sharding = next(
+                    sh for sh in jax.tree.leaves(self._cache_sh)
+                    if sh is not rep)
+            cache_sh = self._cache_sh
             # in_shardings as a per-argument prefix pytree: the cache
             # subtree takes the pool sharding, everything else is
             # replicated. Argument layouts (see the programs below):
@@ -578,11 +597,19 @@ class DecodeEntry:
             def choose(logits, pos):
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+        # (a closure that named `self` would tie the entry, and the arrays
+        # it holds, into a cycle only the collector frees)
+        counted = bool(self.counter_names)
+
         def _step(p, c, t, pos, a, bt, *samp):
             x, c = model.paged_hidden(
                 p, c, t[:, None], pos[:, None], bt, a.astype(jnp.int32),
                 decode=True)
-            return choose(model.head_logits(p, x), pos, *samp), c
+            nxt = choose(model.head_logits(p, x), pos, *samp)
+            if counted:
+                nxt = jnp.concatenate(
+                    [nxt, model.step_counters(c).astype(jnp.int32)])
+            return nxt, c
 
         def _prefill(p, c, t, pos, bt, ln):
             # lengths masks the rounded-up bucket's padded tail (and
@@ -616,9 +643,10 @@ class DecodeEntry:
         kw_m = ({} if self._rep_sharding is None else
                 {"in_shardings": (self._rep_sharding,) * 3,
                  "out_shardings": self._rep_sharding})
+        S = self.num_slots
         self._jit_merge = jax.jit(
-            lambda use_prev, prev, host: jax.numpy.where(use_prev, prev,
-                                                         host), **kw_m)
+            lambda use_prev, prev, host: jnp.where(
+                use_prev, prev[:S] if counted else prev, host), **kw_m)
 
     def _place(self, a):
         """`a` where the programs take it; an array already on the device
@@ -636,25 +664,14 @@ class DecodeEntry:
             self._placed = jax.tree.map(self._place, self.params)
         return self._placed
 
-    def _raw_caches(self, params):
-        """The model's zero cache pytree for this registration: the paged
-        block pool with, for a model that has one, its state resident by
-        slot."""
+    def _raw_caches(self, params, pool_blocks: Optional[int] = None):
+        """The model's zero cache pytree for this registration: the pools
+        of blocks (of whatever shapes the model lays out) with, for a model
+        that has one, its state resident by slot."""
         by_slot = {"num_slots": self.num_slots} if self.slot_state else {}
         return self.model.make_paged_slot_caches(
-            params, self.pool_blocks, self.kv_block, **by_slot)
-
-    def _cache_shardings(self):
-        """The caches' sharding under a mesh, as a prefix of their pytree:
-        the pool's block-dim sharding under kv_shard, replicated otherwise;
-        leaves resident by slot are always replicated."""
-        pool = self._pool_sharding or self._rep_sharding
-        if self._slot_mask is None or pool is None:
-            return pool
-        import jax
-        return jax.tree.map(
-            lambda by_slot: self._rep_sharding if by_slot else pool,
-            self._slot_mask)
+            params, pool_blocks or self.pool_blocks, self.kv_block,
+            **by_slot)
 
     def split_caches(self, caches):
         """(the leaves pooled by block, the leaves resident by slot)."""
@@ -669,10 +686,9 @@ class DecodeEntry:
     def make_caches(self):
         """The persistent cache pytree (zeros, placed)."""
         caches = self._raw_caches(self.params)
-        sh = self._cache_shardings()
-        if sh is not None:
+        if self._cache_sh is not None:
             import jax
-            caches = jax.device_put(caches, sh)
+            caches = jax.device_put(caches, self._cache_sh)
         return caches
 
     # --------------------------------------------------------------- AOT
@@ -696,15 +712,13 @@ class DecodeEntry:
 
         p_s = jax.tree.map(lambda a: spec(tuple(a.shape), a.dtype),
                            self.params)
-        raw = jax.eval_shape(self._raw_caches, self.params)
-        sh = self._cache_shardings()
-        if sh is None or self._slot_mask is None:
-            c_s = jax.tree.map(
-                lambda a: spec(tuple(a.shape), a.dtype, sharding=sh), raw)
+        raw = self._cache_specs
+        if self._cache_sh is None:
+            c_s = jax.tree.map(lambda a: spec(tuple(a.shape), a.dtype), raw)
         else:
             c_s = jax.tree.map(
-                lambda a, s: spec(tuple(a.shape), a.dtype, sharding=s),
-                raw, sh)
+                lambda a, sh: spec(tuple(a.shape), a.dtype, sharding=sh),
+                raw, self._cache_sh)
         S, M = self.num_slots, self.blocks_per_slot
         i32 = np.dtype(np.int32)
         f32 = np.dtype(np.float32)
@@ -738,7 +752,8 @@ class DecodeEntry:
         self._assert_pool_sharding(self._aot_prefill_all)
         results[f"prefill{C}x{S}"] = cost
         cost, self._aot_merge = precompile_fixed(
-            self._jit_merge, (act, vec, vec),
+            self._jit_merge,
+            (act, spec((S + len(self.counter_names),), i32), vec),
             name=f"serve/{self.name}/decode/merge")
         results["merge"] = cost
         return results
@@ -764,15 +779,14 @@ class DecodeEntry:
         if self._pool_sharding is None or exe is None:
             return
         import jax
-        want = self._pool_sharding.spec
-        flat = jax.tree.leaves(exe.input_shardings[0])
-        got = [s for s in flat
-               if getattr(s, "spec", None) == want]
-        if not got:
-            raise RuntimeError(
-                f"serve[{self.name}]: kv_shard pool sharding {want} "
-                f"absent from the AOT executable's input shardings — "
-                f"GSPMD dropped the block-dim partition")
+        have = {getattr(s, "spec", None)
+                for s in jax.tree.leaves(exe.input_shardings[0])}
+        for sh in jax.tree.leaves(self._cache_sh):
+            if sh is not self._rep_sharding and sh.spec not in have:
+                raise RuntimeError(
+                    f"serve[{self.name}]: kv_shard pool sharding {sh.spec} "
+                    f"absent from the AOT executable's input shardings — "
+                    f"GSPMD dropped the block-dim partition")
 
     # ------------------------------------------------------------ device
     def run_prefill(self, caches, tokens: np.ndarray, positions, table,
@@ -955,10 +969,12 @@ class _Step(NamedTuple):
     """A decode step that is enqueued and whose tokens are not fetched yet:
     the device array of next tokens, the requests it computes a row for
     (matched by identity when the tokens arrive: a slot may have changed
-    hands meanwhile) and when it was dispatched."""
+    hands meanwhile), when it was dispatched and the longest context
+    (tokens a row may attend) among its rows."""
     nxt: object
     rows: List[_GenRequest]
     t_dispatch: float
+    context: int
 
 
 class DecodeScheduler:
@@ -1104,6 +1120,20 @@ class DecodeScheduler:
             f"serve/{n}/decode/prefill_rows")
         self._m_state_resets = observe.counter(
             f"serve/{n}/decode/state_resets")
+        # over every query token a program computed: the tokens it may
+        # attend (those before it and itself), and how many of them its
+        # attention admits (all, but for a model with a sparse selection)
+        self._m_context = observe.counter(
+            f"serve/{n}/decode/context_tokens")
+        self._m_attended = observe.counter(
+            f"serve/{n}/decode/attended_tokens")
+        # `context_tokens` of the decode steps' rows alone
+        self._m_step_context = observe.counter(
+            f"serve/{n}/decode/step_context_tokens")
+        # what the model counts on the device, fetched with a step's tokens
+        self._m_model = [observe.counter(f"serve/{n}/decode/{c}")
+                         for c in entry.counter_names]
+        self._model_counts = [0] * len(self._m_model)
         self._win_t0 = self._clock()
         self._win_tokens = 0
         if start:
@@ -1258,10 +1288,10 @@ class DecodeScheduler:
         `last_pos` (lock held) — the lazy frontier-crossing acquisition;
         the admission reservation guarantees success."""
         row = self._tables[req.slot]
-        for j in range(last_pos // self.entry.kv_block + 1):
-            if row[j] < 0:
-                row[j] = self._pool.acquire_reserved()
-                req.reserved -= 1
+        for j in np.flatnonzero(row[:last_pos // self.entry.kv_block + 1]
+                                < 0):
+            row[j] = self._pool.acquire_reserved()
+            req.reserved -= 1
 
     def _release_blocks(self, req: _GenRequest) -> None:
         """Return a leaving request's blocks (takes the lock): shared /
@@ -1344,6 +1374,15 @@ class DecodeScheduler:
                 self._prefill_call(C, reqs, by_row=False)
         return len(pending)
 
+    def _count_context(self, contexts: np.ndarray) -> None:
+        """`contexts`: of each query token a call computes, the tokens it
+        may attend (its position + 1)."""
+        self._m_context.inc(int(contexts.sum()))
+        topk = self.entry.index_topk
+        self._m_attended.inc(int(
+            contexts.sum() if topk is None
+            else np.minimum(contexts, topk).sum()))
+
     def _prefill_call(self, C: int, reqs: List[_GenRequest],
                       by_row: bool) -> None:
         """One prefill program call that advances `reqs` by a chunk of
@@ -1365,9 +1404,12 @@ class DecodeScheduler:
                 self._ensure_blocks(req, req.fed + int(lengths[i]) - 1)
             table = self._tables[slots] if by_row else self._tables.copy()
         t0 = self._clock()
+        # of each valid query token, the tokens it may attend
+        contexts = (positions + 1)[np.arange(C) < lengths[:, None]]
         with observe.span("serve/decode/prefill", cat="serve",
                           args={"model": self.name, "chunk": C,
                                 "slots": len(reqs), "rows": R,
+                                "context": int(contexts.max()),
                                 "state": self.entry.state_kind}):
             # lengths masks the rounded-up bucket's padded tail (and
             # inactive rows) out of the pool scatter
@@ -1378,6 +1420,7 @@ class DecodeScheduler:
         self._m_prefill_calls.inc()
         self._m_prefill_rows.inc(R)
         self._m_prefill_tokens.inc(int(lengths.sum()))
+        self._count_context(contexts)
         for req in reqs:
             req.fed += min(req.prefill_target - req.fed, C)
             if self._prefix is not None:
@@ -1404,10 +1447,14 @@ class DecodeScheduler:
         then fetch and deliver the step that was in flight, which the
         device has been running meanwhile."""
         prev = self._in_flight
-        with observe.span("serve/decode/step", cat="serve",
-                          args={"model": self.name,
-                                "state": self.entry.state_kind}):
+        # `rows` and `context` (the longest of the step enqueued) are known
+        # once it is dispatched; the span keeps the dict it was given
+        args = {"model": self.name, "state": self.entry.state_kind}
+        with observe.span("serve/decode/step", cat="serve", args=args):
             self._in_flight = self._dispatch_step(prev)
+            if self._in_flight is not None:
+                args["rows"] = len(self._in_flight.rows)
+                args["context"] = self._in_flight.context
             if prev is not None:
                 self._deliver(prev)
         step = prev or self._in_flight
@@ -1463,7 +1510,10 @@ class DecodeScheduler:
             self._caches, tokens, positions, active, *extra)
         if prev is not None:
             self._m_steps_ahead.inc()
-        return _Step(nxt, rows, t0)
+        contexts = positions[active] + 1
+        self._count_context(contexts)
+        self._m_step_context.inc(int(contexts.sum()))
+        return _Step(nxt, rows, t0, int(contexts.max()))
 
     def _deliver(self, step: _Step) -> None:
         """Fetch a step's tokens (the iteration's single host sync), push
@@ -1480,6 +1530,11 @@ class DecodeScheduler:
         self._h_step.record(
             max(0.0, (now - max(step.t_dispatch, self._t_fetched)) * 1e3))
         self._t_fetched = now
+        for i, m in enumerate(self._m_model):
+            # running int32 totals of the device, which wrap
+            total = int(nxt[self.entry.num_slots + i])
+            m.inc((total - self._model_counts[i]) & 0xFFFFFFFF)
+            self._model_counts[i] = total
         live = [r for r in step.rows if self._slots[r.slot] is r]
         self._m_rows_dropped.inc(len(step.rows) - len(live))
         self._h_occ.record(len(step.rows) / self.entry.num_slots)
@@ -1758,6 +1813,11 @@ class DecodeScheduler:
             "prefill_tokens": int(self._m_prefill_tokens.value),
             "prefill_calls": int(self._m_prefill_calls.value),
             "prefill_rows": int(self._m_prefill_rows.value),
+            "context_tokens": int(self._m_context.value),
+            "attended_tokens": int(self._m_attended.value),
+            "step_context_tokens": int(self._m_step_context.value),
+            **{c: int(m.value) for c, m in zip(self.entry.counter_names,
+                                               self._m_model)},
             "kv_block": self.entry.kv_block,
             "kv_blocks_total": pool.total,
             "kv_blocks_free": pool.free,
