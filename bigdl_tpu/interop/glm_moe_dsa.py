@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu.core import init as initializers
 from bigdl_tpu.core.module import Module, ParamSpec
+from bigdl_tpu.nn.attention import carried_rows, join_rows
 from bigdl_tpu.nn.experts import GatedExperts
 from bigdl_tpu.nn.latent_attention import SparseLatentAttention
 from bigdl_tpu.nn.linear import Linear
@@ -192,24 +193,36 @@ class GlmMoeDsaLM(Module):
         return caches[-1]
 
     def paged_hidden(self, params, caches, tokens, positions, block_table,
-                     lengths, decode=False):
+                     lengths, decode=False, chunk=None):
         """Hidden states of one chunk a slot: tokens/positions (S, C)
         int32, block_table (S, M) int32, lengths (S,) int32 = valid
         leading tokens a row (0 = inactive). `decode` says the chunk is a
         step's one token: attention then gathers the rows it selects, and
         else attends a slot's context under a mask (nn/latent_attention.py).
-        Returns (x (S, C, d), the new caches)."""
+        `chunk` (the optional carrying form, nn/attention.carried_rows) is a
+        prompt chunk of streaming slots that the same pass computes: every
+        product and the experts read their weights once for both, attention
+        takes each in its own form. Returns (x (S, C, d), the new caches)."""
+        def valid_of(tokens, lengths):
+            return jnp.arange(tokens.shape[1])[None, :] < lengths[:, None]
+        S = tokens.shape[0]
+        valid = valid_of(tokens, lengths)
+        if chunk is not None:
+            valid = join_rows([valid_of(chunk[0], chunk[3]), valid])
+        tokens, positions, parts = carried_rows(
+            tokens, positions, block_table, lengths, decode, chunk)
         x = params["embed"][tokens]
-        valid = jnp.arange(tokens.shape[1])[None, :] < lengths[:, None]
         new, selection = [], None
         counts = jnp.zeros((2,), jnp.int32)
         for (name, blk), pools in zip(self._blocks(), caches):
             mixed, pools, selection = blk.children()["attn"].paged_step(
                 params[name]["attn"], blk.normed(params[name], x), pools,
-                positions, block_table, lengths, selection, decode)
+                positions, block_table, lengths, selection, decode, parts)
             x, n = blk.rest(params[name], x, mixed, valid)
             counts += n
             new.append(pools)
+        if parts:
+            x = x[0, -S:, None]         # the step's rows, one token each
         through = jnp.sum(valid, dtype=jnp.int32) \
             * self.mlp_layer_types.count(SPARSE)
         return x, tuple(new) + (

@@ -219,21 +219,30 @@ class GPT2LM(Module):
                      for _ in range(self.num_layers))
 
     def paged_hidden(self, params, caches, tokens, positions,
-                     block_table, lengths, decode=False):
+                     block_table, lengths, decode=False, chunk=None):
         """Hidden states of one chunk a slot, its K/V written into the
         pool: tokens/positions (S, C) int32, block_table (S, M) int32
         (-1 = unacquired), lengths (S,) int32 = VALID leading tokens per
         row (0 = inactive; padded tail tokens of a rounded-up bucket are
         dropped, not written). `decode` (the one-token step, not a
-        prompt chunk) changes nothing here. Returns (x (S, C, d), the
-        new pool caches)."""
+        prompt chunk) changes nothing here. `chunk` (the optional carrying
+        form, nn/attention.carried_rows) is a prompt chunk of streaming slots
+        that the same pass writes: every product reads its weights once
+        for both. Returns (x (S, C, d), the new pool caches)."""
+        from bigdl_tpu.nn.attention import carried_rows
+        S = tokens.shape[0]
+        tokens, positions, parts = carried_rows(
+            tokens, positions, block_table, lengths, decode, chunk)
         pos = jnp.clip(positions, 0, self.n_positions - 1)
         x = params["wte"][tokens] + params["wpe"][pos]
         pools = []
         for i in range(self.num_layers):
             x, pool = self.children()[f"h{i}"].paged_slot_cached_step(
-                params[f"h{i}"], x, caches[i], pos, block_table, lengths)
+                params[f"h{i}"], x, caches[i], pos, block_table, lengths,
+                parts)
             pools.append(pool)
+        if parts:
+            x = x[0, -S:, None]         # the step's rows, one token each
         return x, tuple(pools)
 
     def head_logits(self, params, x):
@@ -539,7 +548,7 @@ class LlamaBlock(Module):
         return x + dn, ck, cv
 
     def paged_slot_cached_step(self, params, x, kv_pool, positions,
-                               block_table, lengths):
+                               block_table, lengths, parts=None):
         """`cached_step` over a slot batch with PER-ROW positions (N, T)
         int32 against a PAGED grouped-KV pool
         (nn/attention.paged_slot_cached_attend): RoPE angles and the
@@ -547,8 +556,10 @@ class LlamaBlock(Module):
         at its own offset; K/V are written into pool blocks through the
         slot's block table, the grouped query heads attending to the pool
         where it lies. Per row the same lanes as cached_step with the
-        matching scalar start."""
+        matching scalar start. With `parts` (nn/attention.carried_rows) x
+        and positions are their joined tokens, attention part by part."""
         from bigdl_tpu.nn.attention import (rotary_embedding,
+                                            paged_parts_attend,
                                             paged_slot_cached_attend)
         c = self.children()
         attn = c["attn"]
@@ -569,8 +580,12 @@ class LlamaBlock(Module):
                              positions)
         k = rotary_embedding(k.transpose(0, 2, 1, 3), attn.rope_theta,
                              positions).transpose(0, 2, 1, 3)
-        a, kv_pool = paged_slot_cached_attend(
-            q, k, v, kv_pool, positions, block_table, lengths)
+        if parts is None:
+            a, kv_pool = paged_slot_cached_attend(
+                q, k, v, kv_pool, positions, block_table, lengths)
+        else:
+            a, kv_pool = paged_parts_attend(q.transpose(0, 2, 1, 3), k, v,
+                                            kv_pool, parts)
         x = x + a @ at["wo"]
         h, _ = c["ln2"].apply(params["ln2"], {}, x)
         g, _ = c["gate"].apply(params["gate"], {}, h)
@@ -689,16 +704,22 @@ class LlamaLM(Module):
                      for _ in range(self.num_layers))
 
     def paged_hidden(self, params, caches, tokens, positions,
-                     block_table, lengths, decode=False):
+                     block_table, lengths, decode=False, chunk=None):
         """Hidden states of one chunk a slot against the paged grouped-KV
-        pool (see GPT2LM.paged_hidden — same contract)."""
+        pool (see GPT2LM.paged_hidden — same contract, `chunk` too)."""
+        from bigdl_tpu.nn.attention import carried_rows
+        S = tokens.shape[0]
+        tokens, positions, parts = carried_rows(
+            tokens, positions, block_table, lengths, decode, chunk)
         x = params["embed"][tokens]
         pools = []
         for i in range(self.num_layers):
             x, pool = self.children()[f"l{i}"].paged_slot_cached_step(
                 params[f"l{i}"], x, caches[i], positions, block_table,
-                lengths)
+                lengths, parts)
             pools.append(pool)
+        if parts:
+            x = x[0, -S:, None]         # the step's rows, one token each
         return x, tuple(pools)
 
     def head_logits(self, params, x):

@@ -27,8 +27,9 @@ import jax.numpy as jnp
 
 from bigdl_tpu.core import init as initializers
 from bigdl_tpu.core.module import Module, ParamSpec
-from bigdl_tpu.nn.attention import (causal_mask, dot_product_attention,
-                                    make_paged_kv_pool,
+from bigdl_tpu.nn.attention import (carried_rows, causal_mask,
+                                    dot_product_attention,
+                                    make_paged_kv_pool, paged_parts_attend,
                                     paged_slot_cached_attend)
 from bigdl_tpu.nn.linear import Linear
 from bigdl_tpu.nn.linear_attention import GatedDeltaNet
@@ -75,13 +76,19 @@ class QKNormAttention(Module):
         return self.children()["o"].apply(params["o"], {}, a)[0], state
 
     def paged_step(self, params, x, kv_pool, positions, block_table,
-                   lengths):
+                   lengths, parts=None):
         """The chunk's K/V into the slot's pool blocks and attention over
         the pool where it lies (nn/attention.paged_slot_cached_attend).
-        Returns (out (N, T, d), new pool)."""
+        With `parts` (nn/attention.carried_rows) x is their joined tokens
+        and attention goes part by part. Returns (out (N, T, d), new
+        pool)."""
         q, k, v = self._qkv(params, x)
-        a, kv_pool = paged_slot_cached_attend(
-            q, k, v, kv_pool, positions, block_table, lengths)
+        if parts is None:
+            a, kv_pool = paged_slot_cached_attend(
+                q, k, v, kv_pool, positions, block_table, lengths)
+        else:
+            a, kv_pool = paged_parts_attend(q.transpose(0, 2, 1, 3), k, v,
+                                            kv_pool, parts)
         return self.children()["o"].apply(params["o"], {}, a)[0], kv_pool
 
 
@@ -195,20 +202,28 @@ class OlmoHybridLM(Module):
                      for (_, blk), c in zip(self._blocks(), caches))
 
     def paged_hidden(self, params, caches, tokens, positions, block_table,
-                     lengths, decode=False):
+                     lengths, decode=False, chunk=None):
         """Hidden states of one chunk a slot: tokens/positions (S, C)
         int32, block_table (S, M) int32, lengths (S,) int32 = valid
         leading tokens a row (0 = inactive). `decode` says the chunk is a
         step's one token: the linear layers then take their recurrence and
-        not the chunk form, which a one-token prompt chunk keeps. Returns
-        (x (S, C, d), the new caches)."""
+        not the chunk form, which a one-token prompt chunk keeps. `chunk`
+        (the optional carrying form, nn/attention.carried_rows) is a prompt
+        chunk of streaming slots that the same pass computes: every product
+        reads its weights once for both, each linear layer runs both of its
+        forms. Returns (x (S, C, d), the new caches)."""
+        S = tokens.shape[0]
+        tokens, positions, parts = carried_rows(
+            tokens, positions, block_table, lengths, decode, chunk)
         x = params["embed"][tokens]
         new = []
         for (name, blk), cache in zip(self._blocks(), caches):
             mixer, p = blk.children()["mixer"], params[name]["mixer"]
             if blk.kind == FULL:
                 mixed, cache = mixer.paged_step(
-                    p, x, cache, positions, block_table, lengths)
+                    p, x, cache, positions, block_table, lengths, parts)
+            elif parts:
+                mixed, cache = mixer.parts_step(p, x, cache, parts)
             elif decode:
                 mixed, cache = mixer.decode_step(
                     p, x, cache, positions[:, 0], lengths > 0)
@@ -217,6 +232,8 @@ class OlmoHybridLM(Module):
                     p, x, cache, positions, lengths)
             x = blk._rest(params[name], x, mixed)
             new.append(cache)
+        if parts:
+            x = x[0, -S:, None]         # the step's rows, one token each
         return x, tuple(new)
 
     def head_logits(self, params, x):

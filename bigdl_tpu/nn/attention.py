@@ -19,7 +19,7 @@ TPU-first design:
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -276,10 +276,22 @@ def paged_slot_cached_attend(q_heads, k_chunk, v_chunk, kv_pool, positions,
     grouped divisor (GQA: the H/Hc query heads of a group attend to its
     one KV head without a repeated copy of it). Returns ((N, T, H*hd),
     new_kv_pool)."""
-    N, H, T, hd = q_heads.shape
-    Hc, P, B, _ = kv_pool.shape
+    kv_pool = _paged_write(k_chunk, v_chunk, kv_pool, positions, block_table,
+                           lengths)
+    return _paged_attend(q_heads, kv_pool, positions, block_table), kv_pool
+
+
+# (jitted by themselves: a model calls them once a layer with the same
+# shapes, and a traced call of a jitted function is one equation of the
+# caller's program, found again in the cache, where the index arithmetic
+# written out is a few hundred; the compiler inlines the calls. A 48-layer
+# program traces in half the time: set-up pays for that a program.)
+@jax.jit
+def _paged_write(k_chunk, v_chunk, kv_pool, positions, block_table, lengths):
+    """The write of `paged_slot_cached_attend`: -> the new pool."""
+    N, T, Hc, hd = k_chunk.shape
+    _, P, B, _ = kv_pool.shape
     M = block_table.shape[1]
-    # -- write the chunk into the blocks it straddles ------------------
     nb = -(-(T - 1) // B) + 1
     start = positions[:, 0]
     m = start[:, None] // B + jnp.arange(nb)                    # (N, nb)
@@ -295,10 +307,18 @@ def paged_slot_cached_attend(q_heads, k_chunk, v_chunk, kv_pool, positions,
         kv, jnp.clip(t, 0, T - 1).reshape(N, nb * B, 1, 1), axis=1)
     new = new.reshape(N * nb, B, Hc, 2 * hd).transpose(2, 0, 1, 3)
     old = kv_pool[:, jnp.clip(ids, 0, P - 1)]             # (Hc, N*nb, B, 2hd)
-    kv_pool = kv_pool.at[:, ids].set(
+    return kv_pool.at[:, ids].set(
         jnp.where(live.reshape(N * nb, B)[None, :, :, None], new, old),
         mode="drop")
-    # -- attend over the pool under the ownership mask -----------------
+
+
+@jax.jit
+def _paged_attend(q_heads, kv_pool, positions, block_table):
+    """The read of `paged_slot_cached_attend`: every query over the whole
+    pool under the ownership mask. -> (N, T, H*hd)."""
+    N, H, T, hd = q_heads.shape
+    Hc, P, B, _ = kv_pool.shape
+    M = block_table.shape[1]
     owns = block_table[:, :, None] == jnp.arange(P)             # (N, M, P)
     logical = jnp.max(jnp.where(owns, jnp.arange(M)[None, :, None], -1),
                       axis=1)                                   # (N, P)
@@ -311,8 +331,82 @@ def paged_slot_cached_attend(q_heads, k_chunk, v_chunk, kv_pool, positions,
     lanes = kv_pool.reshape(Hc, 1, P * B, 2 * hd)
     a = dot_product_attention(q_heads.reshape(N, Hc, H // Hc, T, hd),
                               lanes[..., :hd], lanes[..., hd:], mask)
-    a = a.reshape(N, H, T, hd).transpose(0, 2, 1, 3).reshape(N, T, H * hd)
-    return a, kv_pool
+    return a.reshape(N, H, T, hd).transpose(0, 2, 1, 3).reshape(N, T, H * hd)
+
+
+class SlotRows(NamedTuple):
+    """The rows of one shape in a pass over a slot batch: `positions` (N, T)
+    and `lengths` (N,) as `paged_slot_cached_attend` takes them,
+    `block_table` (N, M) their slots' rows of it, `decode` whether they are
+    a step's one token, `slots` (N,) the slots they belong to where that is
+    not "row i is slot i" (a leaf resident by slot is read and written at
+    those rows alone)."""
+    positions: Any
+    block_table: Any
+    lengths: Any
+    decode: bool = False
+    slots: Optional[Any] = None
+
+
+def carried_rows(tokens, positions, block_table, lengths, decode, chunk):
+    """A step that carries a prompt chunk (serve/decode.py): the chunk's
+    `chunk = (tokens (R, C), positions (R, C), block_table (R, M), lengths
+    (R,), slots (R,))` then the step's rows (S, 1), their tokens one after
+    another as ONE row, so that everything a model computes token by token
+    (norms, projections, the MLP or the experts) is one product over both
+    and reads its weights once; what is computed row by row (attention over
+    the pool, a recurrent state) takes its tokens back out part by part
+    (`split_rows`), in this order: a part sees what the parts before it
+    wrote, so a step's row may be the token that follows the chunk in the
+    same slot (a prompt's last token behind its last chunk). Returns
+    (tokens (1, n), positions (1, n), parts); the step's rows are the last
+    S of the n. Without a chunk: (tokens, positions, None)."""
+    if chunk is None:
+        return tokens, positions, None
+    c_tokens, c_positions, c_table, c_lengths, c_slots = chunk
+    parts = (SlotRows(c_positions, c_table, c_lengths, False, c_slots),
+             SlotRows(positions, block_table, lengths, decode))
+    return (join_rows([c_tokens, tokens]),
+            join_rows([c_positions, positions]), parts)
+
+
+def join_rows(arrays):
+    """[(N_i, T_i, ...)] -> (1, sum N_i T_i, ...): the parts' tokens one
+    after another."""
+    return jnp.concatenate(
+        [a.reshape((1, -1) + a.shape[2:]) for a in arrays], axis=1)
+
+
+def split_rows(parts, *joined):
+    """The inverse of `join_rows`, array by array: -> for each part the
+    tuple of its (N, T, ...) share of every `joined` (1, n, ...)."""
+    out, at = [], 0
+    for part in parts:
+        N, T = part.positions.shape
+        out.append(tuple(a[0, at:at + N * T].reshape((N, T) + a.shape[2:])
+                         for a in joined))
+        at += N * T
+    return out
+
+
+def paged_parts_attend(q, k, v, kv_pool, parts):
+    """`paged_slot_cached_attend` over joined rows: q (1, n, H, hd), k, v
+    (1, n, Hc, hd) -> ((1, n, H*hd), new_kv_pool). The parts write one
+    after another (a later part's window may be a block an earlier part
+    has just written: the same slot's next token), nothing reading the
+    pool in between, so it stays where it lies. Then every token attends
+    the pool as a row of its own (its position, its slot's row of the
+    block table), all parts in one pass over the pool, in which another
+    slot's new lanes stay masked."""
+    for part, (k_, v_) in zip(parts, split_rows(parts, k, v)):
+        kv_pool = _paged_write(k_, v_, kv_pool, part.positions,
+                               part.block_table, part.lengths)
+    positions = jnp.concatenate([p.positions.reshape(-1, 1) for p in parts])
+    tables = jnp.concatenate([
+        jnp.repeat(p.block_table, p.positions.shape[1], axis=0)
+        for p in parts])
+    a = _paged_attend(q[0][:, :, None, :], kv_pool, positions, tables)
+    return a.reshape((1,) + a.shape[:1] + a.shape[2:]), kv_pool
 
 
 class MultiHeadAttention(Module):
@@ -527,15 +621,17 @@ class TransformerLayer(Module):
         return x + f, ck, cv
 
     def paged_slot_cached_step(self, params, x, kv_pool, positions,
-                               block_table, lengths):
+                               block_table, lengths, parts=None):
         """`cached_step` over a slot batch with PER-ROW positions (N, T)
         int32 against a PAGED KV pool: each row is an independent
         sequence at its own offset, the chunk's K/V are written into the
         pool's blocks through the slot's block table and attention reads
         the pool where it lies (paged_slot_cached_attend). Per row the
         same lanes are attended as by `cached_step` with the matching
-        scalar start, summed in pool order. Self-attention blocks only;
-        same custom-attn_impl refusal as cached_step."""
+        scalar start, summed in pool order. With `parts` (`carried_rows`)
+        x is their joined tokens (1, n, d) and attention goes part by
+        part. Self-attention blocks only; same custom-attn_impl refusal
+        as cached_step."""
         if self.cross:
             raise ValueError("paged_slot_cached_step supports self-"
                              "attention decoder blocks only")
@@ -554,11 +650,15 @@ class TransformerLayer(Module):
         v = h @ at["wv"]
         if self.attn.bias:
             q, k, v = q + at["bq"], k + at["bk"], v + at["bv"]
-        q = q.reshape(N, T, H, hd).transpose(0, 2, 1, 3)
+        q = q.reshape(N, T, H, hd)
         k = k.reshape(N, T, H, hd)
         v = v.reshape(N, T, H, hd)
-        a, kv_pool = paged_slot_cached_attend(
-            q, k, v, kv_pool, positions, block_table, lengths)
+        if parts is None:
+            a, kv_pool = paged_slot_cached_attend(
+                q.transpose(0, 2, 1, 3), k, v, kv_pool, positions,
+                block_table, lengths)
+        else:
+            a, kv_pool = paged_parts_attend(q, k, v, kv_pool, parts)
         a = a @ at["wo"]
         if self.attn.bias:
             a = a + at["bo"]

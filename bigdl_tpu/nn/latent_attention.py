@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from bigdl_tpu.core.module import Module
+from bigdl_tpu.nn.attention import SlotRows, join_rows, split_rows
 from bigdl_tpu.nn.linear import Linear
 from bigdl_tpu.nn.normalization import LayerNormalization, RMSNorm
 
@@ -142,6 +143,16 @@ def paged_pool_write(pool, chunk, positions, block_table, lengths):
     return pool.at[ids].set(
         jnp.where(live.reshape(N * nb, B, 1), new.reshape(N * nb, B, W),
                   old), mode="drop")
+
+
+def paged_pool_write_parts(pool, parts, chunks):
+    """`paged_pool_write` of every part's chunk (nn/attention.SlotRows),
+    one after another: a later part may write into a block an earlier one
+    has just written (the same slot's next token)."""
+    for part, chunk in zip(parts, chunks):
+        pool = paged_pool_write(pool, chunk, part.positions,
+                                part.block_table, part.lengths)
+    return pool
 
 
 def gather_context(pool, block_table):
@@ -394,49 +405,67 @@ class SparseLatentAttention(Module):
         return pools
 
     def paged_step(self, params, x, pools, positions, block_table, lengths,
-                   selection=None, decode=False):
+                   selection=None, decode=False, parts=None):
         """A chunk a slot against the pools: the chunk's rows are written
         through the block table, then every query attends what is admitted
         to it. `decode` says the chunk is a step's one token: the selection
         is then positions, whose rows are gathered, and else a mask over the
         slot's context. `selection` is a `shared` layer's, of the `full`
-        layer before it (which makes its own). Returns (out, pools,
+        layer before it (which makes its own). With `parts`
+        (nn/attention.carried_rows) x and positions are their joined
+        tokens: the projections are one product over all of them, the pools
+        are written once, and each part selects and attends in its own
+        form, `selection` being a tuple of theirs. Returns (out, pools,
         selection)."""
         N, T, _ = x.shape
         H = self.num_heads
+        joined = parts is not None
+        if joined:
+            share = lambda *a: split_rows(parts, *a)           # noqa: E731
+            selection = selection or (None,) * len(parts)
+        else:
+            share = lambda *a: [a]                             # noqa: E731
+            parts = (SlotRows(positions, block_table, lengths, decode),)
+            selection = (selection,)
         cq, q_c, q_r = self._queries(params, x, positions)
         width = self.row_width
-        latent = paged_pool_write(
-            pools["latent"],
-            _widened(self._latent(params, x, positions), width), positions,
-            block_table, lengths)
+        rows = _widened(self._latent(params, x, positions), width)
+        latent = paged_pool_write_parts(
+            pools["latent"], parts, [r for r, in share(rows)])
         new = {"latent": latent}
         w_uk, w_uv = self._up(params)
         q_abs = _widened(jnp.concatenate(
             [jnp.einsum("nthc,lhc->nthl", q_c, w_uk), q_r], axis=-1), width)
-        index = None
+        mine = share(q_abs)
         if self.indexer:
             # the chunk's keys into their pool, then the slots' keys in the
             # order of their positions
             q_idx, w, k_idx = self._index(params, x, cq, positions)
             lanes = pools["index"].shape[-1]
-            new["index"] = paged_pool_write(
-                pools["index"], _widened(k_idx, lanes), positions,
-                block_table, lengths)
-            index = (_widened(q_idx, lanes), w,
-                     gather_context(new["index"], block_table))
-        elif selection is None:
+            new["index"] = paged_pool_write_parts(
+                pools["index"], parts,
+                [k for k, in share(_widened(k_idx, lanes))])
+            mine = share(q_abs, _widened(q_idx, lanes), w)
+        elif None in selection:
             raise ValueError(_NEEDS_SELECTION)
-        if decode:
-            out, selection = self._step_rows(
-                q_abs, latent, index, positions, block_table, selection)
-        else:
-            out, selection = self._chunk_rows(
-                q_abs, latent, index, positions, block_table, lengths,
-                selection)
+        outs, selections = [], []
+        for part, given, (q_abs_, *scored) in zip(parts, selection, mine):
+            index = (*scored, gather_context(
+                new["index"], part.block_table)) if scored else None
+            if part.decode:
+                out, given = self._step_rows(
+                    q_abs_, latent, index, part.positions,
+                    part.block_table, given)
+            else:
+                out, given = self._chunk_rows(
+                    q_abs_, latent, index, part.positions,
+                    part.block_table, part.lengths, given)
+            outs.append(out)
+            selections.append(given)
+        out = join_rows(outs) if joined else outs[0]
         a = jnp.einsum("nthl,lhv->nthv", out, w_uv)
         return self._run(params, "o", a.reshape(N, T, H * self.v_dim)), \
-            new, selection
+            new, tuple(selections) if joined else selections[0]
 
     def _step_rows(self, q_abs, latent, index, positions, block_table,
                    selection):

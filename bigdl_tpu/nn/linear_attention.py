@@ -49,6 +49,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu.core import init as initializers
 from bigdl_tpu.core.module import Module, ParamSpec
+from bigdl_tpu.nn.attention import join_rows, split_rows
 from bigdl_tpu.nn.linear import Linear
 from bigdl_tpu.nn.normalization import RMSNorm
 
@@ -221,10 +222,16 @@ class GatedDeltaNet(Module):
         token past a row's length changes nothing, and the carried
         convolution inputs are the last valid ones. Returns (out (N, C, d),
         new state); a row of length 0 gets its state back bit for bit."""
-        C = x.shape[1]
+        o, new = self._chunk_form(params, *self._project(params, x),
+                                  carried, positions, lengths)
+        return self._finish(params, x, o), new
+
+    def _chunk_form(self, params, pre, beta, g, carried, positions, lengths):
+        """`prefill_step` between its projections and `_finish`: ->
+        (o (N, C, H, dv) float32, new state)."""
+        C = pre.shape[1]
         active = lengths > 0
         S, conv = _zero_rows(carried, active & (positions[:, 0] == 0))
-        pre, beta, g = self._project(params, x)
         valid = (jnp.arange(C) < lengths[:, None])[..., None]
         beta, g = jnp.where(valid, beta, 0.0), jnp.where(valid, g, 0.0)
         window = jnp.concatenate([conv, pre.astype(conv.dtype)], axis=1)
@@ -234,22 +241,53 @@ class GatedDeltaNet(Module):
         # window's rows lengths .. lengths+2
         at = lengths[:, None] + jnp.arange(self.conv_kernel - 1)
         conv = jnp.take_along_axis(window, at[..., None], axis=1)
-        return (self._finish(params, x, o),
-                _keep_idle_rows(active, S, conv, carried))
+        return o, _keep_idle_rows(active, S, conv, carried)
 
     def decode_step(self, params, x, carried, positions, active):
         """One token a slot, the recurrence as written: x (N, 1, d);
         positions (N,) int32; active (N,) bool. A row at position 0 starts
         from a zero state. Returns (out (N, 1, d), new state); inactive
         rows get their state back bit for bit."""
+        o, new = self._token_form(params, *self._project(params, x),
+                                  carried, positions, active)
+        return self._finish(params, x, o), new
+
+    def _token_form(self, params, pre, beta, g, carried, positions, active):
+        """`decode_step` between its projections and `_finish`: ->
+        (o (N, 1, H, dv) float32, new state)."""
         S, conv = _zero_rows(carried, active & (positions == 0))
-        pre, beta, g = self._project(params, x)
         window = jnp.concatenate([conv, pre.astype(conv.dtype)], axis=1)
         q, k, v = self._conv_qkv(params, window)
         S, o = gated_delta_step(S, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                                 beta[:, 0])
-        return (self._finish(params, x, o[:, None]),
-                _keep_idle_rows(active, S, window[:, 1:], carried))
+        return o[:, None], _keep_idle_rows(active, S, window[:, 1:], carried)
+
+    def parts_step(self, params, x, carried, parts):
+        """Rows of several shapes in one pass (nn/attention.carried_rows):
+        x (1, n, d) their joined tokens. The projections in and out are one
+        product over all of them; each part's tokens then go through its
+        own form, the recurrence (`decode`) or the chunk form, against the
+        state of its slots, `part.slots`' rows being taken out of `carried`
+        and put back in place. Returns (out (1, n, d), new state)."""
+        outs = []
+        for part, mine in zip(parts, split_rows(
+                parts, *self._project(params, x))):
+            own = carried if part.slots is None else jax.tree.map(
+                lambda a: a.at[part.slots].get(mode="promise_in_bounds"),
+                carried)
+            if part.decode:
+                o, own = self._token_form(params, *mine, own,
+                                          part.positions[:, 0],
+                                          part.lengths > 0)
+            else:
+                o, own = self._chunk_form(params, *mine, own,
+                                          part.positions, part.lengths)
+            carried = own if part.slots is None else jax.tree.map(
+                lambda a, new: a.at[part.slots].set(
+                    new, mode="promise_in_bounds", unique_indices=True),
+                carried, own)
+            outs.append(o)
+        return self._finish(params, x, join_rows(outs)), carried
 
 
 def _zero_rows(carried, fresh):

@@ -39,7 +39,24 @@ this codebase's discipline):
     its block-table row, its row of a state resident by slot, put back
     in place), and the full chunk keeps a `num_slots`-row program for
     the burst in which half the slots or more stream a full chunk in one
-    iteration (`DecodeEntry.prefill_rows`).
+    iteration (`DecodeEntry.prefill_rows`). A call of its own is what a
+    chunk costs only while nothing decodes: an iteration that dispatches
+    a decode step runs ONE program, the step, which CARRIES the chunk of
+    one streaming slot, the one admitted first (for each bucket of
+    `carried_buckets`, those of 64 tokens and more, a program `(step's
+    arguments, chunk_tokens (1, b), chunk_positions (1, b), chunk_table
+    (1, M), chunk_length (1,), chunk_slot (1,)) -> (next_tokens,
+    caches)`; a shorter chunk is padded up to the smallest carried bucket
+    and masked by its length). The step's rows and the chunk's tokens go
+    through every product of the model as one operand, so the weights,
+    which bound both programs, are read once and not twice; the chunk's
+    part goes first wherever rows are told apart, so the slot whose
+    prompt it completes is a row of the same step; every other
+    streaming slot keeps its place and rides a later step, first come
+    first served, so no token of a decoding slot waits behind a second
+    program, and two chunks cost two carrying steps (and two tokens a
+    decoding slot) where they cost a step and a call. A model without
+    the carrying form keeps the call beside the step.
   * **iteration-level scheduler** — clock-injectable (the batcher.py
     fake-clock testing discipline): every iteration first admits
     queued requests into free slots (prefill), then runs one fused step
@@ -51,7 +68,11 @@ this codebase's discipline):
     sync): a slot that continues feeds N+1 its own output of N on the
     device (a tiny merge program picks, row by row, that or the token
     the host knows), and position, block table and sampling arguments
-    never needed the token. So the fetch, the push to the streams,
+    never needed the token, nor does the prompt chunk the step carries
+    (a request whose last chunk rides step N is a decode row of step N
+    too, with its last prompt token, which the program computes behind
+    the chunk: its first token comes out of the carrying step). So the
+    fetch, the push to the streams,
     retirement, the gauges, the cancel sweep, admission and the next
     prefill chunks run beside the chip's step and not between two of
     them; device order (one stream, the donated caches threaded
@@ -62,7 +83,9 @@ this codebase's discipline):
     fetch (requests are matched by identity, a slot may have changed
     hands), its stray KV write lies inside its own reservation, in
     blocks any later owner overwrites by programs enqueued after it,
-    and a recurrent state restarts at position 0. The tokens are those
+    and a recurrent state restarts at position 0. A slot cancelled
+    while its chunk rides the step in flight is the same case: the
+    chunk's write lies in blocks it had reserved. The tokens are those
     of the serial loop, bit for bit. What a step in flight costs is
     paid by a request that arrives: its prefill queues behind every
     step already enqueued. So while a slot is free and nobody is
@@ -79,9 +102,12 @@ over a slot batch (`paged_hidden`) and its final norm and head
 (`head_logits`), plus `vocab_size`, (default) `eos_id` and, where the
 cache holds more than keys and values, `slot_resident`. The prefill
 program, the step and the choice of token (argmax or nn/sampling.py)
-are composed from them in `DecodeEntry._build`. GPT2LM and LlamaLM
-(interop/huggingface.py) and OlmoHybridLM (interop/olmo_hybrid.py)
-provide it.
+are composed from them in `DecodeEntry._build`. One optional argument,
+`paged_hidden(..., chunk=)`, is the carrying form: the hidden states of
+a step's rows with a prompt chunk of streaming slots computed in the same
+pass (nn/attention.carried_rows). GPT2LM and LlamaLM
+(interop/huggingface.py), OlmoHybridLM (interop/olmo_hybrid.py) and
+GlmMoeDsaLM (interop/glm_moe_dsa.py) provide all of it.
 
 On top of the block table sits the **prefix cache**: whole prompt
 blocks finished by prefill are published under a chained token-hash
@@ -103,17 +129,21 @@ prefill_ms, step_ms, queue_wait_ms, latency_ms, ttft_ms}` + counters
 (`steps`; `steps_ahead`, the steps enqueued while the one before them
 was unfetched; `rows_dropped`, the rows computed for a sequence that
 had ended by value or been cancelled; `prefill_tokens`, `prefill_calls`
-and `prefill_rows`, the rows those calls computed, streaming or not),
+and `prefill_rows`, the chunk advances, the rows they computed, streaming
+or not, and the valid tokens they wrote, whether the advance was a call
+or rode a step; `prefill_carried`, those that rode a step),
 a `decode` section in /statusz,
 per-peer decode rows in /fleetz, and the ServeWatchdog pointed at
 decode latency p99 with queue-vs-prefill-vs-step attribution
 (observe/doctor.py). `step_ms` is what one iteration costs a token:
 from a step's dispatch, or from the fetch before it where that came
-later (a step was in flight), to its own fetch's return.
+later (a step was in flight), to its own fetch's return; `prefill_ms`
+times the calls alone (a carried chunk's time is its step's).
 """
 
 from __future__ import annotations
 
+import inspect
 import logging
 import queue as _queue
 import threading
@@ -362,6 +392,18 @@ def prefill_buckets(chunk: int) -> Tuple[int, ...]:
     return tuple(sorted(set(out)))
 
 
+def carried_buckets(buckets: Tuple[int, ...]) -> Tuple[int, ...]:
+    """The buckets of the ladder a decode step carries a prompt chunk at:
+    those of 64 tokens and more (the full chunk alone where it is shorter);
+    a shorter chunk is padded up to the smallest of them and masked by its
+    length. Set-up pays for each program (~3 s a program of 48 layers from
+    a warm cache), and under 64 tokens beside a step's rows every product
+    is bound by reading its weights, so a shorter bucket buys the chunk's
+    attention alone: carrying steps of 8 | 16 | 32 | 64 tokens take 12.42 |
+    12.43 | 12.49 | 13.18 ms in GPT-2 XL on a v5e (PERF.md section 5)."""
+    return tuple(b for b in buckets if b >= min(64, buckets[-1]))
+
+
 class DecodeEntry:
     """One decode-served model: its paged KV pool (with, for a model
     that has one, its state resident by slot), AOT prefill + decode
@@ -426,6 +468,14 @@ class DecodeEntry:
                 f"position table)")
         self.prefill_chunk = min(self.prefill_chunk, self.max_seq_len)
         self.buckets = prefill_buckets(self.prefill_chunk)
+        # the optional carrying form (`paged_hidden(..., chunk=)`): a decode
+        # step then advances a streaming slot by a prompt chunk in the same
+        # pass over the weights, at these buckets; () keeps the two-call
+        # iteration
+        self.carried = (
+            carried_buckets(self.buckets)
+            if "chunk" in inspect.signature(model.paged_hidden).parameters
+            else ())
         self.eos_id = (eos_id if eos_id is not None
                        else getattr(model, "eos_id", None))
         if self.eos_id is None:
@@ -517,10 +567,12 @@ class DecodeEntry:
             f"{what} = {self.kv_cache_bytes:,} bytes + {to_place:,} bytes "
             f"of params yet to be placed)")
         self._jit_decode = None
+        self._jit_carry = None
         self._jit_prefill = None
         self._jit_prefill_rows = None
         self._jit_merge = None
         self._aot_decode = None
+        self._aot_carry: Dict[int, object] = {}   # bucket -> carrying step
         self._aot_merge = None
         # bucket -> the one-row program; the num_slots-row program of the
         # full chunk
@@ -575,6 +627,7 @@ class DecodeEntry:
             # replicated. Argument layouts (see the programs below):
             #   decode:  (params, caches, tokens, positions, active,
             #             table[, temps, top_ks, top_ps, seeds])
+            #   carry:   decode's, then a one-row prefill's five
             #   prefill: (params, caches, tokens, positions, table,
             #             lengths[, slots])
             n_samp = 4 if self.sampling else 0
@@ -601,15 +654,29 @@ class DecodeEntry:
         # it holds, into a cycle only the collector frees)
         counted = bool(self.counter_names)
 
-        def _step(p, c, t, pos, a, bt, *samp):
-            x, c = model.paged_hidden(
-                p, c, t[:, None], pos[:, None], bt, a.astype(jnp.int32),
-                decode=True)
+        def chosen(p, c, x, pos, *samp):
             nxt = choose(model.head_logits(p, x), pos, *samp)
             if counted:
                 nxt = jnp.concatenate(
                     [nxt, model.step_counters(c).astype(jnp.int32)])
             return nxt, c
+
+        def _step(p, c, t, pos, a, bt, *samp):
+            x, c = model.paged_hidden(
+                p, c, t[:, None], pos[:, None], bt, a.astype(jnp.int32),
+                decode=True)
+            return chosen(p, c, x, pos, *samp)
+
+        def _carry(p, c, t, pos, a, bt, *rest):
+            # the step, and in the same pass, before it where rows are
+            # told apart, the prompt chunk of the slots `rest[-1]` (their
+            # tokens, positions, block-table rows, lengths): every weight
+            # is read once for both
+            *samp, ct, cpos, cbt, cln, cslots = rest
+            x, c = model.paged_hidden(
+                p, c, t[:, None], pos[:, None], bt, a.astype(jnp.int32),
+                decode=True, chunk=(ct, cpos, cbt, cln, cslots))
+            return chosen(p, c, x, pos, *samp)
 
         def _prefill(p, c, t, pos, bt, ln):
             # lengths masks the rounded-up bucket's padded tail (and
@@ -635,6 +702,11 @@ class DecodeEntry:
                 if by_slot else new, mask, c, _prefill(p, own, t, pos, bt, ln))
 
         self._jit_decode = jax.jit(_step, **kw_d)
+        if self.carried:
+            kw_c = dict(kw_d)
+            if "in_shardings" in kw_c:
+                kw_c["in_shardings"] += (self._rep_sharding,) * 5
+            self._jit_carry = jax.jit(_carry, **kw_c)
         self._jit_prefill = jax.jit(_prefill, **kw_p)
         self._jit_prefill_rows = jax.jit(_prefill_rows, **kw_r)
         # the next step's input tokens while this step's are still on the
@@ -700,7 +772,8 @@ class DecodeEntry:
         tests/test_decode.py). The prefill programs: one over ONE row (the
         slot that streams a prompt, named by its index) for every bucket,
         and the `num_slots`-row program of the full chunk, for the burst in
-        which many slots stream a full chunk at once (`prefill_rows`). Cost
+        which many slots stream a full chunk at once (`prefill_rows`); and
+        for each of `carried`, the step that carries such a row. Cost
         analyses land under `compile/serve/<model>/decode/...`."""
         import jax
         from bigdl_tpu.compilecache import precompile_fixed
@@ -737,13 +810,20 @@ class DecodeEntry:
         row = spec((1,), i32)
         for b in self.buckets:
             chunk = spec((1, b), i32)
+            one_row = (chunk, chunk, spec((1, M), i32), row, row)
             cost, exe = precompile_fixed(
-                self._jit_prefill_rows,
-                (p_s, c_s, chunk, chunk, spec((1, M), i32), row, row),
+                self._jit_prefill_rows, (p_s, c_s) + one_row,
                 name=f"serve/{self.name}/decode/prefill{b}")
             self._assert_pool_sharding(exe)
             self._aot_prefill[b] = exe
             results[f"prefill{b}"] = cost
+            if b in self.carried:
+                cost, exe = precompile_fixed(
+                    self._jit_carry, d_args + one_row,
+                    name=f"serve/{self.name}/decode/carry{b}")
+                self._assert_pool_sharding(exe)
+                self._aot_carry[b] = exe
+                results[f"carry{b}"] = cost
         C = self.prefill_chunk
         chunk = spec((S, C), i32)
         cost, self._aot_prefill_all = precompile_fixed(
@@ -829,19 +909,28 @@ class DecodeEntry:
         which goes in untouched. The scheduler fetches next_tokens (the
         iteration's single host sync) only after it has enqueued the step
         that follows. `rest` is the trailing host args (positions, active,
-        block_table[, temps, top_ks, top_ps, seeds])."""
+        block_table[, temps, top_ks, top_ps, seeds]) and, where the step
+        carries a prompt chunk, a one-row prefill call's five behind them
+        (tokens (1, C), positions, the slot's block-table row, its length,
+        its index), C one of `carried`."""
         args = (self.placed_params(), caches,
                 self._place(tokens_last)) + \
             tuple(self._place(a) for a in rest)
-        if self._aot_decode is not None:
+        C = (rest[-5].shape[1]
+             if len(rest) > (7 if self.sampling else 3) else None)
+        exe = self._aot_decode if C is None else self._aot_carry.get(C)
+        if exe is not None:
             try:
-                return self._aot_decode(*args)
+                return exe(*args)
             except (TypeError, ValueError):   # see run_prefill
                 log.warning("serve[%s]: decode-step AOT executable "
                             "rejected live inputs; falling back to jit",
                             self.name)
-                self._aot_decode = None
-        return self._jit_decode(*args)
+                if C is None:
+                    self._aot_decode = None
+                else:
+                    self._aot_carry.pop(C)
+        return (self._jit_decode if C is None else self._jit_carry)(*args)
 
     def merge_tokens(self, use_prev: np.ndarray, prev_next, host_tokens):
         """`where(use_prev, prev_next, host_tokens)` over the slots, on the
@@ -919,7 +1008,7 @@ class GenReply:
 
 class _GenRequest:
     __slots__ = ("prompt", "max_new", "eos_id", "reply", "t_submit",
-                 "t_admit", "t_first", "fed", "generated", "slot",
+                 "t_admit", "t_first", "fed", "generated", "slot", "order",
                  "temperature", "top_k", "top_p", "seed",
                  "need_blocks", "reserved", "shared", "keys",
                  "committed", "commit_upto")
@@ -937,6 +1026,7 @@ class _GenRequest:
         self.fed = 0                       # prompt tokens prefilled so far
         self.generated: List[int] = []
         self.slot: Optional[int] = None
+        self.order = 0                     # its turn among the admitted
         # sampling (greedy unless temperature > 0; nn/sampling.py)
         self.temperature = float(temperature)
         self.top_k = int(top_k)
@@ -987,9 +1077,11 @@ class DecodeScheduler:
          step — requests join the running batch mid-flight), having
          waited for one while a slot is free, nobody is queued and the
          step in flight is still running;
-      2. **prefill**: slots still streaming their prompt advance by one
-         length-bucketed chunk (grouped by bucket so one program call
-         serves every slot on the same chunk size);
+      2. **prefill**: where the iteration decodes, the oldest streaming
+         slot's next chunk is handed to the step, which carries it, and
+         the other streaming slots wait; where nothing decodes, every
+         streaming slot advances by one length-bucketed chunk, a one-row
+         call each (`_prefill_pass`);
       3. **decode**: enqueue one fused step over all prompt-complete
          slots (a slot that continues from the step in flight takes its
          token on the device), then fetch the step that was in flight:
@@ -1051,6 +1143,7 @@ class DecodeScheduler:
                 kind="slot_state", meta={"slots": entry.num_slots})
         self._closed = False
         self._draining = False
+        self._admitted = 0                 # requests given a slot so far
         # the decode step that is enqueued and not fetched yet; written by
         # the thread that runs step_once, cleared by close()
         self._in_flight: Optional[_Step] = None
@@ -1118,6 +1211,10 @@ class DecodeScheduler:
             f"serve/{n}/decode/prefill_calls")
         self._m_prefill_rows = observe.counter(
             f"serve/{n}/decode/prefill_rows")
+        # of those calls, the chunk advances that rode a decode step and
+        # were no program of their own
+        self._m_prefill_carried = observe.counter(
+            f"serve/{n}/decode/prefill_carried")
         self._m_state_resets = observe.counter(
             f"serve/{n}/decode/state_resets")
         # over every query token a program computed: the tokens it may
@@ -1246,6 +1343,8 @@ class DecodeScheduler:
                 self._queue.pop(0)
                 free_slots.pop(0)
                 req.slot = s
+                self._admitted += 1
+                req.order = self._admitted
                 req.t_admit = self._clock()
                 self._h_qw.record(
                     max(0.0, (req.t_admit - req.t_submit) * 1e3))
@@ -1354,25 +1453,43 @@ class DecodeScheduler:
                 return b
         return c
 
-    def _prefill_pass(self) -> int:
-        """Advance every prompt-streaming slot by one chunk, bucket by
-        bucket: a one-row program call for each slot, or, where half the
-        slots or more stream a full chunk at once, the one `num_slots`-row
-        call (`DecodeEntry.prefill_rows`)."""
+    def _prefill_pass(self, stepping: bool) -> Tuple[int, Optional[tuple]]:
+        """The iteration's prompt chunks. Where it dispatches a decode step
+        (`stepping`) and the model has the carrying form, the step is the
+        iteration's one program: it carries the chunk of ONE streaming
+        slot, the one admitted first, and every other streaming slot waits
+        its turn (a second program would read the weights a second time,
+        and every decoding slot's token would wait for it). Else every
+        streaming slot advances by one chunk, bucket by bucket: a one-row
+        program call for each slot. Either way, where half the slots or
+        more stream a full chunk at once, the one `num_slots`-row call
+        (`DecodeEntry.prefill_rows`) and a plain step. Returns (the slots
+        that stream, the chunk for the step to carry or None)."""
         pending = [r for r in self._slots
                    if r is not None and r.fed < r.prefill_target]
         if not pending:
-            return 0
+            return 0, None
         by_bucket: Dict[int, List[_GenRequest]] = {}
         for req in pending:
             by_bucket.setdefault(self._chunk_for(req), []).append(req)
+        if stepping and self.entry.carried:
+            full = self.entry.prefill_chunk
+            burst = by_bucket.get(full, [])
+            if self.entry.prefill_rows(full, len(burst)) > 1:
+                self._prefill_call(full, burst, by_row=False)
+                return len(pending), None
+            first = min(pending, key=lambda r: r.order)
+            C = next(b for b in self.entry.carried
+                     if b >= self._chunk_for(first))
+            return len(pending), self._prefill_call(
+                C, [first], by_row=True, carried=True)
         for C, reqs in sorted(by_bucket.items()):
             if self.entry.prefill_rows(C, len(reqs)) == 1:
                 for req in reqs:
                     self._prefill_call(C, [req], by_row=True)
             else:
                 self._prefill_call(C, reqs, by_row=False)
-        return len(pending)
+        return len(pending), None
 
     def _count_context(self, contexts: np.ndarray) -> None:
         """`contexts`: of each query token a call computes, the tokens it
@@ -1383,11 +1500,15 @@ class DecodeScheduler:
             contexts.sum() if topk is None
             else np.minimum(contexts, topk).sum()))
 
-    def _prefill_call(self, C: int, reqs: List[_GenRequest],
-                      by_row: bool) -> None:
+    def _prefill_call(self, C: int, reqs: List[_GenRequest], by_row: bool,
+                      carried: bool = False) -> Optional[tuple]:
         """One prefill program call that advances `reqs` by a chunk of
         bucket `C`: over their slots' rows and no others (`by_row`), or
-        over all `num_slots` rows, those of the other slots at length 0."""
+        over all `num_slots` rows, those of the other slots at length 0.
+        `carried`: no call is made; the arguments are returned for the
+        decode step this iteration dispatches, which carries the chunk, and
+        the requests are advanced as by a call (the step is enqueued before
+        anything that reads what it writes)."""
         slots = np.asarray([req.slot for req in reqs], np.int32)
         R = len(reqs) if by_row else self.entry.num_slots
         rows = range(R) if by_row else slots
@@ -1403,20 +1524,23 @@ class DecodeScheduler:
             for i, req in zip(rows, reqs):
                 self._ensure_blocks(req, req.fed + int(lengths[i]) - 1)
             table = self._tables[slots] if by_row else self._tables.copy()
-        t0 = self._clock()
         # of each valid query token, the tokens it may attend
         contexts = (positions + 1)[np.arange(C) < lengths[:, None]]
-        with observe.span("serve/decode/prefill", cat="serve",
-                          args={"model": self.name, "chunk": C,
-                                "slots": len(reqs), "rows": R,
-                                "context": int(contexts.max()),
-                                "state": self.entry.state_kind}):
-            # lengths masks the rounded-up bucket's padded tail (and
-            # inactive rows) out of the pool scatter
-            self._caches = self.entry.run_prefill(
-                self._caches, tokens, positions, table, lengths,
-                slots if by_row else None)
-        self._h_prefill.record(max(0.0, (self._clock() - t0) * 1e3))
+        if carried:
+            self._m_prefill_carried.inc()
+        else:
+            t0 = self._clock()
+            with observe.span("serve/decode/prefill", cat="serve",
+                              args={"model": self.name, "chunk": C,
+                                    "slots": len(reqs), "rows": R,
+                                    "context": int(contexts.max()),
+                                    "state": self.entry.state_kind}):
+                # lengths masks the rounded-up bucket's padded tail (and
+                # inactive rows) out of the pool scatter
+                self._caches = self.entry.run_prefill(
+                    self._caches, tokens, positions, table, lengths,
+                    slots if by_row else None)
+            self._h_prefill.record(max(0.0, (self._clock() - t0) * 1e3))
         self._m_prefill_calls.inc()
         self._m_prefill_rows.inc(R)
         self._m_prefill_tokens.inc(int(lengths.sum()))
@@ -1425,6 +1549,7 @@ class DecodeScheduler:
             req.fed += min(req.prefill_target - req.fed, C)
             if self._prefix is not None:
                 self._commit_prefix(req)
+        return (tokens, positions, table, lengths, slots) if carried else None
 
     def _commit_prefix(self, req: _GenRequest) -> None:
         """Publish the whole prompt blocks `req`'s prefill frontier has
@@ -1442,16 +1567,17 @@ class DecodeScheduler:
                 j += 1
             req.commit_upto = j
 
-    def _decode_pass(self) -> int:
-        """Enqueue the next fused step over every prompt-complete slot,
-        then fetch and deliver the step that was in flight, which the
-        device has been running meanwhile."""
+    def _decode_pass(self, rows: List[Tuple[_GenRequest, bool]],
+                     chunk: Optional[tuple]) -> int:
+        """Enqueue the next fused step over `rows` (`_step_rows`), with the
+        prompt chunk it carries, then fetch and deliver the step that was
+        in flight, which the device has been running meanwhile."""
         prev = self._in_flight
         # `rows` and `context` (the longest of the step enqueued) are known
         # once it is dispatched; the span keeps the dict it was given
         args = {"model": self.name, "state": self.entry.state_kind}
         with observe.span("serve/decode/step", cat="serve", args=args):
-            self._in_flight = self._dispatch_step(prev)
+            self._in_flight = self._dispatch_step(prev, rows, chunk)
             if self._in_flight is not None:
                 args["rows"] = len(self._in_flight.rows)
                 args["context"] = self._in_flight.context
@@ -1460,34 +1586,46 @@ class DecodeScheduler:
         step = prev or self._in_flight
         return len(step.rows) if step is not None else 0
 
-    def _dispatch_step(self, prev: Optional[_Step]) -> Optional[_Step]:
-        """One fused decode step over every slot whose prompt is complete
-        and whose sequence does not end by count in `prev`, the step in
-        flight. A row of `prev` that continues takes its input token from
-        `prev.nxt` on the device; everything else of the step the host
-        knows without that token."""
+    def _step_rows(self, prev: Optional[_Step]
+                   ) -> List[Tuple[_GenRequest, bool]]:
+        """The rows of the step to dispatch now: every slot whose prompt is
+        complete and whose sequence does not end by count in `prev`, the
+        step in flight, each with whether it continues from a row of
+        `prev`."""
+        rows = []
+        for req in self._slots:
+            if req is None or req.fed < req.prefill_target:
+                continue
+            ahead = prev is not None and any(r is req for r in prev.rows)
+            if ahead and len(req.generated) + 1 >= req.max_new:
+                continue            # `prev` holds its last token
+            rows.append((req, ahead))
+        return rows
+
+    def _dispatch_step(self, prev: Optional[_Step],
+                       step_rows: List[Tuple[_GenRequest, bool]],
+                       chunk: Optional[tuple]) -> Optional[_Step]:
+        """One fused decode step over `step_rows`. A row of `prev` that
+        continues takes its input token from `prev.nxt` on the device;
+        everything else of the step the host knows without that token, the
+        prompt chunk it carries (`_prefill_pass`) included."""
+        if not step_rows:
+            return None
         S = self.entry.num_slots
         tokens = np.zeros((S,), np.int32)
         positions = np.zeros((S,), np.int32)
         active = np.zeros((S,), bool)
         use_prev = np.zeros((S,), bool)
-        rows: List[_GenRequest] = []
-        for req in self._slots:
-            if req is None or req.fed < req.prefill_target:
-                continue
+        rows = [req for req, _ in step_rows]
+        for req, ahead in step_rows:
             tok, pos = req.next_input()
-            if prev is not None and any(r is req for r in prev.rows):
-                if len(req.generated) + 1 >= req.max_new:
-                    continue        # `prev` holds its last token
+            if ahead:
                 pos += 1
                 use_prev[req.slot] = True
             else:
                 tokens[req.slot] = tok
             positions[req.slot] = pos
             active[req.slot] = True
-            rows.append(req)
-        if not rows:
-            return None
         with self._cv:
             for req in rows:
                 self._ensure_blocks(req, int(positions[req.slot]))
@@ -1503,6 +1641,7 @@ class DecodeScheduler:
                 tps[req.slot] = req.top_p
                 seeds[req.slot] = req.seed
             extra += [temps, tks, tps, seeds]
+        extra += chunk or ()
         t0 = self._clock()
         if use_prev.any():
             tokens = self.entry.merge_tokens(use_prev, prev.nxt, tokens)
@@ -1624,8 +1763,9 @@ class DecodeScheduler:
         return is_ready is None or is_ready()
 
     def step_once(self) -> bool:
-        """One scheduler iteration: sweep cancels → admit → prefill →
-        decode (enqueue the next step, then fetch the one in flight).
+        """One scheduler iteration: sweep cancels → admit → prefill (as
+        calls, or as the chunk the step carries) → decode (enqueue the next
+        step, then fetch the one in flight).
         Returns True when any work happened (the thread loop sleeps
         otherwise); tests drive this synchronously with a fake clock."""
         worked = self._sweep_cancelled() > 0
@@ -1641,8 +1781,13 @@ class DecodeScheduler:
                                           "blocks": swept})
         self._await_taker()
         worked = self._admit() > 0 or worked
-        worked = self._prefill_pass() > 0 or worked
-        worked = self._decode_pass() > 0 or worked
+        stepping = bool(self._step_rows(self._in_flight))
+        streaming, chunk = self._prefill_pass(stepping)
+        # a slot whose prompt that chunk completes, in a call or carried,
+        # joins this iteration's step with its last prompt token
+        rows = self._step_rows(self._in_flight)
+        worked = streaming > 0 or worked
+        worked = self._decode_pass(rows, chunk) > 0 or worked
         self._refresh_pool_stats()
         return worked
 
@@ -1813,6 +1958,7 @@ class DecodeScheduler:
             "prefill_tokens": int(self._m_prefill_tokens.value),
             "prefill_calls": int(self._m_prefill_calls.value),
             "prefill_rows": int(self._m_prefill_rows.value),
+            "prefill_carried": int(self._m_prefill_carried.value),
             "context_tokens": int(self._m_context.value),
             "attended_tokens": int(self._m_attended.value),
             "step_context_tokens": int(self._m_step_context.value),

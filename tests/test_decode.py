@@ -149,17 +149,19 @@ def test_greedy_generate_matches_beam1(lm):
 
 
 # ------------------------------------------------ the parity acceptance
-def _staggered_run(entry, submits, poison=False):
+def _staggered_run(entry, submits, poison=False, name="stag"):
     """Drive a synchronous scheduler through a staggered schedule:
-    `submits` = [(step_at_which_to_submit, prompt, max_new, eos)].
-    Returns the per-request generated arrays (submission order)."""
-    sched = DecodeScheduler(entry, name="stag", start=False)
+    `submits` = [(step_at_which_to_submit, prompt, max_new, eos[, sampling
+    keywords])]. Returns the per-request generated arrays (submission
+    order)."""
+    sched = DecodeScheduler(entry, name=name, start=False)
     replies = [None] * len(submits)
     step = 0
     while True:
-        for i, (at, prompt, max_new, eos) in enumerate(submits):
+        for i, (at, prompt, max_new, eos, *kw) in enumerate(submits):
             if at == step:
-                replies[i] = sched.submit(prompt, max_new, eos_id=eos)
+                replies[i] = sched.submit(prompt, max_new, eos_id=eos,
+                                          **(kw[0] if kw else {}))
         worked = sched.step_once()
         if poison:
             # poison every FREE cache region (the unallocated pool
@@ -179,6 +181,7 @@ def _staggered_run(entry, submits, poison=False):
             break
         assert step < 500, "scheduler failed to converge"
     out = [r.result(timeout=1) for r in replies]
+    _staggered_run.stats = sched.stats()
     sched.close(drain=False)
     return out
 
@@ -438,8 +441,10 @@ def test_free_slot_waits_for_its_taker_while_a_step_is_in_flight(
         entry, monkeypatch, arrives):
     """With a slot free, nobody queued and a step in flight, the iteration
     waits for a request until that step is done: one that arrives meanwhile
-    is admitted in the same iteration, its prefill enqueued BEFORE the next
-    decode step; if none arrives, the step's end lets the iteration go on."""
+    is admitted in the same iteration and its prompt chunk rides the decode
+    step that iteration enqueues, no program of its own, and that step
+    its first token; if none arrives, the step's end lets the iteration go
+    on."""
     import threading
     sched = DecodeScheduler(entry, name=f"taker-{arrives}", start=False)
     first = sched.submit([2, 3], 6, eos_id=-1)
@@ -468,7 +473,9 @@ def test_free_slot_waits_for_its_taker_while_a_step_is_in_flight(
     timer.cancel()
     monkeypatch.undo()
     if arrives:
-        assert calls == ["prefill", "decode"] and sched.active_slots == 2
+        assert calls == ["decode"] and sched.active_slots == 2
+        assert _counter(sched, "prefill_carried") == 1
+        # and completed its prompt: the same step computes its first token
         assert len(sched._in_flight.rows) == 2
         assert _run_until_done(sched, reply)[0].shape == (3,)
     else:
@@ -560,6 +567,340 @@ def test_failed_fetch_fails_every_reply_with_the_real_error(entry,
             rep.result(timeout=30)
     thread.join(timeout=30)
     assert not thread.is_alive() and sched._in_flight is None
+
+
+# ---------------------------- one program an iteration: the carrying step
+@pytest.mark.parametrize("chunk,want", [
+    (8, (8,)), (64, (64,)), (96, (64, 96)), (256, (64, 128, 256))])
+def test_carried_buckets_are_the_ladders_upper_end(chunk, want):
+    from bigdl_tpu.serve.decode import carried_buckets
+    assert carried_buckets(prefill_buckets(chunk)) == want
+
+
+def _family(lm, family):
+    """A demo model of a served family and its parameters."""
+    if family == "GPT2LM":
+        return lm[:2]
+    if family == "OlmoHybridLM":
+        return _tiny_hybrid()
+    from bigdl_tpu.interop.huggingface import LlamaLM
+    model = LlamaLM(VOCAB, 16, 4, 2, 32, 2, eos_id=EOS)
+    return model, model.init(jax.random.PRNGKey(1))[0]
+
+
+@pytest.fixture(scope="module")
+def family_entries(lm):
+    """family -> its entry with the sampling step (which serves greedy
+    requests too), built once: the cases share its programs."""
+    kept = {}
+
+    def entry_of(family):
+        if family not in kept:
+            model, params = _family(lm, family)
+            kept[family] = DecodeEntry(
+                f"carry{family}", model, params, num_slots=4,
+                max_seq_len=32, prefill_chunk=8, kv_block=8, sampling=True)
+        return kept[family]
+    return entry_of
+
+
+# (join step, prompt length, max_new): every prompt after the first streams
+# while another slot decodes, one of them in three chunks
+CARRIED = [(0, 3, 12), (1, 12, 6), (2, 21, 6), (3, 5, 8), (5, 9, 6)]
+
+
+@pytest.mark.parametrize("mode", ["greedy", "sampled"])
+@pytest.mark.parametrize("family", ["GPT2LM", "LlamaLM", "OlmoHybridLM"])
+def test_carrying_iteration_gives_each_sequence_its_own_tokens(
+        lm, family_entries, family, mode):
+    """Staggered requests whose prompt chunks ride the decode steps of the
+    slots that decode get the tokens each gets alone (when nothing decodes
+    beside it, its chunks are calls of their own), greedy and sampled; for
+    GPT-2, greedy, those of `generate` too."""
+    e = family_entries(family)
+    assert e.carried == (8,)
+    r = np.random.RandomState(13)
+    subs = [(at, r.randint(2, VOCAB, p).astype(np.int32), new, -1,
+             {} if mode == "greedy" else dict(
+                 temperature=1.5, top_k=12, top_p=0.9, seed=40 + i))
+            for i, (at, p, new) in enumerate(CARRIED)]
+    outs = _staggered_run(e, subs, name=f"{family}-{mode}")
+    stats = _staggered_run.stats
+    assert stats["prefill_carried"] >= 6
+    assert stats["prefill_tokens"] == sum(len(s[1]) - 1 for s in subs)
+    for sub, got in zip(subs, outs):
+        alone, = _staggered_run(e, [(0,) + sub[1:]], name=f"{family}-alone")
+        assert _staggered_run.stats["prefill_carried"] == 0
+        np.testing.assert_array_equal(got, alone)
+        if (family, mode) == ("GPT2LM", "greedy"):
+            check_vs_oracle(lm, sub[1], got, sub[2], eos_id=-1)
+
+
+@pytest.fixture(scope="module")
+def entry8(lm):
+    """8 slots, so that two and three slots streaming a full chunk are under
+    half of them (the burst rule's switch)."""
+    model, params, _ = lm
+    return DecodeEntry("rule", model, params, num_slots=8, max_seq_len=32,
+                       prefill_chunk=8)
+
+
+def _spied(monkeypatch, entry):
+    """The programs the scheduler enqueues, in order: ("prefill", rows) a
+    prefill call, ("step", slot its chunk is of or None) a decode step."""
+    calls = []
+    real_prefill, real_run = entry.run_prefill, entry.run_decode
+
+    def prefill(caches, tokens, *rest):
+        calls.append(("prefill", tokens.shape[0]))
+        return real_prefill(caches, tokens, *rest)
+
+    def run(caches, tokens, *rest):
+        calls.append(("step", int(rest[-1][0]) if len(rest) > 3 else None))
+        return real_run(caches, tokens, *rest)
+    monkeypatch.setattr(entry, "run_prefill", prefill)
+    monkeypatch.setattr(entry, "run_decode", run)
+    return calls
+
+
+@pytest.mark.parametrize("streaming", [2, 3])
+def test_an_iteration_that_decodes_runs_one_program(lm, entry8, monkeypatch,
+                                                    streaming):
+    """Slots streaming prompts while another decodes: every iteration
+    enqueues ONE program, the decode step, which carries the chunk of the
+    slot admitted first; the others keep their `fed` and ride later steps,
+    first come first served; no prefill call is made; `prefill_carried`
+    counts each advance, like `prefill_calls`, `_rows` and `_tokens`."""
+    sched = DecodeScheduler(entry8, name=f"one{streaming}", start=False)
+    r = np.random.RandomState(17)
+    first = sched.submit(r.randint(2, VOCAB, 9).astype(np.int32), 20,
+                         eos_id=-1)
+    sched.step_once()                 # alone: its chunk a call, then a step
+    assert _counter(sched, "prefill_calls") == 1
+    calls = _spied(monkeypatch, entry8)
+    # prompts of 17 tokens: two full chunks each
+    prompts = [r.randint(2, VOCAB, 17).astype(np.int32)
+               for _ in range(streaming)]
+    reps = [sched.submit(p, 4, eos_id=-1) for p in prompts]
+    reqs = list(sched._queue)
+    # its slot, chunk by chunk
+    want = [i + 1 for i in range(streaming) for _ in range(2)]
+    for n, slot in enumerate(want):
+        before = [q.fed for q in reqs]
+        sched.step_once()
+        assert calls[-1] == ("step", slot) and len(calls) == n + 1
+        moved = [q.fed - b for q, b in zip(reqs, before)]
+        assert [m > 0 for m in moved] == [q.slot == slot for q in reqs]
+        assert _counter(sched, "prefill_carried") == n + 1
+    assert _counter(sched, "prefill_calls") == 1 + len(want)
+    assert _counter(sched, "prefill_rows") == 1 + len(want)
+    assert _counter(sched, "prefill_tokens") == 8 + sum(
+        len(p) - 1 for p in prompts)
+    assert sched.stats()["prefill_carried"] == len(want)
+    outs = _run_until_done(sched, [first] + reps)
+    assert all(c[0] == "step" for c in calls)
+    monkeypatch.undo()
+    for p, got in zip(prompts, outs[1:]):
+        check_vs_oracle(lm, p, got, 4, eos_id=-1)
+    sched.close(drain=False)
+
+
+@pytest.mark.parametrize("case,want", [
+    ("no-decode-row", [("prefill", 1), ("prefill", 1), ("step", None)]),
+    ("burst", [("prefill", 8), ("step", None)])])
+def test_prefill_calls_where_the_rule_leaves_them(lm, entry8, monkeypatch,
+                                                  case, want):
+    """An iteration with no decode row still runs a one-row call for every
+    streaming slot, then a step over the prompts they completed; a burst,
+    from half the slots on streaming a full chunk, still takes the one
+    `num_slots`-row call and a plain step, beside slots that decode too."""
+    sched = DecodeScheduler(entry8, name=f"left-{case}", start=False)
+    r = np.random.RandomState(19)
+    if case == "burst":
+        sched.submit(r.randint(2, VOCAB, 9).astype(np.int32), 8, eos_id=-1)
+        sched.step_once()
+        n, length = 4, 17
+    else:
+        n, length = 2, 9
+    calls = _spied(monkeypatch, entry8)
+    prompts = [r.randint(2, VOCAB, length).astype(np.int32)
+               for _ in range(n)]
+    reps = [sched.submit(p, 3, eos_id=-1) for p in prompts]
+    sched.step_once()
+    assert calls == want
+    assert _counter(sched, "prefill_carried") == 0
+    monkeypatch.undo()
+    for p, got in zip(prompts, _run_until_done(sched, reps)):
+        check_vs_oracle(lm, p, got, 3, eos_id=-1)
+    sched.close(drain=False)
+
+
+@pytest.mark.parametrize("family", ["GPT2LM", "OlmoHybridLM"])
+def test_a_chunk_under_the_smallest_carried_bucket_is_padded_and_masked(
+        lm, family):
+    """The ladder of carried buckets is the upper end of the ladder of
+    buckets (here the full chunk alone): a shorter row rides padded up to
+    the smallest of them and masked by its length, and leaves in the pool
+    and in the state resident by slot what the one-row call of its own,
+    exact bucket leaves, every other block and slot as it was."""
+    model, params = _family(lm, family)
+    e = DecodeEntry(f"pad{family}", model, params, num_slots=4,
+                    max_seq_len=32, prefill_chunk=8, kv_block=8)
+    assert e.buckets == (1, 2, 4, 8) and e.carried == (8,)
+    r = np.random.RandomState(23)
+    dirty = jax.tree.map(
+        lambda a: jnp.asarray(r.randn(*a.shape), a.dtype), e.make_caches())
+    s, fed, n, exact, C = 2, 8, 2, 2, 8
+    table = np.full((4, e.blocks_per_slot), -1, np.int32)
+    table[:, :2] = np.arange(8).reshape(4, 2)
+
+    def chunk(bucket):
+        tokens = np.zeros((1, bucket), np.int32)
+        tokens[0, :n] = [5, 9]
+        return (tokens, fed + np.arange(bucket, dtype=np.int32)[None],
+                table[[s]], np.asarray([n], np.int32),
+                np.asarray([s], np.int32))
+    call = e.run_prefill(dirty, *chunk(exact))
+    idle = np.zeros((4,), np.int32)
+    _, rode = e.run_decode(dirty, idle, idle, np.zeros((4,), bool), table,
+                           *chunk(C))
+    for was, a, b in zip(*map(jax.tree.leaves, (dirty, call, rode))):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=1e-6, atol=1e-6)
+        assert not np.array_equal(np.asarray(b), np.asarray(was))
+        # what the call left as it was, the carried chunk did too
+        same = np.asarray(a) == np.asarray(was)
+        np.testing.assert_array_equal(np.asarray(b)[same],
+                                      np.asarray(was)[same])
+
+
+@pytest.mark.parametrize("family", ["GPT2LM", "OlmoHybridLM"])
+def test_the_step_that_carries_a_prompts_last_chunk_decodes_its_slot_too(
+        lm, family):
+    """The chunk's part goes first: the slot whose prompt the carried chunk
+    completes is a row of the same step, its last prompt token at the
+    position behind the chunk (in the same block of the pool, after the
+    chunk's update of the state). Tokens and caches are those of the one-row
+    call followed by the plain step, for that slot and for one that decodes
+    beside it."""
+    model, params = _family(lm, family)
+    e = DecodeEntry(f"both{family}", model, params, num_slots=4,
+                    max_seq_len=32, prefill_chunk=8, kv_block=8)
+    r = np.random.RandomState(41)
+    caches = e.make_caches()
+    table = np.full((4, e.blocks_per_slot), -1, np.int32)
+    table[:, :2] = np.arange(8).reshape(4, 2)
+    # slot 0 decodes at position 3 (its prompt went in before); slot 2 has
+    # 9 tokens in and streams its last 5, then its last prompt token
+    def prompt(s, n):
+        tokens = np.zeros((1, 16), np.int32)
+        tokens[0, :n] = r.randint(2, VOCAB, n)
+        return (tokens[:, :8 * -(-n // 8)], np.arange(8 * -(-n // 8),
+                dtype=np.int32)[None], table[[s]], np.asarray([n], np.int32),
+                np.asarray([s], np.int32))
+    caches = e.run_prefill(caches, *prompt(0, 3))
+    caches = e.run_prefill(caches, *prompt(2, 8))
+    s, fed, n = 2, 8, 5
+    tokens = np.zeros((1, 8), np.int32)
+    tokens[0, :n] = r.randint(2, VOCAB, n)
+    chunk = (tokens, fed + np.arange(8, dtype=np.int32)[None], table[[s]],
+             np.asarray([n], np.int32), np.asarray([s], np.int32))
+    last = np.asarray([7, 0, 11, 0], np.int32)
+    positions = np.asarray([3, 0, fed + n, 0], np.int32)
+    active = np.asarray([True, False, True, False])
+    want, after = e.run_decode(e.run_prefill(caches, *chunk), last,
+                               positions, active, table)
+    got, rode = e.run_decode(caches, last, positions, active, table, *chunk)
+    np.testing.assert_array_equal(np.asarray(got)[active],
+                                  np.asarray(want)[active])
+    for a, b in zip(jax.tree.leaves(after), jax.tree.leaves(rode)):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=1e-5, atol=1e-6)
+
+
+class _TwoCallModel:
+    """A served model that implements the three-name contract and not the
+    optional carrying form."""
+
+    def __init__(self, model):
+        self._model = model
+        self.vocab_size, self.eos_id = model.vocab_size, model.eos_id
+        self.n_positions = model.n_positions
+        self.make_paged_slot_caches = model.make_paged_slot_caches
+        self.head_logits = model.head_logits
+
+    def paged_hidden(self, params, caches, tokens, positions, block_table,
+                     lengths, decode=False):
+        return self._model.paged_hidden(params, caches, tokens, positions,
+                                        block_table, lengths, decode)
+
+
+def test_a_model_without_the_carrying_form_keeps_the_two_call_iteration(
+        lm, monkeypatch):
+    model, params, _ = lm
+    e = DecodeEntry("twocall", _TwoCallModel(model), params, num_slots=4,
+                    max_seq_len=32, prefill_chunk=8)
+    assert e.carried == () and e._jit_carry is None
+    assert "carry8" not in e.precompile()
+    sched = DecodeScheduler(e, name="twocall", start=False)
+    r = np.random.RandomState(29)
+    first = sched.submit(r.randint(2, VOCAB, 3).astype(np.int32), 8,
+                         eos_id=-1)
+    sched.step_once()
+    calls = _spied(monkeypatch, e)
+    prompt = r.randint(2, VOCAB, 12).astype(np.int32)
+    rep = sched.submit(prompt, 5, eos_id=-1)
+    sched.step_once()
+    sched.step_once()
+    assert calls == [("prefill", 1), ("step", None)] * 2
+    monkeypatch.undo()
+    check_vs_oracle(lm, prompt, _run_until_done(sched, [first, rep])[1], 5,
+                    eos_id=-1)
+    assert sched.stats()["prefill_carried"] == 0
+    sched.close(drain=False)
+
+
+@pytest.mark.parametrize("how", ["cancel", "eos"])
+def test_a_slot_ends_while_a_chunk_rides_the_step_in_flight(lm, entry, how):
+    """`cancel`: the slot whose chunk rides the step in flight is cancelled;
+    its blocks return, the slot's next owner and the slot that decoded
+    beside it get their own tokens. `eos`: a decoding slot ends by value in
+    step N while step N+1, which carries another slot's chunk and its own
+    stray row, is in flight: the row is dropped, the chunk stands."""
+    sched = DecodeScheduler(entry, name=f"ride-{how}", start=False)
+    r = np.random.RandomState(37)
+    a, b, c = (r.randint(2, VOCAB, n).astype(np.int32) for n in (4, 20, 9))
+    alone_a, alone_b, alone_c = _staggered_run(
+        entry, [(0, a, 10, -1)]) + _staggered_run(
+        entry, [(0, b, 6, -1)]) + _staggered_run(entry, [(0, c, 6, -1)])
+    eos = int(alone_a[2]) if how == "eos" else -1
+    stop = int(np.argmax(alone_a == eos)) + 1 if how == "eos" else 10
+    rep_a = sched.submit(a, 10, eos_id=eos)
+    sched.step_once()
+    while len(rep_a._tokens.queue) < min(stop - 1, 3):
+        sched.step_once()             # `eos`: A's last token is in flight
+    rep_b = sched.submit(b, 6, eos_id=-1)
+    sched.step_once()                 # B's first chunk rides a step
+    assert _counter(sched, "prefill_carried") == 1
+    assert sched._in_flight is not None
+    if how == "cancel":
+        rep_b.cancel()
+        sched.step_once()             # sweeps B; its chunk's step is fetched
+        assert rep_b.result(timeout=1).shape == (0,)
+        rep_c = sched.submit(c, 6, eos_id=-1)     # B's slot, B's blocks
+        got_a, got_c = _run_until_done(sched, [rep_a, rep_c])
+        np.testing.assert_array_equal(got_c, alone_c)
+        assert _counter(sched, "rows_dropped") == 0
+    else:
+        assert rep_a.done()           # ended by value at that fetch
+        got_a, got_b = _run_until_done(sched, [rep_a, rep_b])
+        np.testing.assert_array_equal(got_b, alone_b)
+        assert _counter(sched, "rows_dropped") == 1
+    np.testing.assert_array_equal(got_a, alone_a[:stop])
+    p = sched._pool
+    assert p.free + sched._prefix.cached_count() == p.total
+    assert p.live == 0 and p.reserved == 0
+    sched.close(drain=False)
 
 
 # ------------------------------------ paged KV pool & prefix cache (r21)
@@ -797,11 +1138,12 @@ def test_zero_fresh_compiles_after_precompile(engine):
     sched = engine._decoders["lm"]
     dec = sched.entry
     assert sorted(dec._aot_prefill) == list(dec.buckets) == [1, 2, 4, 8]
+    assert sorted(dec._aot_carry) == list(dec.carried) == [8]
     assert dec._aot_prefill_all is not None and dec._aot_decode is not None
     compiles = observe.registry().counter("jit/compiles")
     c0 = compiles.value
-    calls0, rows0 = (_counter(sched, n)
-                     for n in ("prefill_calls", "prefill_rows"))
+    calls0, rows0, carried0 = (_counter(sched, n) for n in (
+        "prefill_calls", "prefill_rows", "prefill_carried"))
     r = np.random.RandomState(4)
     with sched._cv:     # all eight queued before the first is admitted
         reps = [engine.submit_generate("lm", r.randint(2, VOCAB, p), 6)
@@ -814,6 +1156,11 @@ def test_zero_fresh_compiles_after_precompile(engine):
     calls = _counter(sched, "prefill_calls") - calls0
     assert calls < _counter(sched, "prefill_rows") - rows0 < 4 * calls
     assert dec._aot_prefill_all is not None and len(dec._aot_prefill) == 4
+    # and prompts streamed beside slots that decoded (the tail of 13, a
+    # request admitted once another retired): those chunks rode steps,
+    # through AOT executables too
+    assert _counter(sched, "prefill_carried") - carried0 >= 2
+    assert len(dec._aot_carry) == 1
 
 
 def test_streaming_reply_yields_before_completion(engine):
@@ -839,6 +1186,10 @@ def test_engine_stats_and_statusz_decode_section(engine):
     payload = statusz.status_payload()
     assert payload["decode"]["lm"]["tokens"] == d["tokens"]
     assert payload["serve"]["lm"]["decode"]["slots"] == 4
+    # the chunks that rode a step stand beside the steps enqueued ahead
+    assert 0 < d["prefill_carried"] <= d["prefill_calls"]
+    assert payload["decode"]["lm"]["prefill_carried"] == d["prefill_carried"]
+    assert payload["decode"]["lm"]["steps_ahead"] == d["steps_ahead"]
 
 
 def test_generate_for_unregistered_model_raises(engine):

@@ -337,6 +337,83 @@ def test_counters_of_the_selection_and_the_experts(served):
         3 * 128 + 2 * 128) + 12     # rows of whole 128-lane tiles
 
 
+# ------------------------------- a question's chunk rides the decode step
+@pytest.fixture(scope="module")
+def sampled_entry(lm):
+    return _entry(lm, "ride", sampling=True)
+
+
+def _staggered(entry, name, submits):
+    """`submits` = [(iteration at which it is sent, prompt, max_new,
+    sampling keywords)] through one scheduler -> (tokens, stats)."""
+    sched = DecodeScheduler(entry, name=name, start=False)
+    reps, at = [None] * len(submits), 0
+    while not all(r is not None and r.done() for r in reps):
+        for i, (when, prompt, new, kw) in enumerate(submits):
+            if when == at:
+                reps[i] = sched.submit(prompt, new, eos_id=-1, **kw)
+        sched.step_once()
+        at += 1
+        assert at < 400
+    out = [r.result(timeout=1) for r in reps], sched.stats()
+    sched.close(drain=False)
+    return out
+
+
+@pytest.mark.parametrize("mode", ["greedy", "sampled"])
+def test_questions_that_ride_the_steps_get_their_own_tokens(sampled_entry,
+                                                            mode):
+    """Questions over one document and a longer prompt, sent while other
+    slots decode: their chunks ride the decode steps (the selection by
+    positions for the step's rows and by mask for the chunk's in one pass,
+    the experts over both), and each request gets the tokens it gets alone,
+    its document from the prefix cache or not."""
+    doc = _tokens(1, 42, seed=5)[0]
+    prompts = [np.concatenate([doc, q]) for q in _tokens(3, 7, seed=6)] \
+        + [_tokens(1, 21, seed=9)[0]]
+    subs = [(at, p, new, {} if mode == "greedy" else dict(
+        temperature=1.3, top_k=24, top_p=0.9, seed=70 + i))
+        for i, (at, p, new) in enumerate(zip((0, 8, 9, 11), prompts,
+                                             (14, 16, 16, 8)))]
+    outs, stats = _staggered(sampled_entry, f"ride-{mode}", subs)
+    assert stats["prefill_carried"] >= 4 and stats["prefix_hits"] >= 20
+    assert stats["prefill_tokens"] == 48 + 8 + 8 + 20
+    for (_, prompt, new, kw), got in zip(subs, outs):
+        alone, st = _staggered(sampled_entry, f"alone-{mode}",
+                               [(0, prompt, new, kw)])
+        np.testing.assert_array_equal(got, alone[0])
+
+
+def test_a_short_question_rides_padded_to_the_smallest_carried_bucket(lm):
+    """Under the smallest carried bucket a chunk is padded up to it and
+    masked by its length: the pools hold what the exact bucket's one-row
+    call leaves, and the experts count its valid tokens alone."""
+    entry = _entry(lm, "pad")
+    assert entry.carried == (CHUNK,)
+    r = np.random.default_rng(3)
+    dirty = jax.tree.map(
+        lambda a: jnp.asarray(r.standard_normal(a.shape), a.dtype)
+        if a.ndim > 1 else a, entry.make_caches())
+    table = np.full((SLOTS, entry.blocks_per_slot), -1, np.int32)
+    table[:, :4] = np.arange(4 * SLOTS).reshape(SLOTS, 4)
+    s, fed, n = 1, 9, 2
+
+    def chunk(bucket):
+        tokens = np.zeros((1, bucket), np.int32)
+        tokens[0, :n] = [5, 9]
+        return (tokens, fed + np.arange(bucket, dtype=np.int32)[None],
+                table[[s]], np.asarray([n], np.int32),
+                np.asarray([s], np.int32))
+    call = entry.run_prefill(dirty, *chunk(2))
+    idle = np.zeros((SLOTS,), np.int32)
+    _, rode = entry.run_decode(dirty, idle, idle, np.zeros((SLOTS,), bool),
+                               table, *chunk(CHUNK))
+    for a, b in zip(jax.tree.leaves(call), jax.tree.leaves(rode)):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=1e-6, atol=1e-6)
+    assert int(rode[-1][2]) == n * 2        # tokens through expert layers
+
+
 def test_kv_shard_shards_every_pool_along_its_own_block_axis(lm):
     """Pools of another shape than K/V (blocks lead) take the block-dim
     sharding where their blocks lie; the counts are replicated; decoding is
