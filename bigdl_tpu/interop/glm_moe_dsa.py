@@ -6,7 +6,9 @@ primitives, with the paged decode contract of serve/decode.py.
 A block is pre-norm, `h = x + Attn(RMSNorm(x))`, `out = h + MLP(RMSNorm(h))`,
 with a final RMSNorm and an untied head, no bias anywhere. `indexer_types`
 says of each block whether its attention scores and selects (`full`) or is
-handed the selection of the last `full` block before it (`shared`);
+handed the selection of the last `full` block before it (`shared`); None
+(and `index_topk` None) makes every block's attention admit every earlier
+position, as GLM-4.7-Flash's do (interop/glm4_moe_lite.py);
 `mlp_layer_types` whether its MLP is one SwiGLU (`dense`) or the expert
 layer (`sparse`). Every norm's statistics, the router's and the indexer's
 scores and every softmax are float32.
@@ -55,20 +57,20 @@ def _rms(norm, params, x):
 class GlmMoeDsaBlock(Module):
     """One block: the mixer its `indexer` kind names, then the MLP its
     `mlp` kind names. `attention` holds `SparseLatentAttention`'s sizes
-    (with `indexer`, which a `shared` block drops), `experts`
-    `GatedExperts`'."""
+    (with `indexer`, which a `shared` block and a block of no kind (None)
+    drop), `experts` `GatedExperts`'."""
 
-    def __init__(self, indexer: str, mlp: str, d_model: int, d_ff: int,
-                 eps: float, attention: dict, experts: dict,
+    def __init__(self, indexer: Optional[str], mlp: str, d_model: int,
+                 d_ff: int, eps: float, attention: dict, experts: dict,
                  name: Optional[str] = None):
         super().__init__(name or "GlmMoeDsaBlock")
-        if indexer not in (FULL, SHARED) or mlp not in (DENSE, SPARSE):
-            raise ValueError(f"a block is {FULL!r} or {SHARED!r} and "
+        if indexer not in (FULL, SHARED, None) or mlp not in (DENSE, SPARSE):
+            raise ValueError(f"a block is {FULL!r}, {SHARED!r} or None and "
                              f"{DENSE!r} or {SPARSE!r}, not {indexer!r} "
                              f"and {mlp!r}")
         self.indexer, self.mlp = indexer, mlp
         attention = dict(attention)
-        if indexer == SHARED:
+        if indexer != FULL:
             attention.pop("indexer", None)
         self.add_child("attn_norm", RMSNorm(d_model, eps=eps))
         self.add_child("attn", SparseLatentAttention(d_model, eps=eps,
@@ -101,8 +103,9 @@ class GlmMoeDsaBlock(Module):
 
 class GlmMoeDsaLM(Module):
     """apply(params, state, tokens (B, T) int32) -> (B, T, vocab) logits
-    over the vocabulary rows held. `indexer_types` and `mlp_layer_types`
-    name each block held; `attention` the sizes of
+    over the vocabulary rows held. `indexer_types` (None: no indexer, and
+    `index_topk` None) and `mlp_layer_types` name each block held;
+    `attention` the sizes of
     `SparseLatentAttention` (`num_heads`, `q_lora_rank`, `kv_lora_rank`,
     `qk_nope_head_dim`, `qk_rope_head_dim`, `v_head_dim`, `index_topk`,
     `rope_theta`, `indexer` = `{"heads", "head_dim"}`); `experts` those of
@@ -114,7 +117,7 @@ class GlmMoeDsaLM(Module):
     counter_names = ("expert_pairs", "expert_loads", "expert_tokens")
 
     def __init__(self, vocab_size: int, d_model: int, d_ff: int,
-                 indexer_types: Sequence[str],
+                 indexer_types: Optional[Sequence[str]],
                  mlp_layer_types: Sequence[str], attention: dict,
                  experts: dict, max_positions: int, eps: float = 1e-5,
                  expert_share: Optional[Tuple[int, int]] = None,
@@ -129,10 +132,12 @@ class GlmMoeDsaLM(Module):
                              f"of {vocab_size} rows")
         # the vocabulary as this model sees it: the rows it holds
         self.vocab_size, self.d_model = self.vocab_share[1], d_model
-        self.indexer_types = tuple(indexer_types)
         self.mlp_layer_types = tuple(mlp_layer_types)
+        self.indexer_types = (tuple(indexer_types) if indexer_types
+                              is not None else (None,) * len(mlp_layer_types))
         if len(self.indexer_types) != len(self.mlp_layer_types) \
-                or self.indexer_types[:1] != (FULL,):
+                or (indexer_types is not None
+                    and self.indexer_types[:1] != (FULL,)):
             raise ValueError(
                 "indexer_types and mlp_layer_types name the same blocks, "
                 f"the first of them {FULL!r}: a {SHARED!r} block needs the "
@@ -168,13 +173,18 @@ class GlmMoeDsaLM(Module):
         return x @ params["lm_head"].T
 
     def _apply(self, params, state, tokens, *, training=False, rng=None):
+        return self._logits(params, self._hidden(params, tokens)), state
+
+    def _hidden(self, params, tokens):
+        """The last block's output over whole sequences, before the final
+        norm (keys and values expanded a head)."""
         x = params["embed"][tokens]
         admitted = None
         for name, blk in self._blocks():
             mixed, admitted = blk.children()["attn"].dense(
                 params[name]["attn"], blk.normed(params[name], x), admitted)
             x, _ = blk.rest(params[name], x, mixed)
-        return self._logits(params, x), state
+        return x
 
     # -------------------------------------------------- paged decoding
     # What serve/decode.py composes its programs from. Every leaf of the
@@ -197,15 +207,27 @@ class GlmMoeDsaLM(Module):
         """Hidden states of one chunk a slot: tokens/positions (S, C)
         int32, block_table (S, M) int32, lengths (S,) int32 = valid
         leading tokens a row (0 = inactive). `decode` says the chunk is a
-        step's one token: attention then gathers the rows it selects, and
-        else attends a slot's context under a mask (nn/latent_attention.py).
-        `chunk` (the optional carrying form, nn/attention.carried_rows) is a
-        prompt chunk of streaming slots that the same pass computes: every
-        product and the experts read their weights once for both, attention
-        takes each in its own form. Returns (x (S, C, d), the new caches)."""
+        step's few tokens: attention then gathers the rows it selects (or,
+        with no selection, reads the slot's context through the block
+        table), and else attends a slot's context under a mask
+        (nn/latent_attention.py). `chunk` (the optional carrying form,
+        nn/attention.carried_rows) is a prompt chunk of streaming slots
+        that the same pass computes: every product and the experts read
+        their weights once for both, attention takes each in its own form.
+        Returns (x (S, C, d), the new caches)."""
+        x, caches, _ = self._paged_pass(params, caches, tokens, positions,
+                                        block_table, lengths, decode, chunk)
+        return x, caches
+
+    def _paged_pass(self, params, caches, tokens, positions, block_table,
+                    lengths, decode, chunk):
+        """`paged_hidden`, returning also the joined hidden states of a
+        carried chunk's and the step's tokens (1, n, d) (None without a
+        chunk). Leaves of the cache after the blocks' pools and before the
+        counts (a draft module's pools) pass through untouched."""
         def valid_of(tokens, lengths):
             return jnp.arange(tokens.shape[1])[None, :] < lengths[:, None]
-        S = tokens.shape[0]
+        S, T = tokens.shape
         valid = valid_of(tokens, lengths)
         if chunk is not None:
             valid = join_rows([valid_of(chunk[0], chunk[3]), valid])
@@ -221,12 +243,14 @@ class GlmMoeDsaLM(Module):
             x, n = blk.rest(params[name], x, mixed, valid)
             counts += n
             new.append(pools)
+        joined = None
         if parts:
-            x = x[0, -S:, None]         # the step's rows, one token each
+            joined = x
+            x = x[0, -S * T:].reshape(S, T, -1)     # the step's rows
         through = jnp.sum(valid, dtype=jnp.int32) \
             * self.mlp_layer_types.count(SPARSE)
-        return x, tuple(new) + (
-            caches[-1] + jnp.append(counts, through),)
+        return x, tuple(new) + tuple(caches[len(new):-1]) + (
+            caches[-1] + jnp.append(counts, through),), joined
 
     def head_logits(self, params, x):
         """x (S, 1, d) -> logits (S, V): the final norm and the head."""

@@ -21,7 +21,12 @@ x_s))` a token and scores every earlier token for each query,
 
 and attention admits only the `index_topk` largest `I(t, .)` among `s <= t`
 (all of them while there are no more). A `shared` layer has no indexer and
-admits what the last `full` layer before it admitted.
+admits what the last `full` layer before it admitted. A layer with neither
+(`index_topk` None, GLM-4.7-Flash's) admits every `s <= t`: its prompt
+chunk is the masked pass under the causal mask, and its decode step reads
+each slot's whole context once through the block table for all of the
+slot's few queries (kernels/paged_latent.py), the rows the step itself
+wrote after the first query folded in after.
 
 The same function is computed two ways:
 
@@ -221,11 +226,13 @@ def admitted_mask(scores, positions, k: int):
     return seen & (above | (ties & (jnp.cumsum(ties, axis=-1) <= room)))
 
 
-def attend_masked(q_abs, context, admitted, n_keys, scale, v_width: int):
+def attend_masked(q_abs, context, admitted, n_keys, scale, v_width: int,
+                  positions=None):
     """Absorbed attention of one slot's queries over its context under a
     mask, `KEY_BLOCK` keys at a time with an online softmax. q_abs (Q, H,
-    W); context (L, W); admitted (Q, L) bool; `n_keys` () int32: keys from
-    there on are admitted to no query and are not read. -> (Q, H,
+    W); context (L, W); admitted (Q, L) bool, or None with `positions`
+    (Q,): every key at or before a query's position; `n_keys` () int32:
+    keys from there on are admitted to no query and are not read. -> (Q, H,
     v_width)."""
     Q, H, _ = q_abs.shape
     L = context.shape[0]
@@ -233,13 +240,17 @@ def attend_masked(q_abs, context, admitted, n_keys, scale, v_width: int):
     pad = -L % kb
     if pad:
         context = jnp.pad(context, ((0, pad), (0, 0)))
-        admitted = jnp.pad(admitted, ((0, 0), (0, pad)))
+        if admitted is not None:
+            admitted = jnp.pad(admitted, ((0, 0), (0, pad)))
 
     def block(j, carry):
         o, m, l = carry
         keys = jax.lax.dynamic_slice_in_dim(context, j * kb, kb)
-        ok = jax.lax.dynamic_slice_in_dim(admitted, j * kb, kb,
-                                          axis=1)[:, None, :]
+        if admitted is None:
+            ok = (j * kb + jnp.arange(kb) <= positions[:, None])[:, None, :]
+        else:
+            ok = jax.lax.dynamic_slice_in_dim(admitted, j * kb, kb,
+                                              axis=1)[:, None, :]
         s = jnp.einsum("qhw,kw->qhk", q_abs, keys,
                        preferred_element_type=jnp.float32) * scale
         m_new = jnp.maximum(m, jnp.max(jnp.where(ok, s, NEG_INF), axis=-1))
@@ -277,11 +288,16 @@ def _rms(norm, params, x):
 class SparseLatentAttention(Module):
     """One layer's mixer: x (N, T, d_model) -> (N, T, d_model). `indexer`
     (`{"heads", "head_dim"}`) makes it a `full` layer, which scores and
-    selects; None a `shared` one, which is handed a selection."""
+    selects; None a `shared` one, which is handed a selection. With
+    `index_topk` None there is no selection at all: every query admits every
+    earlier position (dense latent attention, as GLM-4.7-Flash's layers
+    have it); its decode step reads each slot's whole context once through
+    the block table (kernels/paged_latent.py)."""
 
     def __init__(self, d_model: int, num_heads: int, q_lora_rank: int,
                  kv_lora_rank: int, qk_nope_head_dim: int,
-                 qk_rope_head_dim: int, v_head_dim: int, index_topk: int,
+                 qk_rope_head_dim: int, v_head_dim: int,
+                 index_topk: Optional[int],
                  rope_theta: float, eps: float,
                  indexer: Optional[dict] = None,
                  name: Optional[str] = None):
@@ -292,6 +308,9 @@ class SparseLatentAttention(Module):
         self.index_topk, self.theta = index_topk, rope_theta
         self.scale = 1.0 / math.sqrt(qk_nope_head_dim + qk_rope_head_dim)
         self.indexer = dict(indexer) if indexer else None
+        if index_topk is None and self.indexer:
+            raise ValueError("an indexer selects index_topk positions: "
+                             "give index_topk or no indexer")
         H = num_heads
         lin = lambda a, b: Linear(a, b, bias=False)            # noqa: E731
         self.add_child("q_a", lin(d_model, q_lora_rank))
@@ -368,7 +387,10 @@ class SparseLatentAttention(Module):
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (N, T))
         cq, q_c, q_r = self._queries(params, x, positions)
         row = self._latent(params, x, positions)
-        if self.indexer:
+        if self.index_topk is None:
+            admitted = jnp.broadcast_to(
+                jnp.arange(T)[None, :] <= jnp.arange(T)[:, None], (N, T, T))
+        elif self.indexer:
             q_idx, w, k_idx = self._index(params, x, cq, positions)
             idx = select_topk(index_scores(q_idx, w, k_idx), positions,
                               self.index_topk)
@@ -446,13 +468,19 @@ class SparseLatentAttention(Module):
                 pools["index"], parts,
                 [k for k, in share(_widened(k_idx, lanes))])
             mine = share(q_abs, _widened(q_idx, lanes), w)
-        elif None in selection:
+        elif None in selection and self.index_topk is not None:
             raise ValueError(_NEEDS_SELECTION)
         outs, selections = [], []
-        for part, given, (q_abs_, *scored) in zip(parts, selection, mine):
+        # a dense layer's step folds in the rows it wrote after the first
+        fresh = (share(rows) if self.index_topk is None
+                 else [(None,)] * len(parts))
+        for part, given, (q_abs_, *scored), (rows_,) in zip(
+                parts, selection, mine, fresh):
             index = (*scored, gather_context(
                 new["index"], part.block_table)) if scored else None
-            if part.decode:
+            if part.decode and self.index_topk is None:
+                out = self._step_dense(q_abs_, latent, rows_, part)
+            elif part.decode:
                 out, given = self._step_rows(
                     q_abs_, latent, index, part.positions,
                     part.block_table, given)
@@ -466,6 +494,47 @@ class SparseLatentAttention(Module):
         a = jnp.einsum("nthl,lhv->nthv", out, w_uv)
         return self._run(params, "o", a.reshape(N, T, H * self.v_dim)), \
             new, tuple(selections) if joined else selections[0]
+
+    def paged_write(self, params, x, pools, positions, block_table, lengths):
+        """The rows of a chunk a slot into the pools and nothing read: what
+        a prompt leaves behind in a layer whose output no later token of
+        the prompt needs (a draft module's, serve/decode.py)."""
+        rows = _widened(self._latent(params, x, positions), self.row_width)
+        return dict(pools, latent=paged_pool_write(
+            pools["latent"], rows, positions, block_table, lengths))
+
+    def _step_dense(self, q_abs, latent, rows, part):
+        """A step's few queries a slot over its whole context: the context
+        through the first query's position, read from the pool once for
+        all of a slot's queries and heads (kernels/paged_latent.py), then
+        the step's later rows (a draft's, just written) folded in by each
+        query at or after them. q_abs (N, T, H, W), rows (N, T, W)."""
+        from bigdl_tpu.kernels.paged_latent import paged_latent_attend
+        N, T, H, W = q_abs.shape
+        first = part.positions[:, 0]
+        o, m, l = paged_latent_attend(
+            q_abs.reshape(N, T * H, W), latent,
+            jnp.where(part.lengths > 0, first + 1, 0), part.block_table,
+            scale=self.scale, v_width=self.kv_rank)
+        o, m, l = (o.reshape(N, T, H, -1), m.reshape(N, T, H),
+                   l.reshape(N, T, H))
+        if T > 1:
+            # query t meets the rows 1..t the step wrote after the first
+            s = jnp.einsum("nthw,nrw->nthr", q_abs, rows[:, 1:],
+                           preferred_element_type=jnp.float32) * self.scale
+            r = jnp.arange(1, T)
+            s = jnp.where((r[None, :] <= jnp.arange(T)[:, None])[None, :,
+                                                                 None, :],
+                          s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            alpha = jnp.exp(m - m_new)
+            o = o * alpha[..., None] + jnp.einsum(
+                "nthr,nrc->nthc", p.astype(rows.dtype),
+                rows[:, 1:, :self.kv_rank],
+                preferred_element_type=jnp.float32)
+            l = l * alpha + jnp.sum(p, axis=-1)
+        return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q_abs.dtype)
 
     def _step_rows(self, q_abs, latent, index, positions, block_table,
                    selection):
@@ -502,6 +571,13 @@ class SparseLatentAttention(Module):
             selection = jax.lax.map(select, (
                 jnp.repeat(jnp.arange(N), T // Q), blocks(q_idx), blocks(w),
                 blocks(positions))).reshape(N, T, -1)
+        if self.index_topk is None:
+            out = jax.lax.map(
+                lambda at: attend_masked(at[0], at[1], None, at[2],
+                                         self.scale, self.kv_rank, at[3]),
+                (q_abs, gather_context(latent, block_table),
+                 positions[:, 0] + lengths, positions))
+            return out, None
         out = jax.lax.map(
             lambda at: attend_masked(*at, self.scale, self.kv_rank),
             (q_abs, gather_context(latent, block_table), selection,

@@ -107,7 +107,13 @@ are composed from them in `DecodeEntry._build`. One optional argument,
 a step's rows with a prompt chunk of streaming slots computed in the same
 pass (nn/attention.carried_rows). GPT2LM and LlamaLM
 (interop/huggingface.py), OlmoHybridLM (interop/olmo_hybrid.py) and
-GlmMoeDsaLM (interop/glm_moe_dsa.py) provide all of it.
+GlmMoeDsaLM (interop/glm_moe_dsa.py) provide all of it. A second optional
+piece, the draft piece (`_DRAFT_CONTRACT`, Glm4MoeLiteLM's multi-token
+prediction module, interop/glm4_moe_lite.py), is served under
+`speculative_tokens=1`: every step verifies the draft the step before it
+made for a slot beside the slot's last token, yields one token or two, and
+drafts anew (`DecodeEntry._build` `_verify`); the tokens are those of the
+serial greedy loop.
 
 On top of the block table sits the **prefix cache**: whole prompt
 blocks finished by prefill are published under a chained token-hash
@@ -167,6 +173,11 @@ _TAKER_POLL_S = 0.0005
 # over a slot batch, its final norm and head (DecodeEntry._build composes the
 # programs from them)
 _PAGED_CONTRACT = ("make_paged_slot_caches", "paged_hidden", "head_logits")
+# the optional draft piece (`speculative_tokens=1`): a module that drafts the
+# token after next from a step's hidden states (`paged_draft`, with its own
+# `draft_logits`), whose pool a prompt fills through `paged_hidden(...,
+# follow=)`
+_DRAFT_CONTRACT = ("paged_draft", "draft_logits")
 
 
 class BlockPool:
@@ -278,10 +289,14 @@ class PrefixCache:
         pool.evict_one = self._evict_lru
 
     @staticmethod
-    def chain_keys(prompt: np.ndarray, block: int,
-                   prefill_target: int) -> List[bytes]:
+    def chain_keys(prompt: np.ndarray, block: int, prefill_target: int,
+                   lookahead: int = 0) -> List[bytes]:
         """The chained hash key of every whole prompt block inside the
-        prefill region (tokens [0, prefill_target))."""
+        prefill region (tokens [0, prefill_target)). `lookahead` 1 keys a
+        block with the token after it too, for a model whose rows of a
+        block depend on it (a draft module's row of the pair (h_j,
+        t_j+1), serve/decode.py `DecodeEntry.drafting`): two prompts that
+        share a block and differ in the next token share no entry."""
         import hashlib
         keys: List[bytes] = []
         h = b""
@@ -290,7 +305,11 @@ class PrefixCache:
                 h + np.ascontiguousarray(
                     prompt[j * block:(j + 1) * block]).tobytes(),
                 digest_size=16).digest()
-            keys.append(h)
+            end = (j + 1) * block
+            keys.append(h if not lookahead else hashlib.blake2b(
+                h + np.ascontiguousarray(
+                    prompt[end:end + lookahead]).tobytes(),
+                digest_size=16).digest())
         return keys
 
     def cached_count(self) -> int:
@@ -424,7 +443,8 @@ class DecodeEntry:
                  prefix_cache: Optional[bool] = None,
                  prefix_cache_blocks: Optional[int] = None,
                  sampling: Optional[bool] = None,
-                 kv_shard: Optional[bool] = None):
+                 kv_shard: Optional[bool] = None,
+                 speculative_tokens: Optional[int] = None):
         from bigdl_tpu.utils import config
         # `paged` (here, on ModelEntry and on ServeEngine.register) is kept
         # only because benchmark/configs/*-serve.json hold `"paged": true`
@@ -505,6 +525,23 @@ class DecodeEntry:
                             else self.num_slots * self.blocks_per_slot)
         self.sampling = (bool(config.get("SERVE_SAMPLING"))
                          if sampling is None else bool(sampling))
+        # tokens a step drafts a slot (0 or 1): with 1, a step verifies the
+        # draft of the step before it beside the slot's last token and
+        # advances a slot by one or two (`_build`, `_verify`)
+        self.drafting = int(speculative_tokens or 0)
+        if self.drafting not in (0, 1):
+            raise ValueError(f"speculative_tokens={speculative_tokens}: a "
+                             f"step verifies one drafted token or none")
+        if self.drafting:
+            lacks = [m for m in _DRAFT_CONTRACT if not hasattr(model, m)]
+            if lacks:
+                raise TypeError(
+                    f"speculative_tokens=1 needs a model with the draft "
+                    f"piece {_DRAFT_CONTRACT}; {type(model).__name__} lacks "
+                    f"{lacks} (docs/serving.md)")
+            if self.sampling:
+                raise ValueError("speculative_tokens=1 verifies greedy "
+                                 "tokens: register without sampling")
         if self.slot_state and prefix_cache:
             raise ValueError(
                 f"prefix_cache=True cannot serve {type(model).__name__}: "
@@ -567,6 +604,7 @@ class DecodeEntry:
             f"{what} = {self.kv_cache_bytes:,} bytes + {to_place:,} bytes "
             f"of params yet to be placed)")
         self._jit_decode = None
+        self._jit_verify = None
         self._jit_carry = None
         self._jit_prefill = None
         self._jit_prefill_rows = None
@@ -633,11 +671,13 @@ class DecodeEntry:
             n_samp = 4 if self.sampling else 0
             kw_d["in_shardings"] = (rep, cache_sh) + (rep,) * (4 + n_samp)
             kw_d["out_shardings"] = (rep, cache_sh)
-            kw_p["in_shardings"] = (rep, cache_sh) + (rep,) * 4
+            kw_p["in_shardings"] = (rep, cache_sh) + (rep,) * (
+                4 + self.drafting)
             kw_p["out_shardings"] = cache_sh
         kw_r = dict(kw_p)
         if "in_shardings" in kw_r:
-            kw_r["in_shardings"] += (self._rep_sharding,)
+            kw_r["in_shardings"] = kw_r["in_shardings"][:6] + (
+                self._rep_sharding,) * (1 + self.drafting)
         # the programs, composed from what the model owns: the token choice
         # is the server's, greedy or sampled as the registration says
         jnp = jax.numpy
@@ -678,35 +718,79 @@ class DecodeEntry:
                 decode=True, chunk=(ct, cpos, cbt, cln, cslots))
             return chosen(p, c, x, pos, *samp)
 
-        def _prefill(p, c, t, pos, bt, ln):
+        def _prefill(p, c, t, pos, bt, ln, *follow):
             # lengths masks the rounded-up bucket's padded tail (and
-            # inactive rows) out of the pool write; no logits
-            return model.paged_hidden(p, c, t, pos, bt, ln, decode=False)[1]
+            # inactive rows) out of the pool write; no logits. `follow`
+            # (drafting): the token after each, for the draft module's rows
+            kw = {"follow": follow[0]} if follow else {}
+            return model.paged_hidden(p, c, t, pos, bt, ln, decode=False,
+                                      **kw)[1]
 
         mask = self._slot_mask
 
-        def _prefill_rows(p, c, t, pos, bt, ln, slots):
+        def _prefill_rows(p, c, t, pos, bt, ln, slots, *follow):
             # the same chunk over the rows of the slots `slots` (R,) and no
             # others: a pool is found through a row's block table whatever
             # the row count; a leaf resident by slot gives the call its rows
             # and takes them back where they lay, so a slot that does not
             # stream is never read
             if mask is None:
-                return _prefill(p, c, t, pos, bt, ln)
+                return _prefill(p, c, t, pos, bt, ln, *follow)
             own = jax.tree.map(
                 lambda by_slot, a: a.at[slots].get(
                     mode="promise_in_bounds") if by_slot else a, mask, c)
             return jax.tree.map(
                 lambda by_slot, a, new: a.at[slots].set(
                     new, mode="promise_in_bounds", unique_indices=True)
-                if by_slot else new, mask, c, _prefill(p, own, t, pos, bt, ln))
+                if by_slot else new, mask, c,
+                _prefill(p, own, t, pos, bt, ln, *follow))
+
+        def _verify(p, c, rows, a, bt, *chunk):
+            # `rows` (4, S): each slot's last token, its draft, whether it
+            # has one, the last token's position. The main model computes
+            # both (the draft's row only where there is one), the draft is
+            # accepted where the last token's argmax is the draft, and the
+            # draft module then drafts from the last pair it knows: (h_n,
+            # a) and, accepted, (h_n+1, b). With `chunk` (the carried
+            # prompt chunk's five and the tokens that follow its tokens)
+            # the same pass advances a streaming slot too.
+            t, d, has, pos = rows
+            S = t.shape[0]
+            ln = a.astype(jnp.int32)
+            two = pos[:, None] + jnp.arange(2, dtype=jnp.int32)
+            kw = ({"chunk": tuple(chunk[:5]), "follow": chunk[5]} if chunk
+                  else {})
+            x, c = model.paged_hidden(p, c, jnp.stack([t, d], axis=1), two,
+                                      bt, ln * (1 + has), decode=True, **kw)
+            best = jnp.argmax(model.head_logits(
+                p, x.reshape(2 * S, 1, -1)), axis=-1).astype(
+                    jnp.int32).reshape(S, 2)
+            acc = ((has > 0) & (best[:, 0] == d)).astype(jnp.int32)
+            g, c = model.paged_draft(p, c, x, best, two, bt, ln * (1 + acc))
+            draft = jnp.argmax(model.draft_logits(p, jnp.where(
+                acc[:, None, None] > 0, g[:, 1:], g[:, :1])),
+                axis=-1).astype(jnp.int32)
+            out = [best[:, 0], best[:, 1], acc, draft, pos + 1 + acc]
+            if counted:
+                out.append(model.step_counters(c).astype(jnp.int32))
+            return jnp.concatenate(out), c
 
         self._jit_decode = jax.jit(_step, **kw_d)
+        # a drafting registration's step: (params, caches, rows (4, S),
+        # active, table); the plain step stays for what compiles it alone
+        # (benchmark/rehearse_compile.py)
+        kw_v = dict(kw_d)
+        if "in_shardings" in kw_v:
+            kw_v["in_shardings"] = kw_v["in_shardings"][:5]
+        self._jit_verify = (jax.jit(_verify, **kw_v) if self.drafting
+                            else None)
         if self.carried:
-            kw_c = dict(kw_d)
+            kw_c = dict(kw_v if self.drafting else kw_d)
             if "in_shardings" in kw_c:
-                kw_c["in_shardings"] += (self._rep_sharding,) * 5
-            self._jit_carry = jax.jit(_carry, **kw_c)
+                kw_c["in_shardings"] += (self._rep_sharding,) * (
+                    5 + self.drafting)
+            self._jit_carry = jax.jit(_verify if self.drafting else _carry,
+                                      **kw_c)
         self._jit_prefill = jax.jit(_prefill, **kw_p)
         self._jit_prefill_rows = jax.jit(_prefill_rows, **kw_r)
         # the next step's input tokens while this step's are still on the
@@ -716,7 +800,17 @@ class DecodeEntry:
                 {"in_shardings": (self._rep_sharding,) * 3,
                  "out_shardings": self._rep_sharding})
         S = self.num_slots
+
+        def _merge_rows(use_prev, prev, host):
+            # a drafting step's rows (4, S) from `_verify`'s output: the
+            # last token (the draft's argmax where it was accepted), the
+            # new draft, one, the last token's position
+            a, b, acc, d, at = (prev[i * S:(i + 1) * S] for i in range(5))
+            dev = jnp.stack([jnp.where(acc > 0, b, a), d,
+                             jnp.ones_like(a), at])
+            return jnp.where(use_prev[None], dev, host)
         self._jit_merge = jax.jit(
+            _merge_rows if self.drafting else
             lambda use_prev, prev, host: jnp.where(
                 use_prev, prev[:S] if counted else prev, host), **kw_m)
 
@@ -800,17 +894,20 @@ class DecodeEntry:
         table = spec((S, M), i32)
         samp = ((spec((S,), f32), vec, spec((S,), f32), vec)
                 if self.sampling else ())
-        d_args = (p_s, c_s, vec, vec, act, table) + samp
+        d_args = ((p_s, c_s, spec((4, S), i32), act, table)
+                  if self.drafting else (p_s, c_s, vec, vec, act, table)
+                  + samp)
         results: Dict[str, Dict] = {}
         cost, self._aot_decode = precompile_fixed(
-            self._jit_decode, d_args,
+            self._jit_verify or self._jit_decode, d_args,
             name=f"serve/{self.name}/decode/step")
         self._assert_pool_sharding(self._aot_decode)
         results["decode_step"] = cost
         row = spec((1,), i32)
         for b in self.buckets:
             chunk = spec((1, b), i32)
-            one_row = (chunk, chunk, spec((1, M), i32), row, row)
+            one_row = (chunk, chunk, spec((1, M), i32), row, row) + (
+                (chunk,) * self.drafting)
             cost, exe = precompile_fixed(
                 self._jit_prefill_rows, (p_s, c_s) + one_row,
                 name=f"serve/{self.name}/decode/prefill{b}")
@@ -827,13 +924,16 @@ class DecodeEntry:
         C = self.prefill_chunk
         chunk = spec((S, C), i32)
         cost, self._aot_prefill_all = precompile_fixed(
-            self._jit_prefill, (p_s, c_s, chunk, chunk, table, vec),
+            self._jit_prefill, (p_s, c_s, chunk, chunk, table, vec)
+            + (chunk,) * self.drafting,
             name=f"serve/{self.name}/decode/prefill{C}x{S}")
         self._assert_pool_sharding(self._aot_prefill_all)
         results[f"prefill{C}x{S}"] = cost
+        out = (5 if self.drafting else 1) * S + len(self.counter_names)
         cost, self._aot_merge = precompile_fixed(
             self._jit_merge,
-            (act, spec((S + len(self.counter_names),), i32), vec),
+            (act, spec((out,), i32),
+             spec((4, S), i32) if self.drafting else vec),
             name=f"serve/{self.name}/decode/merge")
         results["merge"] = cost
         return results
@@ -870,15 +970,18 @@ class DecodeEntry:
 
     # ------------------------------------------------------------ device
     def run_prefill(self, caches, tokens: np.ndarray, positions, table,
-                    lengths, slots: Optional[np.ndarray] = None):
+                    lengths, slots: Optional[np.ndarray] = None,
+                    follow: Optional[np.ndarray] = None):
         """One chunk-prefill program call; returns the new caches (the
         input cache buffers are donated on TPU). `slots` (R,) int32 names
         the slots whose rows `tokens`, `positions` (R, C), `table` (R, M)
-        and `lengths` (R,) hold; None: row i is slot i, all `num_slots`."""
+        and `lengths` (R,) hold; None: row i is slot i, all `num_slots`.
+        `follow` (R, C), drafting: the token after each of `tokens`."""
         C = tokens.shape[1]
         by_row = slots is not None
         host = (tokens, positions, table, lengths) + ((slots,) if by_row
-                                                      else ())
+                                                      else ()) + (
+            (follow,) if self.drafting else ())
         args = (self.placed_params(), caches) + \
             tuple(self._place(a) for a in host)
         if by_row:
@@ -912,12 +1015,14 @@ class DecodeEntry:
         block_table[, temps, top_ks, top_ps, seeds]) and, where the step
         carries a prompt chunk, a one-row prefill call's five behind them
         (tokens (1, C), positions, the slot's block-table row, its length,
-        its index), C one of `carried`."""
+        its index), C one of `carried`. A drafting step takes the rows (4,
+        S) of `_verify` for `tokens_last`, then (active, block_table) and
+        the chunk's five and its `follow`."""
         args = (self.placed_params(), caches,
                 self._place(tokens_last)) + \
             tuple(self._place(a) for a in rest)
-        C = (rest[-5].shape[1]
-             if len(rest) > (7 if self.sampling else 3) else None)
+        n_step = 2 if self.drafting else 7 if self.sampling else 3
+        C = rest[n_step + 1].shape[1] if len(rest) > n_step else None
         exe = self._aot_decode if C is None else self._aot_carry.get(C)
         if exe is not None:
             try:
@@ -930,11 +1035,13 @@ class DecodeEntry:
                     self._aot_decode = None
                 else:
                     self._aot_carry.pop(C)
-        return (self._jit_decode if C is None else self._jit_carry)(*args)
+        return (self._jit_verify or self._jit_decode if C is None
+                else self._jit_carry)(*args)
 
     def merge_tokens(self, use_prev: np.ndarray, prev_next, host_tokens):
         """`where(use_prev, prev_next, host_tokens)` over the slots, on the
-        device: `prev_next` is the unfetched output of the step in flight."""
+        device: `prev_next` is the unfetched output of the step in flight
+        (drafting: the rows (4, S) it leads to, `_merge_rows`)."""
         merge = self._aot_merge or self._jit_merge
         return merge(*(self._place(a)
                        for a in (use_prev, prev_next, host_tokens)))
@@ -1011,7 +1118,7 @@ class _GenRequest:
                  "t_admit", "t_first", "fed", "generated", "slot", "order",
                  "temperature", "top_k", "top_p", "seed",
                  "need_blocks", "reserved", "shared", "keys",
-                 "committed", "commit_upto")
+                 "committed", "commit_upto", "draft")
 
     def __init__(self, prompt: np.ndarray, max_new: int, eos_id: int,
                  t_submit: float, temperature: float = 0.0,
@@ -1040,6 +1147,9 @@ class _GenRequest:
         self.keys: List[bytes] = []        # chain keys (prefill region)
         self.committed: List[int] = []     # key idxs THIS req committed
         self.commit_upto = 0               # next key idx to consider
+        # drafting: the token drafted after the last generated one, as the
+        # last fetched step left it (None before the first)
+        self.draft: Optional[int] = None
 
     @property
     def prefill_target(self) -> int:
@@ -1060,11 +1170,13 @@ class _Step(NamedTuple):
     the device array of next tokens, the requests it computes a row for
     (matched by identity when the tokens arrive: a slot may have changed
     hands meanwhile), when it was dispatched and the longest context
-    (tokens a row may attend) among its rows."""
+    (tokens a row may attend) among its rows; drafting, whether each row
+    verifies a draft."""
     nxt: object
     rows: List[_GenRequest]
     t_dispatch: float
     context: int
+    drafted: Tuple[bool, ...] = ()
 
 
 class DecodeScheduler:
@@ -1162,6 +1274,11 @@ class DecodeScheduler:
             f"serve/{n}/decode/steps_ahead")
         self._m_rows_dropped = observe.counter(
             f"serve/{n}/decode/rows_dropped")
+        # drafting: the drafts verified (rows of a delivered step that had
+        # one) and, of those, the ones accepted
+        self._m_draft_rows = observe.counter(f"serve/{n}/decode/draft_rows")
+        self._m_draft_accepted = observe.counter(
+            f"serve/{n}/decode/draft_accepted")
         self._m_tps = observe.gauge(f"serve/{n}/decode/tokens_per_s")
         self._m_active = observe.gauge(f"serve/{n}/decode/active_slots")
         self._m_queued = observe.gauge(f"serve/{n}/decode/queued")
@@ -1262,11 +1379,13 @@ class DecodeScheduler:
                 f"model {self.name!r} was registered without the "
                 f"sampling decode step — register(sampling=True) or "
                 f"BIGDL_TPU_SERVE_SAMPLING=1 to serve temperature > 0")
-        total = prompt.size - 1 + int(max_new_tokens)
+        # a drafting step writes its draft's row one position further
+        total = prompt.size - 1 + int(max_new_tokens) + self.entry.drafting
         if total > self.entry.max_seq_len:
             raise ValueError(
                 f"prompt({prompt.size}) - 1 + max_new({max_new_tokens}) "
-                f"= {total} exceeds the slot cache length "
+                f"{'+ 1 drafted ' if self.entry.drafting else ''}= {total} "
+                f"exceeds the slot cache length "
                 f"{self.entry.max_seq_len} (BIGDL_TPU_SERVE_MAX_SEQ_LEN"
                 f" / register(max_seq_len=...))")
         eos = self.entry.eos_id if eos_id is None else int(eos_id)
@@ -1364,7 +1483,8 @@ class DecodeScheduler:
         B = self.entry.kv_block
         if self._prefix is not None:
             req.keys = PrefixCache.chain_keys(req.prompt, B,
-                                              req.prefill_target)
+                                              req.prefill_target,
+                                              self.entry.drafting)
             m = self._prefix.peek(req.keys)
         else:
             req.keys, m = [], 0
@@ -1385,10 +1505,12 @@ class DecodeScheduler:
     def _ensure_blocks(self, req: _GenRequest, last_pos: int) -> None:
         """Acquire the slot's private blocks through the one covering
         `last_pos` (lock held) — the lazy frontier-crossing acquisition;
-        the admission reservation guarantees success."""
+        the admission reservation guarantees success. No further than
+        the request's own blocks: a drafting step's row past a sequence's
+        end writes nowhere (an unacquired block)."""
         row = self._tables[req.slot]
-        for j in np.flatnonzero(row[:last_pos // self.entry.kv_block + 1]
-                                < 0):
+        upto = min(last_pos // self.entry.kv_block + 1, req.need_blocks)
+        for j in np.flatnonzero(row[:upto] < 0):
             row[j] = self._pool.acquire_reserved()
             req.reserved -= 1
 
@@ -1515,11 +1637,15 @@ class DecodeScheduler:
         tokens = np.zeros((R, C), np.int32)
         positions = np.zeros((R, C), np.int32)
         lengths = np.zeros((R,), np.int32)
+        # drafting: the token after each, for the draft module's rows
+        follow = np.zeros((R, C), np.int32) if self.entry.drafting else None
         for i, req in zip(rows, reqs):
             n = min(req.prefill_target - req.fed, C)
             tokens[i, :n] = req.prompt[req.fed:req.fed + n]
             positions[i] = req.fed + np.arange(C)
             lengths[i] = n
+            if follow is not None:
+                follow[i, :n] = req.prompt[req.fed + 1:req.fed + n + 1]
         with self._cv:
             for i, req in zip(rows, reqs):
                 self._ensure_blocks(req, req.fed + int(lengths[i]) - 1)
@@ -1539,7 +1665,7 @@ class DecodeScheduler:
                 # inactive rows) out of the pool scatter
                 self._caches = self.entry.run_prefill(
                     self._caches, tokens, positions, table, lengths,
-                    slots if by_row else None)
+                    slots if by_row else None, follow)
             self._h_prefill.record(max(0.0, (self._clock() - t0) * 1e3))
         self._m_prefill_calls.inc()
         self._m_prefill_rows.inc(R)
@@ -1549,7 +1675,10 @@ class DecodeScheduler:
             req.fed += min(req.prefill_target - req.fed, C)
             if self._prefix is not None:
                 self._commit_prefix(req)
-        return (tokens, positions, table, lengths, slots) if carried else None
+        if not carried:
+            return None
+        return (tokens, positions, table, lengths, slots) + (
+            (follow,) if follow is not None else ())
 
     def _commit_prefix(self, req: _GenRequest) -> None:
         """Publish the whole prompt blocks `req`'s prefill frontier has
@@ -1611,6 +1740,8 @@ class DecodeScheduler:
         prompt chunk it carries (`_prefill_pass`) included."""
         if not step_rows:
             return None
+        if self.entry.drafting:
+            return self._dispatch_verify(prev, step_rows, chunk)
         S = self.entry.num_slots
         tokens = np.zeros((S,), np.int32)
         positions = np.zeros((S,), np.int32)
@@ -1654,6 +1785,50 @@ class DecodeScheduler:
         self._m_step_context.inc(int(contexts.sum()))
         return _Step(nxt, rows, t0, int(contexts.max()))
 
+    def _dispatch_verify(self, prev: Optional[_Step],
+                         step_rows: List[Tuple[_GenRequest, bool]],
+                         chunk: Optional[tuple]) -> _Step:
+        """`_dispatch_step` of a drafting registration: a row is (last
+        token, its draft, whether there is one, the last token's position).
+        A row that continues from `prev` takes all four from `prev` on the
+        device, since how far `prev` advances it (one or two) is known
+        there alone; the host reserves blocks for either. A row the host
+        feeds verifies the draft the last fetched step left it, if any."""
+        S = self.entry.num_slots
+        host = np.zeros((4, S), np.int32)
+        active = np.zeros((S,), bool)
+        use_prev = np.zeros((S,), bool)
+        drafted = []
+        for req, ahead in step_rows:
+            tok, pos = req.next_input()
+            if ahead:
+                # `prev` yields one token or two: its last one and that
+                # one's draft lie at most three positions on
+                use_prev[req.slot] = True
+                pos += 3
+            else:
+                has = req.draft is not None
+                host[:, req.slot] = (tok, req.draft if has else 0, has, pos)
+                pos += 1
+            drafted.append(ahead or req.draft is not None)
+            active[req.slot] = True
+            with self._cv:
+                self._ensure_blocks(req, pos)
+        with self._cv:
+            table = self._tables.copy()
+        t0 = self._clock()
+        rows = (self.entry.merge_tokens(use_prev, prev.nxt, host)
+                if use_prev.any() else host)
+        nxt, self._caches = self.entry.run_decode(
+            self._caches, rows, active, table, *(chunk or ()))
+        if prev is not None:
+            self._m_steps_ahead.inc()
+        reqs = [req for req, _ in step_rows]
+        # the work of a row is counted when its tokens are known (_deliver)
+        return _Step(nxt, reqs, t0, int(max(req.next_input()[1] + 2
+                                            for req in reqs)),
+                     tuple(drafted))
+
     def _deliver(self, step: _Step) -> None:
         """Fetch a step's tokens (the iteration's single host sync), push
         them to their streams and retire the sequences that end. A row
@@ -1664,16 +1839,14 @@ class DecodeScheduler:
         with sanctioned_sync("decode next-token fetch"):
             nxt = np.asarray(jax.device_get(step.nxt))
         now = self._clock()
+        if self.entry.drafting:
+            return self._deliver_verified(step, nxt, now)
         # what the iteration cost a token: from dispatch when nothing was
         # in flight before it, else from the fetch before this one
         self._h_step.record(
             max(0.0, (now - max(step.t_dispatch, self._t_fetched)) * 1e3))
         self._t_fetched = now
-        for i, m in enumerate(self._m_model):
-            # running int32 totals of the device, which wrap
-            total = int(nxt[self.entry.num_slots + i])
-            m.inc((total - self._model_counts[i]) & 0xFFFFFFFF)
-            self._model_counts[i] = total
+        self._count_model(nxt[self.entry.num_slots:])
         live = [r for r in step.rows if self._slots[r.slot] is r]
         self._m_rows_dropped.inc(len(step.rows) - len(live))
         self._h_occ.record(len(step.rows) / self.entry.num_slots)
@@ -1694,6 +1867,62 @@ class DecodeScheduler:
             if tok == req.eos_id or len(req.generated) >= req.max_new:
                 self._retire(req, now)
         self._m_active.set(self.active_slots)
+
+    def _deliver_verified(self, step: _Step, nxt: np.ndarray,
+                          now: float) -> None:
+        """`_deliver` of a drafting step: a row yields its last token's
+        argmax and, where the draft was accepted, the draft's argmax too;
+        a sequence may end at either. The context of each token delivered
+        is counted here, where its position is known."""
+        S = self.entry.num_slots
+        a, b, acc, draft, at = (nxt[i * S:(i + 1) * S] for i in range(5))
+        self._h_step.record(
+            max(0.0, (now - max(step.t_dispatch, self._t_fetched)) * 1e3))
+        self._t_fetched = now
+        self._count_model(nxt[5 * S:])
+        live = [(r, d) for r, d in zip(step.rows, step.drafted)
+                if self._slots[r.slot] is r]
+        self._m_rows_dropped.inc(len(step.rows) - len(live))
+        self._h_occ.record(len(step.rows) / self.entry.num_slots)
+        self._m_steps.inc()
+        delivered, contexts = 0, []
+        for req, drafted in live:
+            s = req.slot
+            if drafted:
+                self._m_draft_rows.inc()
+                self._m_draft_accepted.inc(int(acc[s]))
+            # the first row's position: the last token's, less the advance
+            first = int(at[s]) - 1 - int(acc[s])
+            self._m_step_context.inc(first + 1)
+            for k, tok in enumerate([a[s], b[s]][:1 + int(acc[s])]):
+                tok = int(tok)
+                req.generated.append(tok)
+                req.reply._push(tok)
+                delivered += 1
+                contexts.append(first + 1 + k)
+                if req.t_first is None:
+                    req.t_first = now
+                    self._h_ttft.record(
+                        max(0.0, (now - req.t_submit) * 1e3))
+                if tok == req.eos_id or len(req.generated) >= req.max_new:
+                    self._retire(req, now)
+                    break
+            else:
+                req.draft = int(draft[s])
+        self._count_context(np.asarray(contexts, np.int64))
+        self._m_tokens.inc(delivered)
+        self._win_tokens += delivered
+        if now - self._win_t0 >= 0.5:
+            self._m_tps.set(self._win_tokens / (now - self._win_t0))
+            self._win_t0, self._win_tokens = now, 0
+        self._m_active.set(self.active_slots)
+
+    def _count_model(self, totals) -> None:
+        """The model's running int32 totals of the device, which wrap."""
+        for i, m in enumerate(self._m_model):
+            total = int(totals[i])
+            m.inc((total - self._model_counts[i]) & 0xFFFFFFFF)
+            self._model_counts[i] = total
 
     def _retire(self, req: _GenRequest, now: float) -> None:
         self._slots[req.slot] = None
@@ -1951,6 +2180,8 @@ class DecodeScheduler:
             "steps": int(self._m_steps.value),
             "steps_ahead": int(self._m_steps_ahead.value),
             "rows_dropped": int(self._m_rows_dropped.value),
+            "draft_rows": int(self._m_draft_rows.value),
+            "draft_accepted": int(self._m_draft_accepted.value),
             "state": self.entry.state_kind,
             "kv_pool_bytes": self.entry.kv_pool_bytes,
             "state_bytes": self.entry.state_bytes,

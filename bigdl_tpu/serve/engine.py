@@ -156,7 +156,8 @@ class ServeEngine:
                  prefix_cache: Optional[bool] = None,
                  prefix_cache_blocks: Optional[int] = None,
                  sampling: Optional[bool] = None,
-                 kv_shard: Optional[bool] = None) -> ModelEntry:
+                 kv_shard: Optional[bool] = None,
+                 speculative_tokens: Optional[int] = None) -> ModelEntry:
         """Register a model and start its scheduler. `precompile_input`
         = (feature_shape, dtype) AOT-compiles every bucket up front.
 
@@ -172,7 +173,10 @@ class ServeEngine:
         BIGDL_TPU_SERVE_{PREFIX_CACHE,SAMPLING} knobs (paged KV block
         pool + shared-prefix reuse — docs/serving.md). `paged` takes
         None or True (DecodeEntry says why it is still here); False is
-        refused: the dense slot bucket was removed.
+        refused: the dense slot bucket was removed. `speculative_tokens`
+        1 makes a model that carries a draft module (docs/serving.md)
+        draft one token a step for every decoding slot and verify it in
+        the next (greedy only).
 
         Admission is memory-checked (observe/memz.py): params+state —
         and for decode the closed-form KV pool, BEFORE allocation —
@@ -193,7 +197,7 @@ class ServeEngine:
             kv_block=kv_block, kv_pool_blocks=kv_pool_blocks,
             prefix_cache=prefix_cache,
             prefix_cache_blocks=prefix_cache_blocks, sampling=sampling,
-            kv_shard=kv_shard)
+            kv_shard=kv_shard, speculative_tokens=speculative_tokens)
         from bigdl_tpu.resilience import faults
         if decode:
             if precompile_decode:

@@ -105,7 +105,8 @@ class ModelEntry:
                  prefix_cache: Optional[bool] = None,
                  prefix_cache_blocks: Optional[int] = None,
                  sampling: Optional[bool] = None,
-                 kv_shard: Optional[bool] = None):
+                 kv_shard: Optional[bool] = None,
+                 speculative_tokens: Optional[int] = None):
         from bigdl_tpu.utils import config
         self.name = name
         self.mesh = mesh
@@ -156,7 +157,8 @@ class ModelEntry:
                 eos_id=eos_id, paged=paged, kv_block=kv_block,
                 kv_pool_blocks=kv_pool_blocks, prefix_cache=prefix_cache,
                 prefix_cache_blocks=prefix_cache_blocks,
-                sampling=sampling, kv_shard=kv_shard)
+                sampling=sampling, kv_shard=kv_shard,
+                speculative_tokens=speculative_tokens)
 
     def precompile_decode(self) -> Dict[str, Dict]:
         """AOT-compile the decode step + every prefill-chunk bucket

@@ -410,3 +410,19 @@ def test_glm_carrying_step_fits_beside_its_weights_and_pools(one_chip):
     needs = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert needs < HBM_BYTES and m.temp_size_in_bytes < 2 ** 29, m
+
+
+def test_paged_latent_kernel_at_glm47_flash_widths(one_chip):
+    """GLM-4.7-Flash's decode-step attention (`glm-4.7-flash-serve`): 64
+    slots of 2 query rows x 20 heads over rows of 640 bfloat16 values, 281
+    blocks of 64 a slot, a pool of 3,712 blocks: the block-table walk
+    compiles as the Pallas kernel, within its fast memory."""
+    from bigdl_tpu.kernels.paged_latent import paged_latent_attend
+    f = jax.jit(lambda q, pool, n, table: paged_latent_attend(
+        q, pool, n, table, scale=1 / 16, v_width=512))
+    compiled = f.lower(_sds(one_chip, (64, 40, 640), jnp.bfloat16),
+                       _sds(one_chip, (3712, 64, 640), jnp.bfloat16),
+                       _sds(one_chip, (64,), np.int32),
+                       _sds(one_chip, (64, 281), np.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
