@@ -16,10 +16,11 @@ expert's; what the absent experts would add is left out (on one chip there
 is no exchange, and nothing stands in for one). No token is dropped
 whatever the routing: the token-expert pairs are sorted by expert, every
 pair has its row, and the held experts' part is ONE grouped matrix product a
-projection (`jax.lax.ragged_dot`: rows of a group meet that group's
-matrix); pairs of absent experts sort behind the last group, where no
-matrix meets them. `parallel/moe.py` `MoE` (ReLU, top-1, capacity) is
-another layer and stays as it is.
+projection (`kernels/grouped_matmul.py`: rows of a group meet that group's
+matrix, on the TPU a Pallas kernel whose row tile fits the rows a group
+gets, else `jax.lax.ragged_dot`); pairs of absent experts sort behind the
+last group, where no matrix meets them. `parallel/moe.py` `MoE` (ReLU,
+top-1, capacity) is another layer and stays as it is.
 """
 
 from __future__ import annotations
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu.core import init as initializers
 from bigdl_tpu.core.module import Module, ParamSpec
+from bigdl_tpu.kernels.grouped_matmul import grouped_matmul
 from bigdl_tpu.nn.linear import Linear
 
 
@@ -48,9 +50,9 @@ def grouped_ffn(x, gate, up, down, group_sizes):
     """SwiGLU over rows sorted by group: rows `[sum(sizes[:g]),
     sum(sizes[:g+1]))` of x (m, d) meet expert g of gate/up (n, d, f) and
     down (n, f, d). Rows past the last group come out as they may."""
-    h = jax.nn.silu(jax.lax.ragged_dot(x, gate, group_sizes)) \
-        * jax.lax.ragged_dot(x, up, group_sizes)
-    return jax.lax.ragged_dot(h, down, group_sizes)
+    h = jax.nn.silu(grouped_matmul(x, gate, group_sizes)) \
+        * grouped_matmul(x, up, group_sizes)
+    return grouped_matmul(h, down, group_sizes)
 
 
 class GatedExperts(Module):

@@ -426,3 +426,47 @@ def test_paged_latent_kernel_at_glm47_flash_widths(one_chip):
                        _sds(one_chip, (64, 281), np.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
+
+
+def _pallas_blocks(jaxpr):
+    """The block shapes of every Pallas call in `jaxpr` and the jaxprs
+    nested in it (`platform_dependent`'s branches among them)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append([tuple(getattr(b, "block_size", None)
+                                for b in bm.block_shape)
+                          for bm in eqn.params["grid_mapping"].block_mappings])
+        for v in eqn.params.values():
+            for j in (v if isinstance(v, (tuple, list)) else (v,)):
+                if hasattr(j, "jaxpr") and hasattr(j.jaxpr, "eqns"):
+                    found += _pallas_blocks(j.jaxpr)
+    return found
+
+
+def test_expert_layer_at_glm47_flash_decode_widths(one_chip):
+    """GLM-4.7-Flash's routed layer in its verifying step
+    (`glm-4.7-flash-serve`): 64 slots x 2 rows, top-4 of 64 experts of
+    2048 -> 1536, so the grouped products see (512, 2048) x (64, 2048,
+    1536). They compile as three `expert_gmm` Pallas calls and no
+    `ragged-dot` (whose row tile of 512 multiplies every pair by every
+    expert), with the rule's decode tile of 128 rows and the depth in one
+    block, and small temporaries."""
+    from bigdl_tpu.kernels.grouped_matmul import tiling
+    from bigdl_tpu.nn.experts import GatedExperts
+    layer = GatedExperts(2048, 1536, 64, 4, scaling=1.8)
+    params, _ = jax.eval_shape(layer.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, jnp.bfloat16),
+                          params)
+    x = _sds(one_chip, (128, 2048), jnp.bfloat16)
+    f = jax.jit(lambda p, x: layer.mixed(p, x))
+    compiled = f.lower(params, x).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert len(re.findall(r"%expert_gmm(\.\d+)? = ", text)) == 3
+    assert tiling(512, 2048, 1536, 64) == (128, 2048, 1536)
+    blocks = _pallas_blocks(jax.make_jaxpr(f)(params, x).jaxpr)
+    assert sorted(b[0] for b in blocks) == [(128, 1536), (128, 2048),
+                                            (128, 2048)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+    _fits(compiled)
