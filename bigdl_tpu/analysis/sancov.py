@@ -25,8 +25,9 @@ dynamic half — a TSan-flavoured, pure-Python, opt-in sanitizer:
     registry, the serve batcher queue, the statusz engine list and the
     autotune table.
   * **Host-sync sanitizer** (``sync`` mode) — wraps ``jax.device_get``
-    so an un-sanctioned device→host fetch inside an instrumented phase
-    span (``observe.phase``) is reported and attributed to that phase.
+    so an un-sanctioned device→host fetch inside an instrumented span
+    (``observe.span`` / ``observe.phase``) is reported and attributed to
+    the innermost one.
     The legitimate fetch points (the trainer's flush fetch, the serve
     dispatch fetch, checkpoint gather, bench timing) are marked with
     :func:`sanctioned_sync` — everything else inside the hot loop is a
@@ -387,8 +388,8 @@ def _install_sync_guard() -> bool:
     if jax.device_get is not _guarded_device_get:
         jax.device_get = _guarded_device_get
     if not _phase_hook_installed:
-        from bigdl_tpu.observe import metrics as _metrics
-        _metrics.set_phase_hook(_on_phase)
+        from bigdl_tpu.observe import trace as _trace
+        _trace.set_phase_hook(_on_phase)
         _phase_hook_installed = True
     SYNC_ON = True
     return True
@@ -403,8 +404,8 @@ def _uninstall_sync_guard() -> None:
         import jax
         jax.device_get = _real_device_get
     if _phase_hook_installed:
-        from bigdl_tpu.observe import metrics as _metrics
-        _metrics.set_phase_hook(None)
+        from bigdl_tpu.observe import trace as _trace
+        _trace.set_phase_hook(None)
         _phase_hook_installed = False
 
 

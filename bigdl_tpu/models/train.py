@@ -90,7 +90,7 @@ def _common(p: argparse.ArgumentParser):
                         "(BIGDL_TPU_PRECOMPILE; logs XLA cost analysis "
                         "per program)")
     p.add_argument("--trace-dir", default=None,
-                   help="flight recorder: record host spans and dump "
+                   help="flight recorder: dump the span ring as "
                         "Chrome/Perfetto trace JSON here at the end of "
                         "training (BIGDL_TPU_TRACE — "
                         "docs/observability.md)")

@@ -4,10 +4,12 @@ Unified observability for the training stack (reference analogues:
 `optim/Metrics.scala` phase timers, `AbstractModule` nanosecond timers,
 `visualization/TrainSummary` events — SURVEY §2.10):
 
-  * **trace**   — thread-safe ring-buffered span tracer emitting
-                  Chrome/Perfetto `trace_event` JSON, with matching
-                  `jax.profiler.TraceAnnotation` scopes so host spans
-                  line up with XLA device traces;
+  * **trace**   — thread-safe, always-on, bounded ring of host spans
+                  (and of the interpreter's collections, `process/gc`)
+                  emitting Chrome/Perfetto `trace_event` JSON, with
+                  matching `jax.profiler.TraceAnnotation` scopes so a
+                  profiler capture holds the host spans on the device's
+                  timeline;
   * **metrics** — process-wide registry of counters, gauges, and
                   log-bucket histograms (bounded memory for any run
                   length) fed only host-side values — no added syncs;
@@ -39,20 +41,25 @@ Unified observability for the training stack (reference analogues:
                   BIGDL_TPU_ALERT_WEBHOOK with bounded retry, off the
                   flush path.
 
-Enable via knobs (utils/config.py): BIGDL_TPU_TRACE=<dir> records and
-dumps a trace per optimize(); BIGDL_TPU_METRICS_JSONL / _PROM / _TB
-attach exporters. The trainers call `ensure_started()` once per
-optimize() and `finish()` at the end — a disabled flight recorder costs
-one attribute check per span site.
+The span ring is always on and bounded (BIGDL_TPU_TRACE_RING events; a
+span costs a clock read at each edge, a ring append and an annotation
+the profiler drops unless a capture is live). Knobs (utils/config.py):
+BIGDL_TPU_TRACE=<dir> dumps the ring as Chrome JSON at each `finish()`;
+BIGDL_TPU_METRICS_JSONL / _PROM / _TB attach exporters. The trainers
+call `ensure_started()` once per optimize() and `finish()` at the end.
 
 Span catalogue (docs/observability.md): training spans (`train/*`,
 `data/*`, `checkpoint/*`, `jit/compile`), resilience markers
-(`fault/*`, `preempt/*`, `retry`), and — since the serving subsystem —
+(`fault/*`, `preempt/*`, `retry`), the collections (`process/gc`, with
+the counter `process/gc_pause_s`), and — since the serving subsystem —
 the serve family: `serve/pack` and `serve/dispatch` spans around each
-continuous-batching dispatch, the `serve/drain` span on graceful
-shutdown, and the `serve/shed` instant for admission-control
-rejections, all riding the same flush cadence (ONE host fetch per
-dispatched batch, no per-request syncs — bigdl_tpu/serve/).
+continuous-batching dispatch; the decode iteration
+`serve/decode/iteration`, tiled by its leaves `serve/decode/admit`,
+`await_taker`, `prefill`, `dispatch`, `fetch` (the host waiting on the
+device) and `deliver`; the `serve/drain` span on graceful shutdown, and
+the `serve/shed` instant for admission-control rejections, all riding
+the same flush cadence (ONE host fetch per dispatched batch or decode
+step, no per-request syncs — bigdl_tpu/serve/).
 """
 
 from __future__ import annotations
@@ -63,9 +70,8 @@ from typing import Optional
 
 from bigdl_tpu.observe import metrics as metrics  # noqa: F401 — re-export
 from bigdl_tpu.observe import trace as trace      # noqa: F401 — re-export
-from bigdl_tpu.observe.metrics import (counter, gauge, histogram, phase,
-                                       registry)
-from bigdl_tpu.observe.trace import get_tracer, instant, span
+from bigdl_tpu.observe.metrics import counter, gauge, histogram, registry
+from bigdl_tpu.observe.trace import get_tracer, instant, phase, span
 from bigdl_tpu.utils.runtime import (install_log_prefix, process_index,
                                      run_id)
 from bigdl_tpu.utils.threads import make_lock
@@ -77,6 +83,8 @@ __all__ = [
     "ensure_started", "finish", "shutdown", "export_manager",
     "statusz_server",
 ]
+
+trace.install_gc_hook()     # every collection of the process, from import on
 
 _lock = make_lock("observe.lifecycle")
 _exports = None            # ExportManager when any exporter is configured
@@ -167,12 +175,11 @@ def ensure_started() -> bool:
         from bigdl_tpu.analysis import sancov
         if sancov.sanitize_modes():
             sancov.refresh()
-        trace_dir = config.get("TRACE")
+        trace_dir = config.get("TRACE") or None
+        if trace_dir in ("1", "true", "yes", "on"):
+            trace_dir = "/tmp/bigdl_tpu_trace"
         t = get_tracer()
-        if trace_dir:
-            if trace_dir in ("1", "true", "yes", "on"):
-                trace_dir = "/tmp/bigdl_tpu_trace"
-            t.enable(trace_dir, ring=config.get("TRACE_RING"))
+        t.configure(trace_dir, ring=config.get("TRACE_RING"))
         if _exports is None:
             exporters = []
             jsonl = config.get("METRICS_JSONL")
@@ -215,7 +222,7 @@ def ensure_started() -> bool:
         if not _atexit_registered:
             atexit.register(shutdown)
             _atexit_registered = True
-        return bool(t.enabled or _exports or sz)
+        return bool(t.trace_dir or _exports or sz)
 
 
 def export_manager():
@@ -230,11 +237,11 @@ def statusz_server():
 
 
 def finish() -> Optional[str]:
-    """End-of-optimize flush: dump the trace (returns its path) and push
-    one final exporter snapshot. The recorder stays enabled — a process
-    training twice appends both runs to the same flight record."""
-    t = get_tracer()
-    path = t.dump() if t.enabled else None
+    """End-of-optimize flush: dump the ring where BIGDL_TPU_TRACE names a
+    directory (returns the path) and push one final exporter snapshot.
+    The ring keeps recording — a process training twice dumps both runs
+    in one flight record."""
+    path = get_tracer().dump()
     if _exports is not None:
         _exports.flush()
     return path
@@ -242,8 +249,8 @@ def finish() -> Optional[str]:
 
 def shutdown() -> None:
     """Tear down fleet poller + serve-SLO watchdog + exporters +
-    statusz server + disable tracing (tests / process exit). Pollers
-    stop before the HTTP server they scrape through."""
+    statusz server, and forget the trace dump directory (tests / process
+    exit). Pollers stop before the HTTP server they scrape through."""
     global _exports, _started
     with _lock:
         from bigdl_tpu.observe import fleet as _fleet
@@ -257,5 +264,5 @@ def shutdown() -> None:
             _exports = None
         from bigdl_tpu.observe import statusz as _statusz
         _statusz.stop()
-        get_tracer().disable()
+        get_tracer().configure(None)
         _started = False

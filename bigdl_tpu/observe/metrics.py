@@ -23,21 +23,13 @@ from __future__ import annotations
 
 import math
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
-from bigdl_tpu.utils.threads import make_lock
+from bigdl_tpu.utils.threads import make_rlock
 
-_lock = make_lock("observe.metrics")
-
-# concurrency-sanitizer hook (analysis/sancov.py): when the sync mode is
-# on it installs a fn(name, entering) here so device->host fetches can
-# be attributed to the innermost live phase span; None costs one load
-_phase_hook: Optional[Callable[[str, bool], None]] = None
-
-
-def set_phase_hook(fn: Optional[Callable[[str, bool], None]]) -> None:
-    global _phase_hook
-    _phase_hook = fn
+# reentrant: a collection may run the tracer's gc hook (observe/trace.py),
+# which counts its pause here, at any allocation of a thread that holds it
+_lock = make_rlock("observe.metrics")
 
 
 class Counter:
@@ -228,9 +220,10 @@ class MetricsRegistry:
     def reset(self) -> None:
         """Drop every registered metric (tests; a fresh optimize() keeps
         accumulating — a flight recorder spans the process)."""
+        from bigdl_tpu.observe import trace
         with _lock:
             self._metrics.clear()
-            _phase_cache.clear()  # else phase() keeps orphaned histograms
+            trace._phase_cache.clear()  # else phase() keeps orphans
 
 
 _REGISTRY = MetricsRegistry()
@@ -251,35 +244,6 @@ def gauge(name: str) -> Gauge:
 def histogram(name: str,
               bounds: Optional[Tuple[float, ...]] = None) -> Histogram:
     return _REGISTRY.histogram(name, bounds)
-
-
-# -------------------------------------------------- phase timing (spans)
-class _Phase:
-    """One clock read per edge feeding BOTH sinks: the phase histogram
-    (always, host-side floats only) and the tracer ring (when enabled).
-    This is the instrumentation primitive the trainers use."""
-
-    __slots__ = ("_hist", "_name", "_cat", "_t0")
-
-    def __init__(self, hist: Histogram, name: str, cat: str):
-        self._hist, self._name, self._cat = hist, name, cat
-
-    def __enter__(self):
-        if _phase_hook is not None:
-            _phase_hook(self._name, True)
-        self._t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc):
-        dur_ns = time.perf_counter_ns() - self._t0
-        if _phase_hook is not None:
-            _phase_hook(self._name, False)
-        self._hist.record(dur_ns * 1e-9)
-        from bigdl_tpu.observe import trace
-        t = trace._TRACER
-        if t.enabled:
-            t.record(self._name, self._cat, self._t0, dur_ns)
-        return False
 
 
 # ------------------------------------------- serialized-bucket quantiles
@@ -367,22 +331,6 @@ def merge_histogram_snapshots(hs: List[dict]) -> Optional[dict]:
         out["min"] = min(out["min"], h.get("min", out["min"]))
         out["max"] = max(out["max"], h.get("max", out["max"]))
     return out
-
-
-_phase_cache: Dict[str, Histogram] = {}
-
-
-def phase(name: str, cat: str = "train") -> _Phase:
-    """`with phase("train/dispatch"): ...` — records seconds into the
-    `phase/<name>` histogram and, when tracing is on, a matching span.
-    The histogram lookup is cached by name, so the steady-state cost is
-    two perf_counter reads + one locked bucket increment."""
-    h = _phase_cache.get(name)
-    if h is None:
-        h = _REGISTRY.histogram(f"phase/{name}")
-        with _lock:              # miss path only; hits stay lock-free
-            _phase_cache[name] = h
-    return _Phase(h, name, cat)
 
 
 def phase_table(snapshot: dict) -> List[dict]:

@@ -240,7 +240,7 @@ def tracez_payload(n: int = 100) -> dict:
                       "ts_us": round(t._ts_us(t0), 1),
                       "dur_us": round(dur / 1e3, 1),
                       "args": args})
-    return {"enabled": t.enabled, "ring": t._ring,
+    return {"dump_dir": t.trace_dir, "ring": t._ring,
             "count": len(spans), "spans": spans}
 
 
@@ -253,7 +253,9 @@ def arm_profiler(seconds: float) -> dict:
     """Start a `jax.profiler` capture for `seconds` (clamped 0.1..600);
     a background timer stops it. One window at a time. The capture dir
     lands under the trace dir (or /tmp) — TensorBoard-loadable, with
-    the host spans' TraceAnnotations aligned to the device timeline."""
+    the host spans' TraceAnnotations aligned to the device timeline
+    (every span annotates, knob or not; the profiler keeps them while a
+    capture is live)."""
     global _profile_until
     seconds = min(600.0, max(0.1, float(seconds)))
     try:

@@ -12,72 +12,94 @@ renders the actual interleaving.
 
 Design constraints, in order:
 
-  * **Zero allocation on the hot path when disabled.** `span()` returns a
-    module-level singleton no-op context manager; the enabled check is one
-    attribute load. Callers pass `args=None` (no kwargs dict is built).
-  * **Thread-safe without a lock.** Events append to a
-    `collections.deque(maxlen=ring)` — atomic under the GIL, and the
-    bounded ring means a forgotten tracer can never eat the heap (the
-    oldest spans fall off, which is exactly what a flight recorder does).
-  * **Monotonic clocks.** Timestamps are `time.perf_counter_ns()` deltas
-    from the tracer's start; the wall-clock anchor rides the metadata so
-    traces from different hosts can still be lined up.
-  * **Device correlation.** When enabled, each span also enters a
-    `jax.profiler.TraceAnnotation` scope, so a `jax.profiler.trace`
-    capture taken during the run shows these host spans aligned with the
-    XLA device timeline (utils/profile.xla_profile).
+  * **Always on, always bounded.** A flight recorder that is off when the
+    incident happens records nothing, so every span lands in the ring.
+    Events append to a `collections.deque(maxlen=ring)` — atomic under
+    the GIL, and the bounded ring means the recorder can never eat the
+    heap (the oldest spans fall off). `BIGDL_TPU_TRACE` only names where
+    `observe.finish()` dumps the ring as Chrome JSON.
+  * **One primitive, one clock read per edge.** A span (`span()`, or
+    `phase()` for one that also feeds the `phase/<name>` histogram)
+    appends one complete event to the ring, enters a
+    `jax.profiler.TraceAnnotation` (kept by the profiler only while a
+    capture is live, so a `jax.profiler.trace` capture shows these host
+    spans aligned with the XLA device timeline), and records its seconds
+    into its histogram where it has one.
+  * **Monotonic clocks.** Timestamps are `time.perf_counter_ns()`; the
+    Chrome export writes them as deltas from the tracer's start, and the
+    wall-clock anchor rides the metadata so traces from different hosts
+    can still be lined up.
+  * **Collections are spans too.** A `gc.callbacks` hook (installed when
+    `bigdl_tpu.observe` is imported) records each collection as the span
+    `process/gc` and adds its pause to the counter `process/gc_pause_s`
+    and the histogram `phase/process/gc`: a collection stops every
+    thread, whichever one ran it.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional
+
+from bigdl_tpu.observe import metrics as _metrics
+
+# concurrency-sanitizer hook (analysis/sancov.py): when the sync mode is
+# on it installs a fn(name, entering) here so device->host fetches can
+# be attributed to the innermost live span; None costs one load
+_phase_hook: Optional[Callable[[str, bool], None]] = None
 
 
-class _NullSpan:
-    """Shared disabled-path context manager: no state, no allocation."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+def set_phase_hook(fn: Optional[Callable[[str, bool], None]]) -> None:
+    global _phase_hook
+    _phase_hook = fn
 
 
-NULL_SPAN = _NullSpan()
+_Annotation = None      # jax.profiler.TraceAnnotation, resolved at first use
+
+
+def _annotation_class():
+    global _Annotation
+    from jax.profiler import TraceAnnotation as _Annotation
+    return _Annotation
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
+    """One host phase. `t0_ns` and `dur_ns` (perf_counter nanoseconds)
+    are readable once the span has closed; `args` may be filled in while
+    it is open (it is read when it closes)."""
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args):
+    __slots__ = ("_tracer", "name", "cat", "args", "_hist", "_ann",
+                 "t0_ns", "dur_ns")
+
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args,
+                 hist=None):
         self._tracer = tracer
         self.name, self.cat, self.args = name, cat, args
+        self._hist = hist
 
     def __enter__(self):
-        ann = None
-        if self._tracer.annotate:
-            try:
-                import jax
-                ann = jax.profiler.TraceAnnotation(self.name)
-                ann.__enter__()
-            except Exception:              # profiler unavailable — host-only
-                ann = None
-        self._ann = ann
-        self._t0 = time.perf_counter_ns()
+        # the annotation's cost is inside the span, so that the leaves of
+        # a span tile it but for the few bytecodes between them
+        self.t0_ns = time.perf_counter_ns()
+        if _phase_hook is not None:
+            _phase_hook(self.name, True)
+        ann = self._ann = (_Annotation or _annotation_class())(self.name)
+        ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-        self._tracer.record(self.name, self.cat, self._t0, t1 - self._t0,
+        self._ann.__exit__(*exc)
+        if _phase_hook is not None:
+            _phase_hook(self.name, False)
+        dur = self.dur_ns = time.perf_counter_ns() - self.t0_ns
+        if self._hist is not None:
+            self._hist.record(dur * 1e-9)
+        self._tracer.record(self.name, self.cat, self.t0_ns, dur,
                             self.args)
         return False
 
@@ -86,10 +108,8 @@ class Tracer:
     """Ring-buffered span recorder. One process-wide instance lives in
     this module (`get_tracer()`); tests may build private ones."""
 
-    def __init__(self, ring: int = 100_000, annotate: bool = True):
-        self.enabled = False
-        self.annotate = annotate
-        self.trace_dir: Optional[str] = None
+    def __init__(self, ring: int = 100_000):
+        self.trace_dir: Optional[str] = None   # where dump() writes
         self._ring = ring
         self._events: deque = deque(maxlen=ring)
         self._thread_names: Dict[int, str] = {}
@@ -97,34 +117,28 @@ class Tracer:
         self._wall0 = time.time()
 
     # ------------------------------------------------------------- control
-    def enable(self, trace_dir: Optional[str] = None,
-               ring: Optional[int] = None) -> None:
+    def configure(self, trace_dir: Optional[str] = None,
+                  ring: Optional[int] = None) -> None:
+        """Name the directory `dump()` writes to (None: no dump) and,
+        where given, resize the ring (its newest events are kept)."""
         if ring and ring != self._ring:
             self._ring = ring
             self._events = deque(self._events, maxlen=ring)
         self.trace_dir = trace_dir
-        self.enabled = True
-
-    def disable(self) -> None:
-        self.enabled = False
 
     def clear(self) -> None:
         self._events.clear()
 
     # ------------------------------------------------------------ recording
-    def span(self, name: str, cat: str = "host", args: Optional[dict] = None):
-        """Context manager timing a host phase. Disabled: returns the
-        shared no-op singleton (zero allocation)."""
-        if not self.enabled:
-            return NULL_SPAN
+    def span(self, name: str, cat: str = "host",
+             args: Optional[dict] = None) -> _Span:
+        """Context manager timing a host phase into this ring."""
         return _Span(self, name, cat, args)
 
     def record(self, name: str, cat: str, t0_ns: int, dur_ns: int,
                args: Optional[dict] = None) -> None:
         """Append one complete ('X') event; called by _Span.__exit__ and
         by instrumentation that timed a phase itself."""
-        if not self.enabled:
-            return
         tid = threading.get_ident()
         if tid not in self._thread_names:
             self._thread_names[tid] = threading.current_thread().name
@@ -133,8 +147,6 @@ class Tracer:
     def instant(self, name: str, cat: str = "host",
                 args: Optional[dict] = None) -> None:
         """Zero-duration marker (fault injected, retry, preemption...)."""
-        if not self.enabled:
-            return
         tid = threading.get_ident()
         if tid not in self._thread_names:
             self._thread_names[tid] = threading.current_thread().name
@@ -195,7 +207,8 @@ class Tracer:
         return path
 
     def events(self) -> Iterable[tuple]:
-        """Raw ring contents (tests / report tooling)."""
+        """Raw ring contents, oldest first: (ph, name, cat, tid, t0_ns,
+        dur_ns, args) with perf_counter nanoseconds."""
         return list(self._events)
 
 
@@ -206,18 +219,66 @@ def get_tracer() -> Tracer:
     return _TRACER
 
 
-def span(name: str, cat: str = "host", args: Optional[dict] = None):
-    """Module-level hot-path entry: `with trace.span("train/dispatch"): ...`
-    Disabled tracing returns the no-op singleton."""
-    if not _TRACER.enabled:
-        return NULL_SPAN
+def span(name: str, cat: str = "host", args: Optional[dict] = None) -> _Span:
+    """Module-level hot-path entry: `with trace.span("serve/decode/fetch"):`"""
     return _Span(_TRACER, name, cat, args)
+
+
+_phase_cache: Dict[str, "_metrics.Histogram"] = {}
+
+
+def phase(name: str, cat: str = "train", args: Optional[dict] = None
+          ) -> _Span:
+    """`with phase("train/dispatch"): ...` — a span that also records its
+    seconds into the `phase/<name>` histogram. The histogram lookup is
+    cached by name, so the steady-state cost is a span's."""
+    h = _phase_cache.get(name)
+    if h is None:
+        h = _phase_cache[name] = _metrics.histogram(f"phase/{name}")
+    return _Span(_TRACER, name, cat, args, h)
 
 
 def instant(name: str, cat: str = "host",
             args: Optional[dict] = None) -> None:
-    if _TRACER.enabled:
-        _TRACER.instant(name, cat, args)
+    _TRACER.instant(name, cat, args)
+
+
+# ------------------------------------------------------------ collections
+GC_SPAN = "process/gc"
+GC_PAUSE = "process/gc_pause_s"
+GC_HIST = f"phase/{GC_SPAN}"
+_gc_t0 = 0
+
+
+def _on_gc(event: str, info: dict) -> None:
+    """`gc.callbacks` hook. Collections never overlap (the interpreter
+    runs one at a time, callbacks included), so one start time serves.
+    It may run at any allocation of any thread, also one made while that
+    thread holds the metrics registry's lock, which is reentrant for it."""
+    global _gc_t0
+    if event == "start":
+        _gc_t0 = time.perf_counter_ns()
+        return
+    t0 = _gc_t0
+    dur = time.perf_counter_ns() - t0
+    # registered by hand: a collection may come while a module that the
+    # registry's accessors import is half imported
+    reg = _metrics.registry()._metrics
+    pause = reg.get(GC_PAUSE) or reg.setdefault(
+        GC_PAUSE, _metrics.Counter(GC_PAUSE))
+    pause.inc(dur * 1e-9)
+    hist = reg.get(GC_HIST) or reg.setdefault(
+        GC_HIST, _metrics.Histogram(GC_HIST))
+    hist.record(dur * 1e-9)
+    _TRACER.record(GC_SPAN, "process", t0, dur,
+                   {"generation": info.get("generation"),
+                    "collected": info.get("collected")})
+
+
+def install_gc_hook() -> None:
+    """Record every collection of this process (idempotent)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def validate_chrome_trace(doc: Any) -> List[str]:

@@ -85,6 +85,12 @@ class _StepEntry:
         self.jitted = jitted
         self.aot = None
 
+    @property
+    def program(self) -> str:
+        """The program's name in a device trace (its `XLA Modules` events
+        read `<name>(<hash>)`), AOT or jitted alike."""
+        return "jit_" + getattr(self.jitted, "__name__", "?")
+
     def __call__(self, *args):
         if self.aot is not None:
             try:
@@ -1008,10 +1014,9 @@ class Optimizer:
             raise
 
     def _optimize_impl(self) -> Tuple[Dict, Dict]:
-        # flight recorder (observe/): knob-gated trace spans + metrics
-        # exporters + the statusz live telemetry plane; a disabled
-        # recorder costs one attribute check per span site
-        # (BIGDL_TPU_TRACE / _METRICS_* / _STATUSZ_PORT —
+        # flight recorder (observe/): the span ring (always on; dumped
+        # where BIGDL_TPU_TRACE says) + metrics exporters + the statusz
+        # live telemetry plane (_METRICS_* / _STATUSZ_PORT —
         # docs/observability.md)
         observe.ensure_started()
         # run-shape gauges for /statusz (host-side ints, no syncs)
@@ -1114,6 +1119,9 @@ class Optimizer:
                 "dcn_step" if dcn else "step")
             fused_step = self._get_built(
                 "dcn_fused" if dcn else "fused") if use_fused else None
+            # what the `train/dispatch` spans say they enqueued, for a
+            # device trace's reader (benchmark/layer_readers/)
+            dispatched = {"program": step.program} if step else None
             self._eval_fn = self._build_eval_fn()
             epoch_start = time.time()
             epoch_records = 0
@@ -1182,7 +1190,7 @@ class Optimizer:
                     # batch refs only (never donated) — lets the Parameters
                     # summary recompute gradients on its cadence
                     self._last_batch = (xd, yd, sub)
-                with observe.phase("train/dispatch"):
+                with observe.phase("train/dispatch", args=dispatched):
                     # async dispatch latency: the time Python takes to
                     # hand XLA the step, NOT device compute (which the
                     # flush span pays when it fetches the losses)
@@ -1332,6 +1340,7 @@ class Optimizer:
         epoch_records = 0
         ended_mid_epoch = False
         W = self._log_every
+        dispatched = {"program": fused_step.program}
         for xs, ys, k_valid in self._observed_batches(
                 self._fused_batch_iter(epoch_iter)):
             k = int(xs.shape[0])
@@ -1341,7 +1350,7 @@ class Optimizer:
             if self._param_summary_enabled():
                 self._last_batch = (xs[k_valid - 1], ys[k_valid - 1],
                                     rngs[k_valid - 1])
-            with observe.phase("train/dispatch"):
+            with observe.phase("train/dispatch", args=dispatched):
                 # one span covers the whole K-step scan dispatch — divide
                 # by k_valid when comparing against per-step numbers
                 if self._dcn_state is not None:

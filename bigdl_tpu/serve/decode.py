@@ -1038,6 +1038,14 @@ class DecodeEntry:
         return (self._jit_verify or self._jit_decode if C is None
                 else self._jit_carry)(*args)
 
+    def step_program(self, carried: bool) -> str:
+        """The name a device trace gives the step program `run_decode`
+        runs (its `XLA Modules` events read `<name>(<hash>)`): the one that
+        carries a prompt chunk or the plain one."""
+        fn = self._jit_carry if carried else (self._jit_verify
+                                              or self._jit_decode)
+        return "jit_" + fn.__name__
+
     def merge_tokens(self, use_prev: np.ndarray, prev_next, host_tokens):
         """`where(use_prev, prev_next, host_tokens)` over the slots, on the
         device: `prev_next` is the unfetched output of the step in flight
@@ -1259,6 +1267,9 @@ class DecodeScheduler:
         # the decode step that is enqueued and not fetched yet; written by
         # the thread that runs step_once, cleared by close()
         self._in_flight: Optional[_Step] = None
+        # the args of the `serve/decode/dispatch` span that enqueued it,
+        # which the `fetch` span of its tokens carries too
+        self._in_flight_args: Optional[dict] = None
         self._t_fetched = float("-inf")    # when the last fetch returned
         self._thread: Optional[threading.Thread] = None
         self._stop_check: Optional[Callable[[], bool]] = None
@@ -1288,6 +1299,12 @@ class DecodeScheduler:
             f"serve/{n}/decode/prefill_ms", LATENCY_MS_BOUNDS)
         self._h_step = observe.histogram(
             f"serve/{n}/decode/step_ms", LATENCY_MS_BOUNDS)
+        # the wall time of an iteration, and of it what the host spent
+        # working: not waiting on the device (`fetch`) or for a taker
+        self._h_iter = observe.histogram(
+            f"serve/{n}/decode/iteration_ms", LATENCY_MS_BOUNDS)
+        self._h_host = observe.histogram(
+            f"serve/{n}/decode/host_ms", LATENCY_MS_BOUNDS)
         self._h_qw = observe.histogram(
             f"serve/{n}/decode/queue_wait_ms", LATENCY_MS_BOUNDS)
         self._h_lat = observe.histogram(
@@ -1575,7 +1592,8 @@ class DecodeScheduler:
                 return b
         return c
 
-    def _prefill_pass(self, stepping: bool) -> Tuple[int, Optional[tuple]]:
+    def _prefill_pass(self, stepping: bool
+                      ) -> Tuple[int, Optional[tuple], Optional[dict]]:
         """The iteration's prompt chunks. Where it dispatches a decode step
         (`stepping`) and the model has the carrying form, the step is the
         iteration's one program: it carries the chunk of ONE streaming
@@ -1586,11 +1604,13 @@ class DecodeScheduler:
         program call for each slot. Either way, where half the slots or
         more stream a full chunk at once, the one `num_slots`-row call
         (`DecodeEntry.prefill_rows`) and a plain step. Returns (the slots
-        that stream, the chunk for the step to carry or None)."""
+        that stream, the chunk for the step to carry or None, the args of
+        the `serve/decode/prefill` span: whether the chunk is carried, the
+        bucket of the last chunk and the rows computed, or None)."""
         pending = [r for r in self._slots
                    if r is not None and r.fed < r.prefill_target]
         if not pending:
-            return 0, None
+            return 0, None, None
         by_bucket: Dict[int, List[_GenRequest]] = {}
         for req in pending:
             by_bucket.setdefault(self._chunk_for(req), []).append(req)
@@ -1599,19 +1619,26 @@ class DecodeScheduler:
             burst = by_bucket.get(full, [])
             if self.entry.prefill_rows(full, len(burst)) > 1:
                 self._prefill_call(full, burst, by_row=False)
-                return len(pending), None
+                return len(pending), None, {
+                    "carried": False, "chunk": full,
+                    "rows": self.entry.num_slots}
             first = min(pending, key=lambda r: r.order)
             C = next(b for b in self.entry.carried
                      if b >= self._chunk_for(first))
             return len(pending), self._prefill_call(
-                C, [first], by_row=True, carried=True)
+                C, [first], by_row=True, carried=True), {
+                    "carried": True, "chunk": C, "rows": 1}
+        rows = 0
         for C, reqs in sorted(by_bucket.items()):
             if self.entry.prefill_rows(C, len(reqs)) == 1:
                 for req in reqs:
                     self._prefill_call(C, [req], by_row=True)
+                rows += len(reqs)
             else:
                 self._prefill_call(C, reqs, by_row=False)
-        return len(pending), None
+                rows += self.entry.num_slots
+        return len(pending), None, {"carried": False, "chunk": C,
+                                    "rows": rows}
 
     def _count_context(self, contexts: np.ndarray) -> None:
         """`contexts`: of each query token a call computes, the tokens it
@@ -1656,16 +1683,11 @@ class DecodeScheduler:
             self._m_prefill_carried.inc()
         else:
             t0 = self._clock()
-            with observe.span("serve/decode/prefill", cat="serve",
-                              args={"model": self.name, "chunk": C,
-                                    "slots": len(reqs), "rows": R,
-                                    "context": int(contexts.max()),
-                                    "state": self.entry.state_kind}):
-                # lengths masks the rounded-up bucket's padded tail (and
-                # inactive rows) out of the pool scatter
-                self._caches = self.entry.run_prefill(
-                    self._caches, tokens, positions, table, lengths,
-                    slots if by_row else None, follow)
+            # lengths masks the rounded-up bucket's padded tail (and
+            # inactive rows) out of the pool scatter
+            self._caches = self.entry.run_prefill(
+                self._caches, tokens, positions, table, lengths,
+                slots if by_row else None, follow)
             self._h_prefill.record(max(0.0, (self._clock() - t0) * 1e3))
         self._m_prefill_calls.inc()
         self._m_prefill_rows.inc(R)
@@ -1696,24 +1718,46 @@ class DecodeScheduler:
                 j += 1
             req.commit_upto = j
 
-    def _decode_pass(self, rows: List[Tuple[_GenRequest, bool]],
-                     chunk: Optional[tuple]) -> int:
-        """Enqueue the next fused step over `rows` (`_step_rows`), with the
-        prompt chunk it carries, then fetch and deliver the step that was
-        in flight, which the device has been running meanwhile."""
-        prev = self._in_flight
-        # `rows` and `context` (the longest of the step enqueued) are known
-        # once it is dispatched; the span keeps the dict it was given
+    def _decode_pass(self, chunk: Optional[tuple]) -> Tuple[int, int]:
+        """Enqueue the next fused step over the rows `_step_rows` gives (a
+        slot whose prompt this iteration's chunk completes among them),
+        with the prompt chunk it carries, then fetch and deliver the step
+        that was in flight, which the device has been running meanwhile.
+        Returns (the rows of the step delivered, or else of the one
+        enqueued; the nanoseconds the host waited in the fetch)."""
+        prev, prev_args = self._in_flight, self._in_flight_args
+        # `program`, `rows` and `context` (the longest of the step
+        # enqueued) are known once it is dispatched; the span reads the
+        # dict when it closes. The fetch of that step's tokens carries the
+        # same dict: a reader pairs the two by it.
         args = {"model": self.name, "state": self.entry.state_kind}
-        with observe.span("serve/decode/step", cat="serve", args=args):
-            self._in_flight = self._dispatch_step(prev, rows, chunk)
+        with observe.span("serve/decode/dispatch", cat="serve", args=args):
+            self._in_flight = self._dispatch_step(
+                prev, self._step_rows(prev), chunk)
+            self._in_flight_args = args
             if self._in_flight is not None:
+                args["program"] = self.entry.step_program(chunk is not None)
+                if chunk is not None:
+                    # the carrying programs are one a bucket
+                    args["chunk"] = chunk[0].shape[1]
                 args["rows"] = len(self._in_flight.rows)
                 args["context"] = self._in_flight.context
+        waited = 0
+        if prev is not None:
+            with observe.span("serve/decode/fetch", cat="serve",
+                              args=prev_args) as fetch:
+                nxt = self._fetch(prev)
+            waited = fetch.dur_ns
+        with observe.span("serve/decode/deliver", cat="serve"):
+            step = prev or self._in_flight
+            rows = len(step.rows) if step is not None else 0
             if prev is not None:
-                self._deliver(prev)
-        step = prev or self._in_flight
-        return len(step.rows) if step is not None else 0
+                self._deliver(prev, nxt)
+            # free the fetched step's device buffer inside this span, not
+            # between spans as the frame unwinds
+            del prev, step
+            self._refresh_pool_stats()
+        return rows, waited
 
     def _step_rows(self, prev: Optional[_Step]
                    ) -> List[Tuple[_GenRequest, bool]]:
@@ -1829,15 +1873,20 @@ class DecodeScheduler:
                                             for req in reqs)),
                      tuple(drafted))
 
-    def _deliver(self, step: _Step) -> None:
-        """Fetch a step's tokens (the iteration's single host sync), push
-        them to their streams and retire the sequences that end. A row
-        whose request left its slot while the step was in flight (it ended
-        by value in the step before, or was cancelled) is dropped."""
+    @staticmethod
+    def _fetch(step: _Step) -> np.ndarray:
+        """A step's tokens on the host: the iteration's single host sync,
+        and the one place the host waits on the device."""
         from bigdl_tpu.analysis.sancov import sanctioned_sync
         import jax
         with sanctioned_sync("decode next-token fetch"):
-            nxt = np.asarray(jax.device_get(step.nxt))
+            return np.asarray(jax.device_get(step.nxt))
+
+    def _deliver(self, step: _Step, nxt: np.ndarray) -> None:
+        """Push a fetched step's tokens to their streams and retire the
+        sequences that end. A row whose request left its slot while the
+        step was in flight (it ended by value in the step before, or was
+        cancelled) is dropped."""
         now = self._clock()
         if self.entry.drafting:
             return self._deliver_verified(step, nxt, now)
@@ -1996,8 +2045,35 @@ class DecodeScheduler:
         calls, or as the chunk the step carries) → decode (enqueue the next
         step, then fetch the one in flight).
         Returns True when any work happened (the thread loop sleeps
-        otherwise); tests drive this synchronously with a fake clock."""
-        worked = self._sweep_cancelled() > 0
+        otherwise); tests drive this synchronously with a fake clock.
+
+        The span `serve/decode/iteration` holds it all, and every instant
+        of it lies in one of its leaves: `admit` (twice: the sweeps, then
+        admission), `await_taker`, `prefill`, `dispatch`, `fetch` and
+        `deliver` (docs/observability.md). `iteration_ms` records its wall
+        time and `host_ms` that time less the waits in `fetch` and
+        `await_taker`."""
+        span = observe.span
+        with span("serve/decode/iteration", cat="serve") as it:
+            with span("serve/decode/admit", cat="serve"):
+                worked = self._sweep() > 0
+            with span("serve/decode/await_taker", cat="serve") as wait:
+                self._await_taker()
+            with span("serve/decode/admit", cat="serve"):
+                worked = self._admit() > 0 or worked
+            with span("serve/decode/prefill", cat="serve") as pre:
+                stepping = bool(self._step_rows(self._in_flight))
+                streaming, chunk, pre.args = self._prefill_pass(stepping)
+            rows, fetched = self._decode_pass(chunk)
+        self._h_iter.record(it.dur_ns * 1e-6)
+        self._h_host.record((it.dur_ns - wait.dur_ns - fetched) * 1e-6)
+        return rows > 0 or streaming > 0 or worked
+
+    def _sweep(self) -> int:
+        """The iteration's sweeps: abandoned requests (`_sweep_cancelled`,
+        whose count it returns) and, under the memory watchdog, the prefix
+        cache's unreferenced blocks."""
+        freed = self._sweep_cancelled()
         if self._prefix is not None:
             from bigdl_tpu.observe import memz as _memz
             if _memz.watchdog_active():
@@ -2008,17 +2084,7 @@ class DecodeScheduler:
                                     cat="serve",
                                     args={"model": self.name,
                                           "blocks": swept})
-        self._await_taker()
-        worked = self._admit() > 0 or worked
-        stepping = bool(self._step_rows(self._in_flight))
-        streaming, chunk = self._prefill_pass(stepping)
-        # a slot whose prompt that chunk completes, in a call or carried,
-        # joins this iteration's step with its last prompt token
-        rows = self._step_rows(self._in_flight)
-        worked = streaming > 0 or worked
-        worked = self._decode_pass(rows, chunk) > 0 or worked
-        self._refresh_pool_stats()
-        return worked
+        return freed
 
     # ----------------------------------------------------------- lifecycle
     def start(self, stop_check: Optional[Callable[[], bool]] = None

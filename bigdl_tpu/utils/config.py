@@ -166,10 +166,10 @@ _register("MAX_NONFINITE", 3, int,
           "on the fused path, their updates are masked out — "
           "optim/local.py)")
 _register("TRACE", "", str,
-          "Flight-recorder span tracing (observe/trace.py): a directory "
-          "records host spans and dumps Chrome/Perfetto trace JSON there "
-          "at the end of each optimize(); '1' uses /tmp/bigdl_tpu_trace; "
-          "'' disables (zero-allocation no-op spans)")
+          "Where the flight recorder's span ring (observe/trace.py, "
+          "always on) is dumped as Chrome/Perfetto trace JSON at the end "
+          "of each optimize(); '1' uses /tmp/bigdl_tpu_trace; '' dumps "
+          "nothing (the ring records all the same)")
 _register("TRACE_RING", 100_000, int,
           "Span ring-buffer capacity: the newest N events are kept, the "
           "oldest fall off — a flight recorder, not an unbounded log "

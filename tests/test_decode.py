@@ -436,6 +436,61 @@ def test_decode_step_is_one_host_sync(entry, monkeypatch):
     sched.close(drain=False)
 
 
+LEAVES = ("admit", "await_taker", "prefill", "dispatch", "fetch", "deliver")
+
+
+def test_iteration_is_tiled_by_its_leaf_spans(entry, monkeypatch):
+    """Under the fake clock: every iteration is one `serve/decode/iteration`
+    span whose leaves cover at least 99 % of it (summed over the run) when
+    the fetch waits 4 ms on the device, as a chip's 12-30 ms step makes it,
+    with what lies between the leaves under 100 us an iteration; `dispatch`
+    names the program it enqueued, and `host_ms` + the waits in `fetch` and
+    `await_taker` = `iteration_ms`, exactly."""
+    import time
+    from bigdl_tpu.observe import trace as obs_trace
+    fetch = DecodeScheduler._fetch
+
+    def device_step(step):
+        time.sleep(0.004)
+        return fetch(step)
+    monkeypatch.setattr(DecodeScheduler, "_fetch", staticmethod(device_step))
+    clock = [0.0]
+    sched = DecodeScheduler(entry, name="tiled", start=False,
+                            clock=lambda: clock[0])
+    replies = [sched.submit([2, 3, 4, 5, 6], 10, eos_id=-1)
+               for _ in range(3)]
+    ring = obs_trace.get_tracer()
+    ring.clear()
+    _run_until_done(sched, replies)
+    sched.close(drain=False)
+    evs = [e for e in ring.events() if e[0] == "X"
+           and e[1].startswith("serve/decode/")]
+    iters = [e for e in evs if e[1] == "serve/decode/iteration"]
+    leaves = [e for e in evs if e[1].rsplit("/", 1)[1] in LEAVES]
+    assert len(iters) >= 10
+    covered, total = sum(e[5] for e in leaves), sum(e[5] for e in iters)
+    assert 0.99 * total <= covered <= total
+    assert (total - covered) / len(iters) < 100e3
+    for it in iters:                     # every leaf inside an iteration
+        inner = [e for e in leaves if it[4] <= e[4]
+                 and e[4] + e[5] <= it[4] + it[5]]
+        assert {e[1].rsplit("/", 1)[1] for e in inner} >= {
+            "admit", "await_taker", "prefill", "dispatch", "deliver"}
+    programs = {e[6].get("program") for e in evs
+                if e[1] == "serve/decode/dispatch"} - {None}
+    assert programs and programs <= {entry.step_program(False),
+                                     entry.step_program(True)}
+    assert entry.step_program(False) == "jit__step"
+    snap = observe.registry().snapshot()["histograms"]
+    it_ms = snap["serve/tiled/decode/iteration_ms"]
+    host_ms = snap["serve/tiled/decode/host_ms"]
+    waits = sum(e[5] for e in evs if e[1] in ("serve/decode/fetch",
+                                               "serve/decode/await_taker"))
+    assert it_ms["count"] == host_ms["count"] == len(iters)
+    assert it_ms["sum"] == pytest.approx(sum(e[5] for e in iters) * 1e-6)
+    assert host_ms["sum"] + waits * 1e-6 == pytest.approx(it_ms["sum"])
+
+
 @pytest.mark.parametrize("arrives", [True, False])
 def test_free_slot_waits_for_its_taker_while_a_step_is_in_flight(
         entry, monkeypatch, arrives):
@@ -558,9 +613,9 @@ def test_failed_fetch_fails_every_reply_with_the_real_error(entry,
     sched.step_once()
     assert sched._in_flight is not None and sched.queued == 1
 
-    def broken(self, step):
+    def broken(step):
         raise RuntimeError("fetch failed")
-    monkeypatch.setattr(DecodeScheduler, "_deliver", broken)
+    monkeypatch.setattr(DecodeScheduler, "_fetch", staticmethod(broken))
     thread = sched.start()._thread
     for rep in replies:
         with pytest.raises(RuntimeError, match="fetch failed"):

@@ -143,14 +143,78 @@ def test_registry_kind_conflict():
 
 # ----------------------------------------------------------------- tracer
 def test_tracer_disabled_is_zero_allocation(clean_observe):
-    s1 = observe.span("x")
-    s2 = observe.span("y")
-    assert s1 is s2 is obs_trace.NULL_SPAN     # shared no-op singleton
+    """With no knob set (what was once the no-op path) a span still lands
+    in the ring, with its own timing; the knob only decides whether
+    finish() dumps the ring."""
+    assert obs_trace.get_tracer().trace_dir is None
+    with observe.span("x", cat="test") as s1:
+        pass
+    with observe.span("y", cat="test") as s2:
+        pass
+    assert s1 is not s2 and s1.dur_ns >= 0 and s2.t0_ns >= s1.t0_ns
+    names = [e[1] for e in obs_trace.get_tracer().events()]
+    assert names[-2:] == ["x", "y"]
+    assert observe.finish() is None                # nothing dumped
+
+
+def test_one_span_feeds_ring_histogram_and_annotation(clean_observe,
+                                                      monkeypatch):
+    """One span, one clock read an edge: the ring event, the phase
+    histogram and the profiler annotation all see the same span."""
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            entered.append(("exit", self.name))
+    monkeypatch.setattr(obs_trace, "_Annotation", Annotation)
+    with observe.phase("test/p", cat="test", args={"k": 1}) as sp:
+        pass
+    assert entered == [("enter", "test/p"), ("exit", "test/p")]
+    ph, name, cat, _, t0, dur, args = obs_trace.get_tracer().events()[-1]
+    assert (ph, name, cat, args) == ("X", "test/p", "test", {"k": 1})
+    assert (t0, dur) == (sp.t0_ns, sp.dur_ns)
+    h = obs_metrics.registry().snapshot()["histograms"]["phase/test/p"]
+    assert h["count"] == 1 and h["sum"] == pytest.approx(dur * 1e-9)
+
+
+def test_span_is_in_a_live_profiler_capture(clean_observe, tmp_path):
+    """No knob: a span's annotation reaches a `jax.profiler` capture."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        with observe.span("test/in_capture", cat="test"):
+            jnp.ones((4,)).block_until_ready()
+    paths = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+             for f in fs if f.endswith(".xplane.pb")]
+    names = {ev.name for p in paths
+             for plane in ProfileData.from_file(p).planes
+             for line in plane.lines for ev in line.events}
+    assert "test/in_capture" in names
+
+
+def test_gc_collect_records_a_pause_span_and_counter(clean_observe):
+    """A collection is the span `process/gc` (generation, collected) and
+    its pause is added to `process/gc_pause_s` and `phase/process/gc`."""
+    import gc
+    before = observe.counter("process/gc_pause_s").value
+    gc.collect()
+    assert observe.counter("process/gc_pause_s").value > before
+    gcs = [e for e in obs_trace.get_tracer().events()
+           if e[1] == "process/gc"]
+    assert gcs and gcs[-1][6]["generation"] == 2
+    assert gcs[-1][6]["collected"] >= 0 and gcs[-1][5] > 0
+    h = obs_metrics.registry().snapshot()["histograms"]["phase/process/gc"]
+    assert h["count"] >= 1
 
 
 def test_perfetto_trace_schema_and_nesting(tmp_path, clean_observe):
     t = obs_trace.get_tracer()
-    t.enable(str(tmp_path))
+    t.configure(str(tmp_path))
     with observe.span("outer", cat="test", args={"k": 1}):
         with observe.span("inner", cat="test"):
             pass
@@ -182,7 +246,6 @@ def test_perfetto_trace_schema_and_nesting(tmp_path, clean_observe):
 
 def test_tracer_ring_is_bounded(clean_observe):
     t = Tracer(ring=16)
-    t.enable()
     for i in range(100):
         t.record(f"s{i}", "test", i, 1)
     assert len(t.events()) == 16
@@ -266,7 +329,7 @@ def test_report_cli_phase_table(tmp_path, clean_observe, capsys):
 def test_report_cli_trace_validation(tmp_path, clean_observe, capsys):
     from bigdl_tpu.observe.report import main as report_main
     t = obs_trace.get_tracer()
-    t.enable(str(tmp_path))
+    t.configure(str(tmp_path))
     with observe.span("s"):
         pass
     path = t.dump()
@@ -341,7 +404,9 @@ def test_observability_bit_identical_and_no_extra_syncs(
     obs_trace.get_tracer().clear()
     p_on, s_on, rng_on, syncs_on = _train(
         k, tmp_path, monkeypatch, True, f"on{k}")
-    assert syncs_on == syncs_off
+    # the ring records in both runs; 2 is the count the loop had when the
+    # ring recorded only under BIGDL_TPU_TRACE
+    assert syncs_on == syncs_off == 2
     for a, b in zip(jax.tree.leaves(p_off), jax.tree.leaves(p_on)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for a, b in zip(jax.tree.leaves(s_off), jax.tree.leaves(s_on)):

@@ -129,7 +129,8 @@ def test_statusz_endpoints_live_during_optimize(tmp_path, monkeypatch,
     # tests/test_dcn_exchange.py::test_statusz_exchange_section...)
     assert "exchange" not in statusz
     tracez = json.loads(res["/tracez?n=50"][1])
-    assert tracez["enabled"] is True and tracez["count"] > 0
+    assert tracez["dump_dir"] == str(tmp_path / "trace")
+    assert tracez["count"] > 0
     assert any(s["name"] == "train/dispatch" for s in tracez["spans"])
     # shutdown tears the plane down: the port must stop answering
     observe.shutdown()
