@@ -6,16 +6,23 @@ leading `v_width` values are also the value (nn/latent_attention.py). A
 decode step has a few query rows a slot (the verified token and its draft)
 and must read each slot's WHOLE context, wherever its block table puts it,
 once a layer. XLA can only gather the context into a copy and read that
-copy twice; this kernel walks the block table itself: the grid is (slot,
-run of `pages` pool blocks), the block table and the lengths are prefetched
-into scalar memory, and each of `pages` inputs (the same pool) fetches one
-block of the run by its index, so the pipeline streams every block of a
-slot's context from HBM into VMEM once while the previous run is computed.
-Past a slot's end the index stays on its last block, which is then not
-fetched again, and nothing is computed. An online softmax keeps the running
-max and sum; the result is unnormalised, with the max and the sum beside
-it, so that the caller can fold in the keys a step writes after the
-context (a draft's own row) before it divides.
+copy twice; this kernel walks the block table itself.
+
+A grid step is one slot. Its context is read in runs of `pages` pool
+blocks, laid one after another in a key tile of `pages x B` rows in fast
+memory by the kernel's own copies (one a block, the pool left in HBM), two
+tiles deep: the copies of the next run, or of the next slot's first run
+after a slot's last, start before the current run is computed, so the
+stream never waits on the grid. A run is one product of all the slot's
+query rows against the whole tile for the scores, one online-softmax
+update of the running max and sum, and one product of the probabilities
+with the tile's values; the position mask is applied on a slot's last run
+alone, the one that crosses its length, and no copy or product is spent
+past it. The MXU then meets each key row once with every query row (a few
+dozen), so a run costs about what its bytes take to arrive. The result is
+unnormalised, with the max and the sum beside it, so that the caller can
+fold in the keys a step writes after the context (a draft's own row)
+before it divides.
 
 `paged_latent_attend` is the function; on the TPU it is the kernel and on
 any other backend (the CPU of the tests) the same arithmetic in XLA, over
@@ -37,94 +44,128 @@ except Exception:                       # pragma: no cover
     pltpu = None
 
 NEG_INF = -1e30
-# pool blocks a grid step reads: 16 blocks of 64 rows of 640 bfloat16 values
-# are 1.3 MB, which the pipeline moves in ~1.6 us at 819 GB/s, several times
-# the ~0.35 us a grid step costs
-PAGES = 16
+# bytes of pool rows a run copies into its key tile: 16 blocks of 64 rows of
+# 640 bfloat16 values at GLM's widths, 1.3 MB. On a v5e, runs of 8 blocks
+# cost 15 % more a call (a run's fixed cost paid twice as often), runs of 32
+# no less, and two tiles of 64 blocks no longer fit the scoped fast memory
+RUN_BYTES = 1280 * 1024
 _STATS = 128                            # lanes of the max and the sum
 
 
-def _kernel(lens_ref, table_ref, q_ref, *refs, pages: int, block: int,
-            v_width: int, scale: float):
-    pools = refs[:pages]
-    o_ref, m_ref, l_ref = refs[pages:]
-    s, j = pl.program_id(0), pl.program_id(1)
+def run_pages(block: int, width: int, itemsize: int, blocks: int) -> int:
+    """Pool blocks a run copies: `RUN_BYTES` of rows, at most a slot's
+    table."""
+    return max(1, min(blocks, RUN_BYTES // (block * width * itemsize)))
 
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+
+def _attend(q, k, carry, *, scale, v_width, valid=None):
+    """One key tile k (T, W) into the running (o, m, l) of q (Q, W)."""
+    o, m, l = carry
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if valid is not None:
+        s = jnp.where(valid, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    if valid is not None:               # a slot with nothing to read adds 0
+        p = jnp.where(valid, p, 0.0)
+    alpha = jnp.exp(m - m_new)
+    o = o * alpha + jax.lax.dot_general(
+        p.astype(k.dtype), k[:, :v_width], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return o, m_new, l * alpha + jnp.sum(p, axis=1, keepdims=True)
+
+
+def _kernel(lens_ref, table_ref, first_ref, q_ref, pool_ref, o_ref, m_ref,
+            l_ref, keys, sems, *, pages: int, block: int, table_width: int,
+            attend):
+    s, S = pl.program_id(0), pl.num_programs(0)
+    tile = pages * block
+
+    def count(t, j):                    # blocks run j of slot t copies
+        n_blocks = (lens_ref[t] + block - 1) // block
+        return jnp.clip(n_blocks - j * pages, 0, pages)
+
+    def copies(t, j, buf, action):
+        def one(i, c):
+            page = jnp.maximum(table_ref[t * table_width + j * pages + i], 0)
+            dst = keys.at[buf, pl.ds(pl.multiple_of(i * block, block), block)]
+            action(pltpu.make_async_copy(pool_ref.at[page], dst,
+                                         sems.at[buf]))
+            return c
+        jax.lax.fori_loop(0, count(t, j), one, 0)
+
+    @pl.when(s == 0)
+    def _first():
+        # rows past a slot's last block keep an earlier run's pool rows,
+        # finite, so that a masked row's 0 probability times its value is 0
+        keys[...] = jnp.zeros_like(keys)
+        copies(0, 0, 0, lambda c: c.start())
 
     n = lens_ref[s]
-    start = j * pages * block
+    runs = jnp.maximum((n + tile - 1) // tile, 1)
+    q = q_ref[0]
+    Q = q.shape[0]
 
-    @pl.when(start < n)
-    def _compute():
-        q = q_ref[0]                                        # (Q, W)
-        Q = q.shape[0]
-        scores, rows = [], []
-        for i in range(pages):
-            k = pools[i][0]                                 # (B, W)
-            sc = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            at = start + i * block + jax.lax.broadcasted_iota(
-                jnp.int32, (Q, block), 1)
-            scores.append(jnp.where(at < n, sc, NEG_INF))
-            rows.append(k)
-        m_prev = m_ref[0][:, :1]
-        m_new = m_prev
-        for sc in scores:
-            m_new = jnp.maximum(m_new, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        o = o_ref[0] * alpha
-        total = l_ref[0][:, :1] * alpha
-        for sc, k in zip(scores, rows):
-            p = jnp.exp(sc - m_new)
-            total = total + jnp.sum(p, axis=1, keepdims=True)
-            o = o + jax.lax.dot_general(
-                p.astype(k.dtype), k[:, :v_width], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        o_ref[0] = o
-        m_ref[0] = jnp.broadcast_to(m_new, (Q, _STATS))
-        l_ref[0] = jnp.broadcast_to(total, (Q, _STATS))
+    def run(j, carry, last):
+        buf = (first_ref[s] + j) % 2
+
+        @pl.when(j + 1 < runs)
+        def _next_run():
+            copies(s, j + 1, 1 - buf, lambda c: c.start())
+
+        @pl.when(jnp.logical_and(j + 1 == runs, s + 1 < S))
+        def _next_slot():
+            copies(s + 1, 0, 1 - buf, lambda c: c.start())
+
+        copies(s, j, buf, lambda c: c.wait())
+        valid = None
+        if last:
+            at = j * tile + jax.lax.broadcasted_iota(jnp.int32, (Q, tile), 1)
+            valid = at < n
+        return attend(q, keys[buf], carry, valid=valid)
+
+    carry = (jnp.zeros(o_ref.shape[1:], jnp.float32),
+             jnp.full((Q, 1), NEG_INF, jnp.float32),
+             jnp.zeros((Q, 1), jnp.float32))
+    carry = jax.lax.fori_loop(0, runs - 1,
+                              lambda j, c: run(j, c, last=False), carry)
+    o, m, l = run(runs - 1, carry, last=True)
+    o_ref[0] = o
+    m_ref[0] = jnp.broadcast_to(m, (Q, _STATS))
+    l_ref[0] = jnp.broadcast_to(l, (Q, _STATS))
 
 
 def _pallas(q, pool, lengths, block_table, scale, v_width, pages,
-            interpret=False):
+            interpret=False, attend=_attend):
     S, Q, W = q.shape
-    P, B, _ = pool.shape
+    _, B, _ = pool.shape
     M = block_table.shape[1]
-    steps = -(-M // pages)
-
-    def page_of(i):
-        def index(s, j, lens, table):
-            last = jnp.maximum(lens[s] - 1, 0) // B
-            m = jnp.minimum(jnp.minimum(j * pages + i, last), M - 1)
-            return jnp.maximum(table[s * M + m], 0), 0, 0
-        return index
-
-    kernel = functools.partial(_kernel, pages=pages, block=B,
-                               v_width=v_width, scale=scale)
-    stats = pl.BlockSpec((1, Q, _STATS), lambda s, j, *_: (s, 0, 0))
+    lengths = lengths.astype(jnp.int32)
+    runs = jnp.maximum(-(-lengths // (pages * B)), 1)
+    kernel = functools.partial(
+        _kernel, pages=pages, block=B, table_width=M,
+        attend=functools.partial(attend, scale=scale, v_width=v_width))
+    per_slot = lambda s, *_: (s, 0, 0)  # noqa: E731
+    stats = pl.BlockSpec((1, Q, _STATS), per_slot)
     o, m, l = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((S, Q, v_width), jnp.float32),
                    jax.ShapeDtypeStruct((S, Q, _STATS), jnp.float32),
                    jax.ShapeDtypeStruct((S, Q, _STATS), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(S, steps),
-            in_specs=[pl.BlockSpec((1, Q, W), lambda s, j, *_: (s, 0, 0))]
-            + [pl.BlockSpec((1, B, W), page_of(i)) for i in range(pages)],
-            out_specs=(pl.BlockSpec((1, Q, v_width),
-                                    lambda s, j, *_: (s, 0, 0)),
-                       stats, stats)),
+            num_scalar_prefetch=3, grid=(S,),
+            in_specs=[pl.BlockSpec((1, Q, W), per_slot),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=(pl.BlockSpec((1, Q, v_width), per_slot), stats, stats),
+            scratch_shapes=[pltpu.VMEM((2, pages * B, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        # a slot's last run starts the next slot's copies: one core, in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret, name="paged_latent_attend",
-    )(lengths.astype(jnp.int32), block_table.reshape(-1).astype(jnp.int32),
-      q, *([pool] * pages))
+    )(lengths, block_table.reshape(-1).astype(jnp.int32),
+      (jnp.cumsum(runs) - runs).astype(jnp.int32), q, pool)
     return o, m[..., 0], l[..., 0]
 
 
@@ -145,7 +186,7 @@ def _xla(q, pool, lengths, block_table, scale, v_width):
 
 
 def paged_latent_attend(q, pool, lengths, block_table, *, scale: float,
-                        v_width: int, pages: int = PAGES,
+                        v_width: int, pages: int | None = None,
                         interpret: bool = False):
     """Every query row of a slot over the first `lengths[s]` positions of
     its context. q (S, Q, W) (a slot's query rows and heads, absorbed);
@@ -154,8 +195,12 @@ def paged_latent_attend(q, pool, lengths, block_table, *, scale: float,
     Returns (o (S, Q, v_width) float32 = sum_s e^(score - m) row_s[:v_width],
     m (S, Q) float32 the largest score, l (S, Q) float32 = sum_s e^(score -
     m)); a slot with nothing to read has o = l = 0 and m = NEG_INF.
-    `interpret` runs the kernel in the Pallas interpreter (tests)."""
-    pages = max(1, min(pages, block_table.shape[1]))
+    `pages`, the pool blocks of a run, comes from the shapes (`run_pages`)
+    unless given; `interpret` runs the kernel in the Pallas interpreter
+    (tests)."""
+    _, B, W = pool.shape
+    M = block_table.shape[1]
+    pages = max(1, min(pages or run_pages(B, W, pool.dtype.itemsize, M), M))
     if interpret:
         return _pallas(q, pool, lengths, block_table, scale, v_width, pages,
                        interpret=True)

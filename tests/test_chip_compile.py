@@ -416,15 +416,29 @@ def test_paged_latent_kernel_at_glm47_flash_widths(one_chip):
     """GLM-4.7-Flash's decode-step attention (`glm-4.7-flash-serve`): 64
     slots of 2 query rows x 20 heads over rows of 640 bfloat16 values, 281
     blocks of 64 a slot, a pool of 3,712 blocks: the block-table walk
-    compiles as the Pallas kernel, within its fast memory."""
-    from bigdl_tpu.kernels.paged_latent import paged_latent_attend
+    compiles as the Pallas kernel, its two key tiles of 16 blocks within
+    its fast memory, and stays the call `latent_attend_roofline_pct`'s
+    match finds: named `paged_latent_attend`, its first output the
+    unnormalised f32[64,40,512]."""
+    import json
+    from bigdl_tpu.kernels.paged_latent import paged_latent_attend, run_pages
+    assert run_pages(64, 640, 2, 281) == 16
     f = jax.jit(lambda q, pool, n, table: paged_latent_attend(
         q, pool, n, table, scale=1 / 16, v_width=512))
     compiled = f.lower(_sds(one_chip, (64, 40, 640), jnp.bfloat16),
                        _sds(one_chip, (3712, 64, 640), jnp.bfloat16),
                        _sds(one_chip, (64,), np.int32),
                        _sds(one_chip, (64, 281), np.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%paged_latent_attend(\.\d+)? = \(f32\[64,40,512\]",
+                     text)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "layer_metrics",
+                           "latent_attend_roofline_pct.json")) as fh:
+        match = json.load(fh)["args"]["match"]
+    assert len(re.findall(match, text)) == 1
     _fits(compiled)
 
 

@@ -22,7 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from bigdl_tpu.kernels.paged_latent import _xla, paged_latent_attend
+from bigdl_tpu.kernels.paged_latent import NEG_INF, _xla, paged_latent_attend
 from bigdl_tpu.serve.decode import DecodeEntry, DecodeScheduler, PrefixCache
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -107,26 +107,51 @@ def _accepting(params, share=0.0):
 
 
 # ---------------------------------------------------- the decode kernel
-def test_the_kernel_is_its_xla_form():
+# runs of 4 blocks of 8 rows (32 keys); the slots' lengths, the table's
+# width in blocks
+KERNEL_CASES = {
+    "crosses_at_a_runs_first_block": ((37, 5, 65), 12),
+    "crosses_at_a_middle_block": ((43, 19, 83), 12),
+    "crosses_at_a_runs_last_block": ((61, 30, 95), 12),
+    "whole_runs": ((32, 64, 96), 12),
+    "table_not_whole_runs": ((80, 73, 33), 10),
+    "empty_slots": ((0, 45, 0, 20), 12),
+}
+
+
+@pytest.mark.parametrize("rows", [20, 40])
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_kernel_is_its_xla_form(case, rows):
     """The block-table walk (Pallas, interpreted here) and the gathered
-    context give the same sums: ragged lengths, a slot with nothing to
-    read, blocks scattered over the pool, a run of pages past a slot's
-    end."""
-    S, Q, W, B, P, M = 3, 8, 128, 8, 40, 6
-    r = np.random.default_rng(0)
-    q = jnp.asarray(r.standard_normal((S, Q, W)), jnp.float32)
+    context give the same sums: a slot's last run crossing its length at
+    each kind of block or at none, a table the run does not divide, slots
+    with nothing to read among others (the next slot's copies still
+    start), blocks scattered over the pool, one query row a slot's head or
+    two (a draft's)."""
+    lengths, M = KERNEL_CASES[case]
+    S, W, B, P = len(lengths), 128, 8, 64
+    r = np.random.default_rng(rows)
+    q = jnp.asarray(r.standard_normal((S, rows, W)), jnp.float32)
     pool = jnp.asarray(r.standard_normal((P, B, W)), jnp.float32)
     table = np.full((S, M), -1, np.int32)
-    table[0, :5] = [3, 7, 1, 20, 9]
-    table[1, :2] = [5, 6]
+    taken = r.permutation(P)
+    for s, n in enumerate(lengths):
+        used = -(-n // B)
+        table[s, :used], taken = taken[:used], taken[used:]
     table = jnp.asarray(table)
-    lens = jnp.asarray([37, 9, 0], jnp.int32)
-    got = paged_latent_attend(q, pool, lens, table, scale=0.1, v_width=64,
+    lens = jnp.asarray(lengths, jnp.int32)
+    # scores of spread ~3, so that the sums, added in another order (a
+    # run's tile, then the runs), stay a few units large
+    got = paged_latent_attend(q, pool, lens, table, scale=0.25, v_width=64,
                               pages=4, interpret=True)
-    want = _xla(q, pool, lens, table, 0.1, 64)
+    want = _xla(q, pool, lens, table, 0.25, 64)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
-    assert float(got[2][2].max()) == 0.0        # nothing read: l = 0
+    for s, n in enumerate(lengths):
+        if n == 0:                              # nothing read
+            assert float(jnp.abs(got[0][s]).max()) == 0.0
+            assert float(got[2][s].max()) == 0.0
+            assert float(got[1][s].max()) == np.float32(NEG_INF)
 
 
 # ------------------------------------------- paged paths vs the reference
